@@ -971,19 +971,31 @@ def with_closed(client, fn):
 
 def cmd_version(args):
     """Version information (the reference's armadactl version,
-    internal/armadactl/version.go: version + runtime)."""
+    internal/armadactl/version.go: version + runtime).  The device line is
+    the control plane's at --url -- the devices its rounds actually ran on
+    -- not this process's: a chip belongs to one process at a time, and
+    asking jax here would claim the one the plane holds."""
     import platform
+
+    import grpc
+    import jax
+    import jaxlib
 
     import armada_tpu
 
     print(f"armadactl-tpu version:\t{armada_tpu.__version__}")
     print(f"Python version:\t{platform.python_version()}")
+    print(f"JAX version:\t{jax.__version__} (jaxlib {jaxlib.__version__})")
     try:
-        import jax
-
-        print(f"JAX version:\t{jax.__version__}")
-    except ImportError:
-        pass
+        dev = with_closed(_client(args), lambda c: c.quarantine_status())["device"]
+    except grpc.RpcError:
+        print(f"Control plane device:\tunreachable ({args.url})")
+        return 0
+    print(
+        f"Control plane device:\tplatform={dev['platform']} "
+        f"device_kind={dev['device_kind']} device_count={dev['device_count']} "
+        f"backend={dev['backend']} fallbacks={dev['fallbacks']}"
+    )
     return 0
 
 
@@ -1577,9 +1589,6 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     import grpc
 
-    from armada_tpu.core.platform import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     args = build_parser().parse_args(argv)
     try:
         return args.fn(args)

@@ -148,8 +148,10 @@ class _Handler(BaseHTTPRequestHandler):
             # the DEGRADATION state (device backend, consecutive failures,
             # last fallback reason -- core/watchdog).  A plane running on
             # the CPU failover is degraded-but-HEALTHY: liveness must not
-            # flip (restarting it would not fix the tunnel), the operator
-            # reads the device block instead (docs/operations.md runbook).
+            # flip (restarting it would not fix the device), the operator
+            # reads the device block instead (docs/operations.md runbook):
+            # backend, fallbacks, and the platform / device kind / count
+            # the last round's output arrays lived on.
             import json
 
             err = srv.checker.check()

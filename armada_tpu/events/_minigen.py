@@ -7,7 +7,7 @@ compiler binary.  This module covers exactly the dialect those files use
 no enums, no nested user messages, no extensions): it parses the .proto into
 a ``FileDescriptorProto`` and emits a ``*_pb2.py`` with the same
 ``AddSerializedFile`` + ``_builder`` structure protoc's python_out produces,
-so downstream imports (including the committed ``rpc_pb2.py``, which resolves
+so downstream imports (including the generated ``rpc_pb2.py``, which resolves
 ``events.proto`` symbols through the default descriptor pool) work
 identically.  When a real ``protoc`` is on PATH the callers prefer it.
 """

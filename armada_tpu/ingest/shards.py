@@ -218,10 +218,30 @@ def _worker_convert(
 
 
 # One process-global converter pool shared by every sharded pipeline in the
-# process (spawn context: forking a thread-heavy serving process deadlocks).
-# Workers import only the light ingest chain (~0.3s each, no jax).
+# process (forkserver context: forking a thread-heavy serving process
+# deadlocks).
 _pool = None
 _pool_lock = make_lock("ingest.convert_pool")
+
+
+def _start_forkserver_on_cpu() -> None:
+    """Start the forkserver NOW, with JAX_PLATFORMS=cpu in its environment
+    (its workers inherit it).  The preloaded ingest chain imports jax, the
+    serving process may hold the chip, and libtpu gives a chip to one
+    process at a time: a converter worker must never be able to initialise
+    an accelerator backend.  This process's own jax read the variable at
+    import, so the brief override does not touch it."""
+    from multiprocessing import forkserver
+
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        forkserver.ensure_running()
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
 
 
 def _convert_pool(workers: int):
@@ -238,11 +258,8 @@ def _convert_pool(workers: int):
             # driver script, and breaking outright under stdin mains).  The
             # forkserver is ONE clean process that preloads the light
             # convert chain; workers fork from it in milliseconds.
-            try:
-                ctx = mp.get_context("forkserver")
-                ctx.set_forkserver_preload(["armada_tpu.ingest.shards"])
-            except ValueError:  # platform without forkserver
-                ctx = mp.get_context("spawn")
+            ctx = mp.get_context("forkserver")
+            ctx.set_forkserver_preload(["armada_tpu.ingest.shards"])
             # Worker startup re-prepares the parent's __main__.  A script
             # main (bench.py imports jax at top) would be re-imported into
             # every worker, and a <stdin> main breaks startup outright --
@@ -265,6 +282,7 @@ def _convert_pool(workers: int):
             # pipeline cannot starve a wide later one (workers spawn
             # lazily, so unused width costs nothing).
             size = min(os.cpu_count() or 8, max(workers, 8))
+            _start_forkserver_on_cpu()
             _pool = ProcessPoolExecutor(max_workers=size, mp_context=ctx)
             atexit.register(_pool.shutdown, wait=False, cancel_futures=True)
         return _pool

@@ -113,21 +113,14 @@ def run_soak_cli(cfg: "SoakConfig") -> dict:
     """The shared driver behind `tools/soak.py` and `armadactl soak`:
     compilation cache on (a cold kernel compile inside the measured window
     would dominate a downscaled run), temp data dir, and the backend
-    platform stamped into the report so CPU-fallback numbers are labelled.
+    platform stamped into the report so a CPU run is labelled as one.
     Returns the report; callers print it as ONE JSON line and map `ok` to
     the exit code."""
     import tempfile
 
     from armada_tpu.core.platform import enable_compilation_cache
 
-    cache_dir = os.environ.get("ARMADA_COMPILE_CACHE", "")
-    if cache_dir != "0":
-        repo_root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        enable_compilation_cache(
-            cache_dir or os.path.join(repo_root, ".jax_cache")
-        )
+    enable_compilation_cache()
     with tempfile.TemporaryDirectory(prefix="armada-soak-") as d:
         report = run_soak(cfg, d)
     import jax
@@ -429,9 +422,9 @@ def run_soak(cfg: SoakConfig, data_dir: str, stub_probe: bool = True) -> dict:
     """Run one soak window; returns the JSON-able report.
 
     `stub_probe`: when a fault is configured, stub the device supervisor's
-    subprocess re-probe healthy (this host's default backend IS the device
-    under test -- same stub chaos_cycle uses) so re-promotion is part of the
-    measured window.
+    re-probe healthy (this host's default backend IS the device under test,
+    and the real probe refuses a CPU -- same stub chaos_cycle uses) so
+    re-promotion is part of the measured window.
     """
     from armada_tpu.analysis import tsan
     from armada_tpu.core import faults, watchdog
@@ -727,5 +720,5 @@ def run_soak(cfg: SoakConfig, data_dir: str, stub_probe: bool = True) -> dict:
         if cfg.fault and stub_probe:
             # Drop the always-healthy probe stub with the supervisor it
             # was installed on; later device-loss tests must pay real
-            # (subprocess) probes again.
+            # probes again.
             watchdog.reset_supervisor()

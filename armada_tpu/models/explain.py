@@ -66,9 +66,6 @@ Approximations (documented, pinned by tests/test_explain.py):
   unplaced gangs; builder problems intern (request, PC) into the key
   (core/keys.py) so this is exact, synthetic label-keys get max-request
   attribution (observability only -- decisions never read this pass).
-- rounds on a mesh with >=2 >1-sized axes skip the pass (the known XLA:CPU
-  GSPMD cross-jit reduction miscompile, see problem._dispatch_compact);
-  the serving mesh is nodes x 1 and keeps it.
 """
 
 from __future__ import annotations
@@ -493,16 +490,6 @@ class ExplainOutcome:
         return out
 
 
-def _mesh_blocked(arr) -> bool:
-    """The >=2 >1-sized-axis GSPMD reduction miscompile gate (same rule as
-    problem._dispatch_compact; the N x 1 serving mesh passes)."""
-    sharding = getattr(arr, "sharding", None)
-    mesh_shape = getattr(getattr(sharding, "mesh", None), "shape", None)
-    return mesh_shape is not None and sum(
-        1 for v in mesh_shape.values() if v > 1
-    ) >= 2
-
-
 def dispatch_explain(device_problem, result, ctx):
     """Enqueue the explain kernel behind the round WITHOUT reading it back;
     returns (device buffer, kcap, fcap) or None (pass unavailable for this
@@ -511,8 +498,6 @@ def dispatch_explain(device_problem, result, ctx):
     import jax
 
     if not isinstance(result.g_state, jax.Array):
-        return None
-    if _mesh_blocked(result.g_state):
         return None
     G = int(result.g_state.shape[0])
     K = int(device_problem.compat.shape[0])

@@ -147,6 +147,93 @@ def synthetic_world(
     return config, nodes, queues, specs, running, spec_factory
 
 
+def synthetic_serving_config(config, burst: int):
+    """A synthetic_world config as the served-cycle harnesses run it: the
+    incremental build on, no rate limiting in the measured cycle, `burst`
+    as the per-cycle placement cap."""
+    import dataclasses
+
+    return dataclasses.replace(
+        config,
+        incremental_problem_build=True,
+        maximum_scheduling_rate=1e9,
+        maximum_per_queue_scheduling_rate=1e9,
+        maximum_scheduling_burst=burst,
+        maximum_per_queue_scheduling_burst=burst,
+    )
+
+
+def synthetic_job_state(spec, jobset: str = "bench"):
+    """One queued, validated synthetic JobSpec as the JobState wire message
+    a mirroring control plane sends in SyncState."""
+    from armada_tpu.events.convert import job_spec_to_proto
+    from armada_tpu.rpc import rpc_pb2 as pb
+
+    return pb.JobState(
+        job_id=spec.id,
+        queue=spec.queue,
+        jobset=jobset,
+        spec=job_spec_to_proto(spec),
+        priority=spec.priority,
+        queued=True,
+        validated=True,
+        submit_time=spec.submit_time,
+    )
+
+
+def synthetic_mirror(config, nodes, specs, running, now_ns: int, chunk: int = 50_000):
+    """(executors, job_state_chunks): the SyncState payload mirroring a
+    synthetic_world into a sidecar session.  Nodes ride in 10 executor
+    snapshots (one giant snapshot is not what real callers send); the
+    queued specs, then the running jobs, come as a GENERATOR of
+    `chunk`-sized JobState lists, so a million messages are never alive at
+    once and each list fits one gRPC message."""
+    from armada_tpu.rpc import rpc_pb2 as pb
+    from armada_tpu.scheduler.executors import ExecutorSnapshot
+
+    n_ex = 10
+    per = (len(nodes) + n_ex - 1) // n_ex
+    executors = [
+        ExecutorSnapshot(
+            id=f"ex{e}",
+            pool="default",
+            nodes=tuple(nodes[e * per : (e + 1) * per]),
+            last_update_ns=now_ns,
+        )
+        for e in range(n_ex)
+    ]
+
+    def running_state(r, i):
+        m = synthetic_job_state(r.job)
+        m.queued = False
+        m.run.MergeFrom(
+            pb.JobRunState(
+                run_id=f"run{i:08d}",
+                node_id=r.node_id,
+                node_name=r.node_id,
+                pool="default",
+                scheduled_at_priority=config.priority_class(
+                    r.job.priority_class
+                ).priority,
+                has_scheduled_at_priority=True,
+                running=True,
+                running_ns=now_ns - 10**9,
+            )
+        )
+        return m
+
+    def chunks():
+        for lo in range(0, len(specs), chunk):
+            yield [synthetic_job_state(s) for s in specs[lo : lo + chunk]]
+        for lo in range(0, len(running), chunk):
+            yield [
+                running_state(r, lo + i)
+                for i, r in enumerate(running[lo : lo + chunk])
+            ]
+
+    return executors, chunks()
+
+
 def synthetic_problem(
     *,
     num_nodes: int,

@@ -202,14 +202,18 @@ class ControlPlaneServer:
 
     def quarantine_status(self, principal: Principal = Principal()) -> dict:
         """The round-verification ledger + device quarantine scoreboard
-        (the same block /healthz embeds).  Plane-LOCAL like the checkpoint
-        verbs: a quarantine is one replica's view of its own accelerator."""
+        (the same block /healthz embeds), with /healthz's `device` block
+        beside it: backend, fallbacks, and the platform / device kind /
+        count the last round's outputs lived on.  Plane-LOCAL like the
+        checkpoint verbs: a quarantine is one replica's view of its own
+        accelerator."""
         self._auth.authorize_action(
             principal, Permission.UPDATE_EXECUTOR_SETTINGS
         )
+        from armada_tpu.core.watchdog import supervisor
         from armada_tpu.models.verify import healthz_block
 
-        return healthz_block()
+        return {**healthz_block(), "device": supervisor().snapshot()}
 
     def quarantine_clear(
         self, device: str = "", principal: Principal = Principal()

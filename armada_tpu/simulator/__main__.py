@@ -14,9 +14,6 @@ import time
 
 
 def main(argv=None) -> int:
-    from armada_tpu.core.platform import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     ap = argparse.ArgumentParser(prog="armada-tpu-simulator")
     ap.add_argument("--clusters", nargs="+", required=True, help="cluster spec YAMLs")
     ap.add_argument("--workloads", nargs="+", required=True, help="workload spec YAMLs")

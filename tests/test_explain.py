@@ -565,10 +565,7 @@ def test_failover_round_keeps_attribution(monkeypatch):
     import armada_tpu.models as models_pkg
     from armada_tpu.core import watchdog
 
-    try:
-        from jax.errors import JaxRuntimeError as XlaError
-    except ImportError:  # older jax: the jaxlib name
-        from jaxlib.xla_extension import XlaRuntimeError as XlaError
+    from jax.errors import JaxRuntimeError as XlaError
 
     monkeypatch.setenv("ARMADA_EXPLAIN_INTERVAL", "2")
     monkeypatch.setenv("ARMADA_WATCHDOG_S", "60")

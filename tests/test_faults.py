@@ -340,7 +340,7 @@ def test_device_error_failover_bit_equal(monkeypatch):
 
 @pytest.mark.fast
 def test_device_hang_failover_within_deadline(monkeypatch):
-    """The tunnel-wedge shape: the round thread hangs; the watchdog abandons
+    """The device-wedge shape: the round thread hangs; the watchdog abandons
     it at the deadline and the CPU re-run produces identical decisions."""
     cfg = make_config()
     F, nodes, queues = make_world(cfg)

@@ -89,9 +89,8 @@ def run_script(
     os.environ["ARMADA_REPROBE_INTERVAL_S"] = "0.05"
     os.environ["ARMADA_WATCHDOG_S"] = str(deadline_s)
     os.environ["ARMADA_FAULT_HANG_S"] = "60"
-    # the re-probe must see a healthy backend (this host's default jax
-    # platform IS the device under test) without paying a subprocess per
-    # poll in a drill loop
+    # the re-probe must see a healthy backend: this host's default jax
+    # platform IS the device under test, and the real probe refuses a CPU
     sup._probe = lambda timeout_s: (True, "chaos-stub")
     ms = reset_mesh_serving()
     if mesh:

@@ -38,12 +38,13 @@ def main():
     runs = int(os.environ.get("PRUNS", nodes // 2))
     burst = int(os.environ.get("PBURST", 1_000))
 
-    import dataclasses
-
-    from armada_tpu.events.convert import job_spec_to_proto
-    from armada_tpu.models.synthetic import synthetic_world
+    from armada_tpu.models.synthetic import (
+        synthetic_job_state,
+        synthetic_mirror,
+        synthetic_serving_config,
+        synthetic_world,
+    )
     from armada_tpu.rpc import rpc_pb2 as pb
-    from armada_tpu.scheduler.executors import ExecutorSnapshot
     from armada_tpu.scheduler.sidecar import ScheduleSidecar
 
     t0 = time.perf_counter()
@@ -66,80 +67,19 @@ def main():
             shape_bucket=max(8192, 4 * burst),
         )
     )
-    config = dataclasses.replace(
-        config,
-        incremental_problem_build=True,
-        maximum_scheduling_rate=1e9,
-        maximum_per_queue_scheduling_rate=1e9,
-        maximum_scheduling_burst=burst,
-        maximum_per_queue_scheduling_burst=burst,
-    )
+    config = synthetic_serving_config(config, burst)
     now0 = 10**12
     clock = [now0]
     sidecar = ScheduleSidecar(config, clock_ns=lambda: clock[0])
     sid = sidecar.create_session("prof")
     session = sidecar.session(sid)
 
-    def state_of_spec(s):
-        return pb.JobState(
-            job_id=s.id,
-            queue=s.queue,
-            jobset="bench",
-            spec=job_spec_to_proto(s),
-            priority=s.priority,
-            queued=True,
-            validated=True,
-            submit_time=s.submit_time,
-        )
-
-    def state_of_run(r, i):
-        m = state_of_spec(r.job)
-        m.queued = False
-        pc = config.priority_class(r.job.priority_class)
-        m.run.MergeFrom(
-            pb.JobRunState(
-                run_id=f"run{i:08d}",
-                node_id=r.node_id,
-                node_name=r.node_id,
-                pool="default",
-                scheduled_at_priority=pc.priority,
-                has_scheduled_at_priority=True,
-                running=True,
-                running_ns=now0 - 10**9,
-            )
-        )
-        return m
-
-    n_ex = 10
-    per = (len(nodes_l) + n_ex - 1) // n_ex
-    executors = [
-        ExecutorSnapshot(
-            id=f"ex{e}",
-            pool="default",
-            nodes=tuple(nodes_l[e * per : (e + 1) * per]),
-            last_update_ns=now0,
-        )
-        for e in range(n_ex)
-    ]
+    executors, job_chunks = synthetic_mirror(
+        config, nodes_l, specs, running, now0
+    )
     session.apply_sync(executors=executors, queues=queues_l)
-    chunk = 50_000
-    for lo in range(0, len(specs), chunk):
-        sidecar.handle_sync(
-            pb.SyncStateRequest(
-                session_id=sid,
-                jobs=[state_of_spec(s) for s in specs[lo : lo + chunk]],
-            )
-        )
-    for lo in range(0, len(running), chunk):
-        sidecar.handle_sync(
-            pb.SyncStateRequest(
-                session_id=sid,
-                jobs=[
-                    state_of_run(r, lo + i)
-                    for i, r in enumerate(running[lo : lo + chunk])
-                ],
-            )
-        )
+    for states in job_chunks:
+        sidecar.handle_sync(pb.SyncStateRequest(session_id=sid, jobs=states))
     print(f"setup {time.perf_counter()-t0:.1f}s", file=sys.stderr)
 
     from armada_tpu.models.xfer import TRANSFER_STATS
@@ -164,7 +104,7 @@ def main():
     def cycle():
         clock[0] += 10**9
         fresh = spec_factory(burst, clock[0] / 1e9)
-        states = [state_of_spec(s) for s in fresh]
+        states = [synthetic_job_state(s) for s in fresh]
         TRANSFER_STATS.reset()
         sidecar.handle_sync(pb.SyncStateRequest(session_id=sid, jobs=states))
         t_sync = _ring_duration("sync")
@@ -181,7 +121,7 @@ def main():
     # Pipeline A/B over the SAME live session (the sidecar reads
     # ARMADA_PIPELINE / ARMADA_PIPELINE_PREFETCH per call): warmed cycles
     # per arm, with per-cycle device-transfer counters split by phase -- on
-    # the real tunnel, upload work counted in the SYNC phase overlaps the
+    # an accelerator, upload work counted in the SYNC phase overlaps the
     # caller's cycle instead of the round's critical path, so the
     # sync-vs-round split is the number to watch even on a CPU host.
     # Arms: pipelined+prefetch (the TPU-shaped config, scatter forced on),
