@@ -1,0 +1,147 @@
+"""The benchmark's plain reference: straight numpy and Python over the
+recorded answers, independent of `armada_tpu`, run after the window.
+
+It replays what the client sent and what the scheduler answered, cycle by
+cycle, against the world's own tables, and holds the answers to what the
+reference scheduler's semantics guarantee, no more (an order of leases within
+a queue is not among them: a job that does not fit is skipped; which of two
+equally good nodes wins is not either).  A violation is a string; none means
+the run's decisions are sound.
+
+Invariants (README.md lists them for users):
+  1. every leased job is one the client submitted, is queued, and was not
+     leased before (a lease is durable: no job is leased twice in a run);
+  2. every lease names a node of the fleet;
+  3. after each round no node holds more than its capacity, in any resource,
+     counting the initial running set, every lease so far, minus every run
+     the client has reported finished;
+  4. a round leases at most the per-round cap, and at most the per-queue cap
+     from any one queue, and says which queue each job belongs to correctly;
+  5. the scheduler's own count of running jobs moves by exactly
+     leases - completions from round to round, and its count of queued jobs
+     by submits - leases (what it acknowledged, it keeps).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+QUEUED, LEASED, DONE = 0, 1, 2
+MAX_REPORTED = 20
+
+
+class Checker:
+    def __init__(self, world, cap: int, queue_cap: int):
+        self.world = world
+        self.cap = cap
+        self.queue_cap = queue_cap
+        self.used = np.zeros_like(world.node_total)
+        np.add.at(self.used, world.run_node, world.run_shape_req[world.run_shape])
+        self.violations: list = []
+        self.bad_cycles: set = set()
+        over = (self.used > world.node_total).any(axis=1)
+        if over.any():
+            self._bad(0, f"the initial running set overfills {int(over.sum())} nodes")
+        self.status = np.full(world.num_jobs, QUEUED, np.int8)
+        self.submitted = int(world.sizes["queued_jobs"])  # numbers below are known
+        self.node_of = {}  # leased job number -> node index
+        self.prev_counts = None
+
+    def _bad(self, n: int, msg: str) -> None:
+        self.bad_cycles.add(n)
+        msg = f"cycle {n}: {msg}"
+        if len(self.violations) < MAX_REPORTED:
+            self.violations.append(msg)
+        elif len(self.violations) == MAX_REPORTED:
+            self.violations.append("... more violations not listed")
+
+    def cycle(self, n: int, record: dict) -> None:
+        """One cycle's record: `submitted` (job numbers the SyncState carried),
+        `completed` (job numbers it reported finished), `leases` ((job id,
+        node id, queue) the round returned), `preempted` (job ids), and the
+        scheduler's own `num_queued` / `num_running` before the round."""
+        w = self.world
+        if len(self.status) < w.num_jobs:
+            self.status = np.concatenate(
+                [self.status, np.full(w.num_jobs - len(self.status), QUEUED, np.int8)]
+            )
+        self.submitted = max(self.submitted, max(record["submitted"], default=-1) + 1)
+        for i in record["completed"]:
+            if self.status[i] != LEASED:
+                self._bad(n, f"the client completed job {i} that holds no lease")
+                continue
+            self.status[i] = DONE
+            self.used[self.node_of.pop(i)] -= w.shape_req[w.job_shape[i]]
+
+        leases = record["leases"]
+        if len(leases) > self.cap:
+            self._bad(n, f"{len(leases)} leases, over the per-round cap {self.cap}")
+        per_queue: dict = {}
+        touched = []
+        for job_id, node_id, queue in leases:
+            try:
+                i = w.job_number(job_id)
+            except KeyError:
+                self._bad(n, f"leased unknown job {job_id!r}")
+                continue
+            if i >= self.submitted:
+                self._bad(n, f"leased job {job_id} before it was submitted")
+                continue
+            if self.status[i] != QUEUED:
+                what = "twice" if self.status[i] == LEASED else "after it finished"
+                self._bad(n, f"job {job_id} leased {what}")
+                continue
+            node = w.node_index.get(node_id)
+            if node is None:
+                self._bad(n, f"job {job_id} leased to unknown node {node_id!r}")
+                continue
+            if queue != w.queue_names[w.job_queue[i]]:
+                self._bad(n, f"job {job_id} reported in queue {queue!r}")
+            per_queue[queue] = per_queue.get(queue, 0) + 1
+            self.status[i] = LEASED
+            self.node_of[i] = node
+            self.used[node] += w.shape_req[w.job_shape[i]]
+            touched.append(node)
+        for queue, k in per_queue.items():
+            if k > self.queue_cap:
+                self._bad(n, f"{k} leases from {queue}, over the per-queue cap")
+        for job_id in record["preempted"]:
+            # nothing in these cells fills the fleet; a preemption frees the
+            # run's resources and requeues the job
+            try:
+                i = w.job_number(job_id)
+            except KeyError:
+                self._bad(n, f"preempted unknown job {job_id!r}")
+                continue
+            if self.status[i] != LEASED:
+                self._bad(n, f"preempted job {job_id} that holds no lease")
+                continue
+            self.status[i] = QUEUED
+            self.used[self.node_of.pop(i)] -= w.shape_req[w.job_shape[i]]
+        if touched:
+            idx = np.unique(np.asarray(touched))
+            over = (self.used[idx] > w.node_total[idx]).any(axis=1)
+            for node in idx[over][:3]:
+                self._bad(
+                    n,
+                    f"node {w.node_ids[node]} holds {self.used[node].tolist()} "
+                    f"of {w.node_total[node].tolist()} (thousandths of cpu, memory)"
+                )
+
+        # 5: the scheduler's own counts, as the round saw them on entry
+        counts = (record.get("num_queued"), record.get("num_running"))
+        if None not in counts and self.prev_counts is not None:
+            (q0, r0), prev = self.prev_counts
+            want_q = q0 + len(record["submitted"]) - prev["leased"] + prev["preempted"]
+            want_r = r0 + prev["leased"] - prev["preempted"] - len(record["completed"])
+            if counts != (want_q, want_r):
+                self._bad(
+                    n,
+                    f"scheduler counts queued/running {counts}, "
+                    f"the mirror should hold {(want_q, want_r)}"
+                )
+        if None not in counts:
+            self.prev_counts = (
+                counts,
+                {"leased": len(leases), "preempted": len(record["preempted"])},
+            )

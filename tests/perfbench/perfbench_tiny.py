@@ -1,0 +1,69 @@
+"""Shared by the perfbench tests: the repo root on sys.path, and a tiny copy
+of the benchmark (its own BENCHMARK.json, one new configuration, one new
+traffic mix, one new per-layer metric) made of files only."""
+
+import json
+import os
+import shutil
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+RUN = os.path.join(ROOT, "perfbench", "run.py")
+
+
+def load(*parts):
+    with open(os.path.join(ROOT, *parts), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3):
+    """A benchmark root under `root` with one tiny cell, `tiny.steady-40`,
+    added as files only; returns the path of its BENCHMARK.json."""
+    root = str(root)
+    data = os.path.join(root, "perfbench")
+    shutil.copytree(os.path.join(ROOT, "perfbench", "layers"), os.path.join(data, "layers"))
+    os.makedirs(os.path.join(data, "configs"))
+    os.makedirs(os.path.join(data, "traffic"))
+    bench = load("BENCHMARK.json")
+    config = load("perfbench", "configs", "cluster-100k-5k.json")
+    config["name"] = "tiny"
+    config["world"].update(
+        nodes=nodes, queued_jobs=queued, running_jobs=running, queues=8,
+        executors=2, mirror_chunk=1000,
+    )
+    config["scheduling"]["shapeBucket"] = 256
+    traffic = load("perfbench", "traffic", "steady-1k.json")
+    traffic.update(
+        name="steady-40", submits_per_cycle=burst, cap=burst,
+        lifetime_cycles=lifetime, traced_cycles=2,
+    )
+    extra = {
+        "name": "downloads_per_cycle", "layer": "decode and apply", "unit": "count",
+        "better": "lower", "moves": "cycle_p50_s", "source": "program_counter",
+        "read": {"kind": "cycle_field", "field": "downloads", "reduce": "median"},
+    }
+    for path, doc in (
+        (os.path.join(data, "configs", "tiny.json"), config),
+        (os.path.join(data, "traffic", "steady-40.json"), traffic),
+        (os.path.join(data, "layers", "downloads_per_cycle.json"), extra),
+    ):
+        with open(path, "w", encoding="utf-8") as f:
+            json.dump(doc, f)
+    bench["configs"] = [
+        {"name": "tiny", "source": "test", "file": "perfbench/configs/tiny.json",
+         "reduced": [], "why": "test"}
+    ]
+    bench["workloads"] = [
+        {"name": "tiny.steady-40", "config": "tiny", "traffic": "steady-40",
+         "chips": 1, "why": "test"}
+    ]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        m.pop("workloads", None)
+    bench["per_layer"].append({k: extra[k] for k in ("name", "unit", "better", "source", "layer", "moves")})
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump(bench, f)
+    return path

@@ -1,0 +1,109 @@
+"""The general reader of per-layer metrics, and adding to the benchmark by
+files only."""
+
+import json
+import os
+
+import pytest
+
+from perfbench_tiny import make_tiny
+
+from perfbench.harness import layers, roofline
+from perfbench.harness.cell import Cell, CellError
+
+TREE = {
+    "name": "sidecar_round", "dur_s": 1.0,
+    "children": [
+        {"name": "assemble", "dur_s": 0.2},
+        {"name": "round", "dur_s": 0.6, "children": [
+            {"name": "feed_apply", "dur_s": 0.1, "children": [{"name": "feed_apply", "dur_s": 0.05}]},
+            {"name": "fetch_decode", "dur_s": 0.3},
+        ]},
+        {"name": "feed_apply", "dur_s": 0.05},
+    ],
+}
+SYNC = {"name": "sidecar_sync", "dur_s": 0.4, "children": [{"name": "feed_apply", "dur_s": 0.3}]}
+CYCLES = [
+    {"spans": [SYNC, TREE], "uploads": 30, "kernel_iters": 1000, "traced": True, "wall_s": 1.5},
+    {"spans": [SYNC, TREE], "uploads": 34, "kernel_iters": 1010, "traced": False, "wall_s": 1.7},
+    {"spans": [], "uploads": 32, "kernel_iters": 1005, "traced": False, "wall_s": 1.6},
+]
+TRACE = {"kernel_device_s_total": 0.25, "device_kind": "TPU v5 lite", "device_idle_share_pct": 55.0}
+CTX = {
+    "cycles": CYCLES, "trace": TRACE, "run": {"memory_peak_bytes": 123},
+    "shapes": {"nodes": 50_000, "queues": 64, "resources": 2},
+}
+
+
+@pytest.mark.parametrize(
+    "spec,want",
+    [
+        ({"kind": "span_sum", "spans": ["assemble"]}, 0.2),
+        # a nested match counts once; both roots are searched
+        ({"kind": "span_sum", "spans": ["feed_apply"]}, 0.3 + 0.1 + 0.05),
+        ({"kind": "span_sum", "spans": ["feed_apply"], "root": "sidecar_round"}, 0.15),
+        ({"kind": "span_sum", "spans": ["nothing_by_this_name"]}, 0.0),
+        ({"kind": "cycle_field", "field": "uploads", "reduce": "median"}, 32),
+        ({"kind": "cycle_field", "field": "uploads", "reduce": "sum"}, 96),
+        ({"kind": "cycle_field", "field": "kernel_iters", "reduce": "sum", "cycles": "traced"}, 1000),
+        ({"kind": "cycle_field", "field": "absent"}, None),
+        ({"kind": "trace_field", "field": "device_idle_share_pct"}, 55.0),
+        ({"kind": "run_field", "field": "memory_peak_bytes"}, 123),
+        (
+            {"kind": "ratio", "scale": 1e6,
+             "num": {"kind": "trace_field", "field": "kernel_device_s_total"},
+             "den": {"kind": "cycle_field", "field": "kernel_iters", "reduce": "sum", "cycles": "traced"}},
+            250.0,
+        ),
+    ],
+)
+def test_reader(spec, want):
+    got = layers.read(spec, CTX)
+    assert got == pytest.approx(want) if want is not None else got is None
+
+
+def test_no_trace_no_device_metric():
+    ctx = dict(CTX, trace={})
+    assert layers.read({"kind": "trace_field", "field": "busy_s"}, ctx) is None
+    roof = {"kind": "roofline", "bytes": "round_trip_bytes",
+            "seconds": {"kind": "trace_field", "field": "kernel_device_s_total"},
+            "trips": {"kind": "cycle_field", "field": "kernel_iters", "reduce": "sum", "cycles": "traced"}}
+    assert layers.read(roof, ctx) is None
+    share = layers.read(roof, CTX)
+    # 1,000 trips x (50,000 nodes x 9 B + 64 queues x 8 B + 16 B) over 819 GB/s, of 0.25 s
+    assert share == pytest.approx(100 * 1000 * (450_000 + 512 + 16) / 819e9 / 0.25)
+    assert 0 < share < 100
+
+
+def test_unknown_device_kind_is_an_error():
+    assert roofline.peak("TPU v5 lite", "hbm_bytes_per_s") == 819e9
+    with pytest.raises(KeyError, match="not in perfbench/peaks.json"):
+        roofline.peak("TPU v9 imaginary", "hbm_bytes_per_s")
+
+
+def test_percentile():
+    xs = [1.0, 2.0, 3.0, 4.0, 5.0]
+    assert layers.percentile(xs, 50) == 3.0
+    assert layers.percentile(xs, 75) == 4.0
+    assert layers.percentile(xs, 100) == 5.0
+
+
+def test_cell_mix_and_span_sum_metric_added_as_files_only(tmp_path):
+    bench = make_tiny(tmp_path)
+    # a span-sum metric over a span no shipped metric reads: one more file, one more entry
+    doc = json.load(open(bench))
+    new = {"name": "submit_many_s", "unit": "s", "better": "lower", "source": "program_span",
+           "layer": "mirror and feed", "moves": "cycle_p50_s"}
+    doc["per_layer"].append(new)
+    json.dump(doc, open(bench, "w"))
+    reader = dict(new, read={"kind": "span_sum", "spans": ["fetch_decode"], "reduce": "median"})
+    with open(os.path.join(tmp_path, "perfbench", "layers", "submit_many_s.json"), "w") as f:
+        json.dump(reader, f)
+    cell = Cell(bench, "tiny.steady-40")
+    assert cell.config["name"] == "tiny" and cell.traffic["cap"] == 40
+    assert cell.scheduling()["maximumSchedulingBurst"] == 40
+    found = {m["name"]: r for m, r in cell.per_layer()}
+    assert "downloads_per_cycle" in found and "submit_many_s" in found
+    assert layers.read(found["submit_many_s"]["read"], CTX) == pytest.approx(0.3)
+    with pytest.raises(CellError):
+        Cell(bench, "no.such-cell")
