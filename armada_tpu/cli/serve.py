@@ -79,6 +79,10 @@ class ControlPlaneProcess:
     # This plane's round-verification arming token (models/verify.py
     # arm_default); disarmed on stop() like the explain token above.
     _verify_token: Optional[int] = None
+    # This plane's arming of the cycle recorder's garbage-collection hook
+    # (ops/trace.py arm_gc): collections inside a cycle become gc_collect
+    # spans while a plane runs; disarmed on stop() like the tokens above.
+    _gc_token: Optional[int] = None
     _stopped: bool = False
 
     def stop(self, grace_s: float = 1.0) -> None:
@@ -104,6 +108,10 @@ class ControlPlaneProcess:
             from armada_tpu.models import verify as _verify
 
             _verify.disarm_default(self._verify_token)
+        if self._gc_token is not None:
+            from armada_tpu.ops.trace import disarm_gc
+
+            disarm_gc(self._gc_token)
         if self.replicator is not None:
             self.replicator.stop()
         for p in self._pipelines:
@@ -808,6 +816,9 @@ def start_control_plane(
     _verify_token = _verify.arm_default(
         True if verify_rounds is None else bool(verify_rounds)
     )
+    from armada_tpu.ops.trace import arm_gc
+
+    _gc_token = arm_gc()
 
     return ControlPlaneProcess(
         port=bound_port,
@@ -835,6 +846,7 @@ def start_control_plane(
         _watchdog_token=_watchdog_token,
         _explain_token=_explain_token,
         _verify_token=_verify_token,
+        _gc_token=_gc_token,
     )
 
 

@@ -28,6 +28,7 @@ from armada_tpu.analysis.tsan import make_lock
 from armada_tpu.core.config import SchedulingConfig
 from armada_tpu.core.ordering import scheduling_order_key
 from armada_tpu.jobdb.job import Job, JobRun
+from armada_tpu.ops.trace import recorder as _trace
 
 
 def _order_key(config: SchedulingConfig) -> Callable[[Job], tuple]:
@@ -114,7 +115,9 @@ class JobDb:
         reaches this point nothing can fail mid-mutation, and re-validating
         a 1k-upsert batch would just re-pay a third of the commit's cost.
         """
-        with self._state:
+        with _trace().span(
+            "mirror_index", upserts=len(upserts), deletes=len(deletes)
+        ), self._state:
             for job_id in deletes:
                 old = self._jobs.pop(job_id, None)
                 if old is not None:

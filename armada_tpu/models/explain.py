@@ -195,7 +195,7 @@ def _kernel():
 
         _KERNEL = functools.partial(
             jax.jit, static_argnames=("kcap", "fcap", "num_reasons")
-        )(_explain_kernel_impl)
+        )(jax.named_scope("armada.explain")(_explain_kernel_impl))
     return _KERNEL
 
 

@@ -407,9 +407,13 @@ class _SortedTable:
             return
         sn = self.sorted_n
         self._live_cache = None
-        for col in self._mat_cols():
-            merged = np.insert(col[:sn], self.ov_pos, col[sn : self.n], axis=0)
-            col[: self.n] = merged
+        # O(table), on the cycles where the overlay outgrew its share
+        with _trace().span("table_merge", rows=int(self.n), overlay=int(k)):
+            for col in self._mat_cols():
+                merged = np.insert(
+                    col[:sn], self.ov_pos, col[sn : self.n], axis=0
+                )
+                col[: self.n] = merged
         self.copied_rows += self.n
         self.sorted_n = self.n
         self.ov_pos = np.zeros((0,), np.int64)
@@ -513,12 +517,14 @@ class _SortedTable:
         self._merge_overlay()
         keep = self.alive[: self.n]
         kept = int(keep.sum())
-        for c in self._cols():
-            cur = getattr(self, c)
-            setattr(self, c, cur[: self.n][keep])
-        self.req = self.req[: self.n][keep]
-        if self.atoms is not None:
-            self.atoms = self.atoms[: self.n][keep]
+        # O(table), on the cycles where tombstones passed a quarter of it
+        with _trace().span("table_compact", rows=int(self.n), kept=kept):
+            for c in self._cols():
+                cur = getattr(self, c)
+                setattr(self, c, cur[: self.n][keep])
+            self.req = self.req[: self.n][keep]
+            if self.atoms is not None:
+                self.atoms = self.atoms[: self.n][keep]
         self.copied_rows += kept
         self.n = self.sorted_n = self.cap = kept
         self.dead = 0
@@ -982,7 +988,11 @@ class IncrementalBuilder:
         copies, so mutation-free cycles pay nothing and the copy otherwise
         runs in the overlapped decode shadow, not the assemble path)."""
         if self._g_ids_shared:
-            self._g_ids = self._g_ids.copy()
+            # a span only when the copy happens (57 MB at 1M slots): its
+            # caller is whichever mutation came first after the round took
+            # the snapshot -- remove_many, submit_many or a single release
+            with _trace().span("g_ids_copy", bytes=int(self._g_ids.nbytes)):
+                self._g_ids = self._g_ids.copy()
             self._g_ids_shared = False
 
     def _share_g_ids(self) -> np.ndarray:
@@ -1154,7 +1164,9 @@ class IncrementalBuilder:
         qis, pcs, reqs = [], [], []
         own_gids = False
         gw = self._g_ids.shape[0]
-        for info in self.jobs.remove_many(enc):
+        with _trace().span("table_remove", n=len(enc)):
+            infos = self.jobs.remove_many(enc)
+        for info in infos:
             if info is None:
                 continue
             slot = int(info["slot"])
@@ -2134,639 +2146,645 @@ class IncrementalBuilder:
         outcome equivalence and scatter==materialize bit-equality."""
         from armada_tpu.models.slab import DeltaBundle
 
-        if self._retype_needed:
-            self._retype_nodes()
-        cfg = self.config
-        R = self.R
-        qbucket = min(cfg.shape_bucket, 256)
-        nbucket = _node_bucket(cfg.shape_bucket)
-        Qreal = len(self.queue_names)
-        Nreal = len(self.node_ids)
-        N = _pad(Nreal, nbucket)
-        nc = self._node_cache
-        if nc is None or nc["key"] != (self._node_epoch, N):
-            nc = self._build_node_tensors(N, Nreal)
-            self._node_cache = nc
+        # Three child spans cover the body: the candidate order, the dirty
+        # rows with the order splice, and the bundle.
+        trace = _trace()
+        with trace.span("assemble_order"):
+            if self._retype_needed:
+                self._retype_nodes()
+            cfg = self.config
+            R = self.R
+            qbucket = min(cfg.shape_bucket, 256)
+            nbucket = _node_bucket(cfg.shape_bucket)
+            Qreal = len(self.queue_names)
+            Nreal = len(self.node_ids)
+            N = _pad(Nreal, nbucket)
+            nc = self._node_cache
+            if nc is None or nc["key"] != (self._node_epoch, N):
+                nc = self._build_node_tensors(N, Nreal)
+                self._node_cache = nc
 
-        jt, rt = self.jobs, self.runs
-        sg, rr = self._sg, self._rr
+            jt, rt = self.jobs, self.runs
+            sg, rr = self._sg, self._rr
 
-        prices = self._prices()  # market: per-cycle (queue, band) bid table
-        if prices is not None and (
-            self._last_prices is None
-            or self._last_prices.shape != prices.shape
-            or not np.array_equal(self._last_prices, prices)
-        ):
-            self._price_epoch += 1
-            self._last_prices = prices
-
-        # --- singles: live rows, (queue, order-key) table order ---------------
-        rows = jt.live_rows()
-        mask_known = np.ones(rows.shape[0], bool)
-        if Qreal and not self.queue_known.all():
-            mask_known = self.queue_known[jt.qi[rows]]
-        rows_known = rows[mask_known]
-        idx_known = np.flatnonzero(mask_known)
-        if prices is not None:
-            perm = self._market_perm(jt, rows_known, prices)
-            rows_known = rows_known[perm]
-            idx_known = idx_known[perm]
-        sq = jt.qi[rows_known].astype(np.int64)
-        counts_s = np.bincount(sq, minlength=Qreal)
-        starts_s = np.zeros((max(1, Qreal),), np.int64)
-        if Qreal:
-            starts_s[1:Qreal] = np.cumsum(counts_s)[:-1]
-        rank_s = np.arange(rows_known.shape[0], dtype=np.int64) - starts_s[sq]
-
-        # --- units merged into the per-queue order (same as assemble()) -------
-        units, unit_members, unit_ubans = self._gang_units(prices)
-        if units:
-            unit_qi = np.array([u["qi"] for u in units], np.int64)
-            unit_vrank = np.array([u["rank"] for u in units], np.int64)
-            shift = np.zeros(rows_known.shape[0], np.int64)
-            units_before = np.zeros(len(units), np.int64)
-            for q in np.unique(unit_qi):
-                in_q = np.flatnonzero(unit_qi == q)
-                order_q = in_q[np.argsort(unit_vrank[in_q], kind="stable")]
-                units_before[order_q] = np.arange(in_q.shape[0])
-                ur = np.sort(unit_vrank[in_q])
-                sel = sq == q
-                shift[sel] = np.searchsorted(ur, rank_s[sel], "right")
-            merged_rank_s = rank_s + shift
-            merged_rank_u = unit_vrank + units_before
-        else:
-            merged_rank_s = rank_s
-            merged_rank_u = np.zeros((0,), np.int64)
-
-        L = cfg.max_queue_lookback
-        keep_s = merged_rank_s < L
-        rows_kept = rows_known[keep_s]
-        sq_kept = sq[keep_s]
-        merged_rank_kept = merged_rank_s[keep_s]
-        kept_units: list[tuple] = []
-        if units:
-            cut_tags = {
-                units[i]["tag"]
-                for i in range(len(units))
-                if units[i]["tag"] and merged_rank_u[i] >= L
-            }
-            for i, u in enumerate(units):
-                if merged_rank_u[i] >= L or (u["tag"] and u["tag"] in cut_tags):
-                    continue
-                kept_units.append((u, merged_rank_u[i], unit_members[i], unit_ubans[i]))
-
-        # --- singles participation flips -> slab validity + demand ------------
-        slots_live = jt.slot[rows].astype(np.int64)
-        valid_flags = np.zeros(rows.shape[0], bool)
-        valid_flags[idx_known[keep_s]] = True
-        cur_valid = sg.valid[slots_live]
-        flip_on = slots_live[valid_flags & ~cur_valid]
-        flip_off = slots_live[~valid_flags & cur_valid]
-        for flips, sign in ((flip_on, 1.0), (flip_off, -1.0)):
-            if flips.size:
-                np.add.at(
-                    self._demand_sg,
-                    (sg.queue[flips].astype(np.int64), sg.pc[flips].astype(np.int64)),
-                    sign * sg.req[flips].astype(np.float64),
-                )
-        sg.set_valid(flip_on, True)
-        sg.set_valid(flip_off, False)
-
-        # --- runs participation flips (queue/node filters) --------------------
-        run_rows = rt.live_rows()
-        rvalid = np.ones(run_rows.shape[0], bool)
-        if Qreal and not self.queue_known.all():
-            rvalid &= self.queue_known[rt.qi[run_rows]]
-        if Nreal and not self.node_present.all():
-            rvalid &= self.node_present[rt.node[run_rows]]
-        rslots = rt.slot[run_rows].astype(np.int64)
-        cur_rvalid = rr.valid[rslots]
-        rflip_on = rslots[rvalid & ~cur_rvalid]
-        rflip_off = rslots[~rvalid & cur_rvalid]
-        for flips, sign in ((rflip_on, 1.0), (rflip_off, -1.0)):
-            if flips.size:
-                np.add.at(
-                    self._demand_run,
-                    (rr.queue[flips].astype(np.int64), rr.pc[flips].astype(np.int64)),
-                    sign * rr.req[flips].astype(np.float64),
-                )
-        rr.set_valid(rflip_on, True)
-        rr.set_valid(rflip_off, False)
-
-        # evictee candidates: preemptible valid runs, table order
-        ev_mask = rt.preempt[run_rows] & rvalid
-        ev_rows = run_rows[ev_mask]
-        if prices is not None:
-            ev_rows = ev_rows[self._market_perm(rt, ev_rows, prices)]
-        evq = rt.qi[ev_rows].astype(np.int64)
-
-        # --- region layout -----------------------------------------------------
-        # Zero-size axes break the kernel's gathers (legacy pads to >=1
-        # bucket); grow empty slabs to their first bucket up front.
-        if sg.cap == 0:
-            sg._grow(1)
-        if rr.cap == 0:
-            rr._grow(1)
-        s_cap = sg.cap
-        r_cap = rr.cap
-        u_n = len(kept_units)
-        if u_n > self._u_cap:
-            # geometric like the slabs: u_cap feeds G and the bundle sig, so
-            # every change recompiles the kernel (27s at 1M x 50k on a
-            # v5e, PR 21 chip run) -- gang-heavy bursts must not cross a
-            # pad per cycle
-            self._u_cap = max(_pad(u_n, 64), _pad(int(self._u_cap * 1.5), 64))
-        u_cap = self._u_cap
-        u_base = s_cap + r_cap
-        G = s_cap + r_cap + u_cap
-        if self._g_ids.shape[0] != G:
-            new_ids = np.zeros((G,), _ID_DTYPE)
-            n_keep = min(self._g_ids.shape[0], s_cap)
-            new_ids[:n_keep] = self._g_ids[:n_keep]
-            self._g_ids = new_ids
-
-        # --- units region content (rebuilt wholesale; small) ------------------
-        uc = {
-            "g_req": np.zeros((u_cap, R), np.float32),
-            "g_card": np.zeros((u_cap,), np.int32),
-            "g_level": np.zeros((u_cap,), np.int32),
-            "g_queue": np.zeros((u_cap,), np.int32),
-            "g_key": np.full((u_cap,), -1, np.int32),
-            "g_pc": np.zeros((u_cap,), np.int32),
-            "g_run": np.full((u_cap,), -1, np.int32),
-            "g_valid": np.zeros((u_cap,), bool),
-            "g_absent": np.ones((u_cap,), bool),
-            "g_price": np.zeros((u_cap,), np.float32),
-            "g_spot_price": np.zeros((u_cap,), np.float32),
-            "g_ban_row": np.zeros((u_cap,), np.int32),
-        }
-        ban_rows: list[np.ndarray] = []
-        members_over: dict[int, list] = {}
-        group_of: dict[int, str] = {}
-        demand_u = np.zeros((max(1, Qreal), len(self.pc_names), R), np.float64)
-        for i, (u, _, members, uban) in enumerate(kept_units):
-            uc["g_req"][i] = u["req"]
-            uc["g_card"][i] = u["card"]
-            uc["g_level"][i] = u["level"]
-            uc["g_queue"][i] = u["qi"]
-            uc["g_key"][i] = u["key"]
-            uc["g_pc"][i] = u["pc"]
-            uc["g_valid"][i] = not u["dead"]
-            uc["g_absent"][i] = False
-            uc["g_price"][i] = u["price"]
-            uc["g_spot_price"][i] = u["spot"]
-            members_over[u_base + i] = list(members)
-            if u["tag"]:
-                group_of[u_base + i] = u["tag"]
-            demand_u[u["qi"], u["pc"]] += u["req"].astype(np.float64) * u["card"]
-            bans = set()
-            for jid in members:
-                bans.update(self.banned.get(jid, ()))
-            if not uban and not bans:
-                continue
-            row = np.zeros((N,), bool)
-            for ni in uban or ():
-                row[ni] = True
-            for nid in bans:
-                ni = self.node_index.get(nid)
-                if ni is not None:
-                    row[ni] = True
-            if row.any():
-                ban_rows.append(row)
-                uc["g_ban_row"][i] = len(ban_rows)
-        # monotone + geometric (like the slabs): BR feeds the problem shape,
-        # so per-cycle swings in retry-banned gang counts must not recompile
-        need_br = _pad(len(ban_rows) + 1, 8) if ban_rows else 1
-        if need_br > self._br_cap:
-            self._br_cap = max(need_br, _pad(int(self._br_cap * 1.5), 8))
-        BR = self._br_cap
-        ban_mask = np.zeros((BR, N), bool)
-        for i, row in enumerate(ban_rows):
-            ban_mask[i + 1] = row
-
-        # --- final candidate order: sorted merge on slot ids ------------------
-        key_s = (sq_kept << 32) | merged_rank_kept
-        seq_s = jt.slot[rows_kept].astype(np.int32)
-        if kept_units:
-            key_u = np.array(
-                [(int(u["qi"]) << 32) | int(mr) for (u, mr, _, _) in kept_units],
-                np.int64,
-            )
-            order_u = np.argsort(key_u, kind="stable")
-            key_u = key_u[order_u]
-            seq_u = (u_base + order_u).astype(np.int32)
-            pos = np.searchsorted(key_s, key_u)
-            queued_seq = np.insert(seq_s, pos, seq_u)
-            queued_q = np.insert(
-                sq_kept,
-                pos,
-                np.array([u["qi"] for (u, _, _, _) in kept_units], np.int64)[order_u],
-            )
-        else:
-            queued_seq = seq_s
-            queued_q = sq_kept
-
-        ev_seq = (s_cap + rt.slot[ev_rows].astype(np.int64)).astype(np.int32)
-        pos_e = np.searchsorted(queued_q, evq, "left")
-        gq_real = np.insert(queued_seq, pos_e, ev_seq)
-        gq_q = np.insert(queued_q, pos_e, evq)
-        nreal_candidates = gq_real.shape[0]
-
-        Q = _pad(Qreal, qbucket)
-        q_len64 = np.bincount(gq_q, minlength=Q)
-        q_start = np.zeros((Q,), np.int32)
-        q_start[1:] = np.cumsum(q_len64)[:-1].astype(np.int32)
-        q_len = q_len64.astype(np.int32)
-        gq_gang = np.zeros((G,), np.int32)
-        gq_gang[:nreal_candidates] = gq_real
-
-        # --- demand -> constrained shares (assemble()'s exact math) -----------
-        C = len(self.pc_names)
-        total_pool = nc["total_pool"]
-        total_pool64 = nc["total_pool64"]
-        drf_mult = nc["drf_mult"]
-        pc_queue_cap = nc["pc_queue_cap"]
-        q_weight = np.zeros((Q,), np.float32)
-        q_weight[:Qreal] = self.queue_weight
-        q_cds = np.zeros((Q,), np.float32)
-        q_penalty = np.zeros((Q, R), np.float32)
-        if queue_penalty:
-            for qname, atoms in queue_penalty.items():
-                qi = self.queue_by_name.get(qname)
-                if qi is not None:
-                    q_penalty[qi] = self.factory.ceil_units(atoms).astype(np.float32)
-        q_demand_raw = [0.0] * Qreal
-        if Qreal and R:
-            demand_by_pc = (
-                self._demand_sg[:Qreal] + self._demand_run[:Qreal] + demand_u[:Qreal]
-            )
-            with np.errstate(divide="ignore", invalid="ignore"):
-                denom = np.maximum(total_pool, 1e-9)
-                raw = demand_by_pc.sum(axis=1)
-                capped = np.minimum(demand_by_pc, pc_queue_cap[None]).sum(axis=1)
-                capped = np.minimum(capped, total_pool.astype(np.float64)[None])
-                frac = np.where(total_pool[None] > 0, capped / denom[None], 0.0)
-                rawfrac = np.where(total_pool[None] > 0, raw / denom[None], 0.0)
-            q_cds[:Qreal] = np.maximum(0.0, (frac * drf_mult[None]).max(axis=1))
-            q_demand_raw = [
-                float(v)
-                for v in np.maximum(0.0, (rawfrac * drf_mult[None]).max(axis=1))
-            ]
-
-        # --- burst caps -------------------------------------------------------
-        burst_cfg = cfg.maximum_scheduling_burst or 2**31 - 1
-        if global_tokens is not None:
-            burst_cfg = max(0, min(burst_cfg, int(global_tokens)))
-        perq_cfg = cfg.maximum_per_queue_scheduling_burst or 2**31 - 1
-        perq_burst = np.full((Q,), 2**31 - 1, np.int32)
-        for qname, qi in self.queue_by_name.items():
-            cap = perq_cfg
-            if queue_tokens is not None and qname in queue_tokens:
-                cap = max(0, min(cap, int(queue_tokens[qname])))
-            perq_burst[qi] = min(cap, 2**31 - 1)
-
-        max_card = max((int(u["card"]) for (u, _, _, _) in kept_units), default=1)
-        if max_card > 10_000:
-            raise ValueError(f"gang cardinality {max_card} exceeds the supported 10k")
-        W = max(1, min(max_card, N))
-        S_slots = max(1, min(max(nreal_candidates, 1), burst_cfg))
-
-        # --- dirty extraction -------------------------------------------------
-        # Two views of each dirty log: ALL dirtied slots (the gq splice and
-        # any order accounting must treat a prefetched slot as moved), and
-        # the PAYLOAD suffix -- rows not already shipped mid-cycle by
-        # prefetch_content.  A slot both prefetched and re-dirtied later
-        # appears in the suffix and re-ships (content wins by last write).
-        sg_log = (
-            np.asarray(sg.dirty_log, np.int64)
-            if sg.dirty_log
-            else np.zeros((0,), np.int64)
-        )
-        sg_dirty_all = np.unique(sg_log)
-        sg_dirty = (
-            np.unique(sg_log[self._shipped_sg :])
-            if self._shipped_sg
-            else sg_dirty_all
-        )
-        sg.dirty_log.clear()
-        self._shipped_sg = 0
-        unit_dirty = np.arange(u_base, u_base + max(u_n, self._u_prev_n), dtype=np.int64)
-        self._u_prev_n = u_n
-        sg_idx = np.concatenate([sg_dirty, unit_dirty])
-        rr_log = (
-            np.asarray(rr.dirty_log, np.int64)
-            if rr.dirty_log
-            else np.zeros((0,), np.int64)
-        )
-        rr_dirty_all = np.unique(rr_log)
-        rr_dirty = (
-            np.unique(rr_log[self._shipped_rr :])
-            if self._shipped_rr
-            else rr_dirty_all
-        )
-        rr.dirty_log.clear()
-        self._shipped_rr = 0
-
-        # --- gq splice: rebuild the order vector ON DEVICE from last cycle's
-        # (slab.DeltaBundle.gq_splice) instead of re-uploading 4MB.  Sound
-        # exactly when the SURVIVING candidates' relative order is unchanged
-        # (steady state: departures + arrivals, order carried by the stable
-        # tables); verified against our own previous vector -- the device's
-        # copy matches it whenever the cache takes the delta path
-        # (seq-consecutive + same sig), and any fallback re-uploads whole.
-        # Slots dirtied THIS cycle never count as survivors: a slot released
-        # by a scheduled job and re-allocated to a fresh submit keeps its id
-        # but moves position (remove old + insert new is always sound).
-        gq_splice = None
-        prev_gq, L0 = self._prev_gq, self._prev_gq_real
-        L1 = int(nreal_candidates)
-        if prev_gq is not None and prev_gq.shape[0] == G:
-            dirty_slot = np.zeros((G,), bool)
-            # ALL dirtied slots, prefetched or not: a prefetched slot's
-            # content is on device but its ORDER position may have moved
-            # (release + re-alloc keeps the id), so it must not count as a
-            # splice survivor.
-            dirty_slot[sg_dirty_all[sg_dirty_all < G]] = True
-            dirty_slot[unit_dirty[unit_dirty < G]] = True
-            ev_dirty = s_cap + rr_dirty_all
-            dirty_slot[ev_dirty[ev_dirty < G]] = True  # evictee projection
-            prev_real = prev_gq[:L0]
-            in_new = np.zeros((G,), bool)
-            in_new[gq_real] = True
-            in_prev = np.zeros((G,), bool)
-            in_prev[prev_real] = True
-            surv = in_new & in_prev & ~dirty_slot
-            dep = ~surv[prev_real]  # departed/moved, prev positions
-            arr = ~surv[gq_real]  # arrived/moved, final positions
-            kept_prev = prev_real[~dep]
-            new_minus = gq_real[~arr]
-            if kept_prev.shape[0] == new_minus.shape[0] and np.array_equal(
-                kept_prev, new_minus
+            prices = self._prices()  # market: per-cycle (queue, band) bid table
+            if prices is not None and (
+                self._last_prices is None
+                or self._last_prices.shape != prices.shape
+                or not np.array_equal(self._last_prices, prices)
             ):
-                rem = np.flatnonzero(dep)
-                ins = np.flatnonzero(arr)
-                vals = gq_real[ins]
-                # padded-tail zeros shift with the real-region length
-                if L1 > L0:  # fewer tail zeros: drop from the prev tail
-                    rem = np.concatenate([rem, np.arange(G - (L1 - L0), G)])
-                elif L0 > L1:  # more tail zeros: insert at the final tail
-                    ins = np.concatenate([ins, np.arange(G - (L0 - L1), G)])
-                    vals = np.concatenate(
-                        [vals, np.zeros((L0 - L1,), vals.dtype)]
-                    )
-                # a big splice costs more than the 4MB it saves
-                if rem.shape[0] + ins.shape[0] <= max(4096, G // 8):
-                    gq_splice = (
-                        rem.astype(np.int32),
-                        ins.astype(np.int32),
-                        vals.astype(np.int32),
-                    )
-        # gq_gang is freshly allocated per cycle and never mutated after
-        # this point: keep the reference, no 4MB copy
-        self._prev_gq = gq_gang
-        self._prev_gq_real = L1
+                self._price_epoch += 1
+                self._last_prices = prices
 
-        is_unit = sg_idx >= u_base
-        i_sing = sg_idx[~is_unit]
-        i_unit = sg_idx[is_unit] - u_base
-        k = sg_idx.shape[0]
-
-        def sg_field(name, sing_vals):
-            out = np.zeros((k,) + sing_vals.shape[1:], uc[name].dtype)
-            out[~is_unit] = sing_vals
-            out[is_unit] = uc[name][i_unit]
-            return out
-
-        sc = self._single_content_cols(i_sing, prices)
-        sg_cols = {name: sg_field(name, vals) for name, vals in sc.items()}
-        rr_cols, ev_cols = self._run_content_cols(rr_dirty, s_cap, prices)
-        type_bias, key_type_row, compat_pre_type = self._type_tables()
-
-        fulls = {
-            # omitted when the splice carries the order (a few KB vs 4MB)
-            **({} if gq_splice is not None else {"gq_gang": gq_gang}),
-            "q_start": q_start,
-            "q_len": q_len,
-            "q_weight": self._stable("q_weight", q_weight),
-            "q_cds": q_cds,
-            "q_penalty": self._stable("q_penalty", q_penalty),
-            "compat": self._compat_matrix(),
-            "type_bias": type_bias,
-            "key_type_row": key_type_row,
-            "compat_pre_type": compat_pre_type,
-            "total_pool": total_pool,
-            "drf_mult": drf_mult,
-            "inv_scale": nc["inv_scale"],
-            "round_cap": nc["round_cap"],
-            "pc_queue_cap": pc_queue_cap.astype(np.float32)
-            if pc_queue_cap.dtype != np.float32
-            else pc_queue_cap,
-            "protected_fraction": self._stable(
-                "protected_fraction",
-                np.float32(cfg.protected_fraction_of_fair_share),
-            ),
-            "global_burst": self._stable(
-                "global_burst", np.int32(min(burst_cfg, 2**31 - 1))
-            ),
-            "perq_burst": self._stable("perq_burst", perq_burst),
-            "node_axes": nc["node_axes"],
-            "float_total": nc["float_total"],
-            "market": self._stable("market", np.bool_(self.market)),
-            "spot_cutoff": self._stable("spot_cutoff", np.asarray(self.spot_cutoff)),
-            "ban_mask": self._stable("ban_mask", ban_mask),
-            "node_total": nc["node_total"],
-            "node_type": nc["node_type"],
-            "node_ok": nc["node_ok"],
-        }
-
-        def materialize():
-            """Full host problem equal to what the scatter stream maintains
-            (called on first upload / fallback; also the test oracle).  Must
-            run before further builder mutations."""
+            # --- singles: live rows, (queue, order-key) table order ---------------
+            rows = jt.live_rows()
+            mask_known = np.ones(rows.shape[0], bool)
+            if Qreal and not self.queue_known.all():
+                mask_known = self.queue_known[jt.qi[rows]]
+            rows_known = rows[mask_known]
+            idx_known = np.flatnonzero(mask_known)
             if prices is not None:
-                slot_price = np.concatenate(
-                    [
-                        prices[
-                            sg.queue.astype(np.int64), sg.band.astype(np.int64)
-                        ],
-                        prices[
-                            rr.queue.astype(np.int64), rr.band.astype(np.int64)
-                        ],
-                        uc["g_price"],
-                    ]
+                perm = self._market_perm(jt, rows_known, prices)
+                rows_known = rows_known[perm]
+                idx_known = idx_known[perm]
+            sq = jt.qi[rows_known].astype(np.int64)
+            counts_s = np.bincount(sq, minlength=Qreal)
+            starts_s = np.zeros((max(1, Qreal),), np.int64)
+            if Qreal:
+                starts_s[1:Qreal] = np.cumsum(counts_s)[:-1]
+            rank_s = np.arange(rows_known.shape[0], dtype=np.int64) - starts_s[sq]
+
+            # --- units merged into the per-queue order (same as assemble()) -------
+            units, unit_members, unit_ubans = self._gang_units(prices)
+            if units:
+                unit_qi = np.array([u["qi"] for u in units], np.int64)
+                unit_vrank = np.array([u["rank"] for u in units], np.int64)
+                shift = np.zeros(rows_known.shape[0], np.int64)
+                units_before = np.zeros(len(units), np.int64)
+                for q in np.unique(unit_qi):
+                    in_q = np.flatnonzero(unit_qi == q)
+                    order_q = in_q[np.argsort(unit_vrank[in_q], kind="stable")]
+                    units_before[order_q] = np.arange(in_q.shape[0])
+                    ur = np.sort(unit_vrank[in_q])
+                    sel = sq == q
+                    shift[sel] = np.searchsorted(ur, rank_s[sel], "right")
+                merged_rank_s = rank_s + shift
+                merged_rank_u = unit_vrank + units_before
+            else:
+                merged_rank_s = rank_s
+                merged_rank_u = np.zeros((0,), np.int64)
+
+            L = cfg.max_queue_lookback
+            keep_s = merged_rank_s < L
+            rows_kept = rows_known[keep_s]
+            sq_kept = sq[keep_s]
+            merged_rank_kept = merged_rank_s[keep_s]
+            kept_units: list[tuple] = []
+            if units:
+                cut_tags = {
+                    units[i]["tag"]
+                    for i in range(len(units))
+                    if units[i]["tag"] and merged_rank_u[i] >= L
+                }
+                for i, u in enumerate(units):
+                    if merged_rank_u[i] >= L or (u["tag"] and u["tag"] in cut_tags):
+                        continue
+                    kept_units.append((u, merged_rank_u[i], unit_members[i], unit_ubans[i]))
+
+            # --- singles participation flips -> slab validity + demand ------------
+            slots_live = jt.slot[rows].astype(np.int64)
+            valid_flags = np.zeros(rows.shape[0], bool)
+            valid_flags[idx_known[keep_s]] = True
+            cur_valid = sg.valid[slots_live]
+            flip_on = slots_live[valid_flags & ~cur_valid]
+            flip_off = slots_live[~valid_flags & cur_valid]
+            for flips, sign in ((flip_on, 1.0), (flip_off, -1.0)):
+                if flips.size:
+                    np.add.at(
+                        self._demand_sg,
+                        (sg.queue[flips].astype(np.int64), sg.pc[flips].astype(np.int64)),
+                        sign * sg.req[flips].astype(np.float64),
+                    )
+            sg.set_valid(flip_on, True)
+            sg.set_valid(flip_off, False)
+
+            # --- runs participation flips (queue/node filters) --------------------
+            run_rows = rt.live_rows()
+            rvalid = np.ones(run_rows.shape[0], bool)
+            if Qreal and not self.queue_known.all():
+                rvalid &= self.queue_known[rt.qi[run_rows]]
+            if Nreal and not self.node_present.all():
+                rvalid &= self.node_present[rt.node[run_rows]]
+            rslots = rt.slot[run_rows].astype(np.int64)
+            cur_rvalid = rr.valid[rslots]
+            rflip_on = rslots[rvalid & ~cur_rvalid]
+            rflip_off = rslots[~rvalid & cur_rvalid]
+            for flips, sign in ((rflip_on, 1.0), (rflip_off, -1.0)):
+                if flips.size:
+                    np.add.at(
+                        self._demand_run,
+                        (rr.queue[flips].astype(np.int64), rr.pc[flips].astype(np.int64)),
+                        sign * rr.req[flips].astype(np.float64),
+                    )
+            rr.set_valid(rflip_on, True)
+            rr.set_valid(rflip_off, False)
+
+            # evictee candidates: preemptible valid runs, table order
+            ev_mask = rt.preempt[run_rows] & rvalid
+            ev_rows = run_rows[ev_mask]
+            if prices is not None:
+                ev_rows = ev_rows[self._market_perm(rt, ev_rows, prices)]
+            evq = rt.qi[ev_rows].astype(np.int64)
+
+            # --- region layout -----------------------------------------------------
+            # Zero-size axes break the kernel's gathers (legacy pads to >=1
+            # bucket); grow empty slabs to their first bucket up front.
+            if sg.cap == 0:
+                sg._grow(1)
+            if rr.cap == 0:
+                rr._grow(1)
+            s_cap = sg.cap
+            r_cap = rr.cap
+            u_n = len(kept_units)
+            if u_n > self._u_cap:
+                # geometric like the slabs: u_cap feeds G and the bundle sig, so
+                # every change recompiles the kernel (27s at 1M x 50k on a
+                # v5e, PR 21 chip run) -- gang-heavy bursts must not cross a
+                # pad per cycle
+                self._u_cap = max(_pad(u_n, 64), _pad(int(self._u_cap * 1.5), 64))
+            u_cap = self._u_cap
+            u_base = s_cap + r_cap
+            G = s_cap + r_cap + u_cap
+            if self._g_ids.shape[0] != G:
+                new_ids = np.zeros((G,), _ID_DTYPE)
+                n_keep = min(self._g_ids.shape[0], s_cap)
+                new_ids[:n_keep] = self._g_ids[:n_keep]
+                self._g_ids = new_ids
+
+            # --- units region content (rebuilt wholesale; small) ------------------
+            uc = {
+                "g_req": np.zeros((u_cap, R), np.float32),
+                "g_card": np.zeros((u_cap,), np.int32),
+                "g_level": np.zeros((u_cap,), np.int32),
+                "g_queue": np.zeros((u_cap,), np.int32),
+                "g_key": np.full((u_cap,), -1, np.int32),
+                "g_pc": np.zeros((u_cap,), np.int32),
+                "g_run": np.full((u_cap,), -1, np.int32),
+                "g_valid": np.zeros((u_cap,), bool),
+                "g_absent": np.ones((u_cap,), bool),
+                "g_price": np.zeros((u_cap,), np.float32),
+                "g_spot_price": np.zeros((u_cap,), np.float32),
+                "g_ban_row": np.zeros((u_cap,), np.int32),
+            }
+            ban_rows: list[np.ndarray] = []
+            members_over: dict[int, list] = {}
+            group_of: dict[int, str] = {}
+            demand_u = np.zeros((max(1, Qreal), len(self.pc_names), R), np.float64)
+            for i, (u, _, members, uban) in enumerate(kept_units):
+                uc["g_req"][i] = u["req"]
+                uc["g_card"][i] = u["card"]
+                uc["g_level"][i] = u["level"]
+                uc["g_queue"][i] = u["qi"]
+                uc["g_key"][i] = u["key"]
+                uc["g_pc"][i] = u["pc"]
+                uc["g_valid"][i] = not u["dead"]
+                uc["g_absent"][i] = False
+                uc["g_price"][i] = u["price"]
+                uc["g_spot_price"][i] = u["spot"]
+                members_over[u_base + i] = list(members)
+                if u["tag"]:
+                    group_of[u_base + i] = u["tag"]
+                demand_u[u["qi"], u["pc"]] += u["req"].astype(np.float64) * u["card"]
+                bans = set()
+                for jid in members:
+                    bans.update(self.banned.get(jid, ()))
+                if not uban and not bans:
+                    continue
+                row = np.zeros((N,), bool)
+                for ni in uban or ():
+                    row[ni] = True
+                for nid in bans:
+                    ni = self.node_index.get(nid)
+                    if ni is not None:
+                        row[ni] = True
+                if row.any():
+                    ban_rows.append(row)
+                    uc["g_ban_row"][i] = len(ban_rows)
+            # monotone + geometric (like the slabs): BR feeds the problem shape,
+            # so per-cycle swings in retry-banned gang counts must not recompile
+            need_br = _pad(len(ban_rows) + 1, 8) if ban_rows else 1
+            if need_br > self._br_cap:
+                self._br_cap = max(need_br, _pad(int(self._br_cap * 1.5), 8))
+            BR = self._br_cap
+            ban_mask = np.zeros((BR, N), bool)
+            for i, row in enumerate(ban_rows):
+                ban_mask[i + 1] = row
+
+            # --- final candidate order: sorted merge on slot ids ------------------
+            key_s = (sq_kept << 32) | merged_rank_kept
+            seq_s = jt.slot[rows_kept].astype(np.int32)
+            if kept_units:
+                key_u = np.array(
+                    [(int(u["qi"]) << 32) | int(mr) for (u, mr, _, _) in kept_units],
+                    np.int64,
                 )
-                slot_spot = np.concatenate(
-                    [
-                        slot_price[: s_cap + r_cap],
-                        uc["g_spot_price"],
-                    ]
+                order_u = np.argsort(key_u, kind="stable")
+                key_u = key_u[order_u]
+                seq_u = (u_base + order_u).astype(np.int32)
+                pos = np.searchsorted(key_s, key_u)
+                queued_seq = np.insert(seq_s, pos, seq_u)
+                queued_q = np.insert(
+                    sq_kept,
+                    pos,
+                    np.array([u["qi"] for (u, _, _, _) in kept_units], np.int64)[order_u],
                 )
             else:
-                slot_price = np.zeros((G,), np.float32)
-                slot_spot = slot_price
-            g_valid_full = np.concatenate(
-                [sg.valid, rr.valid & rr.preempt, uc["g_valid"]]
-            )
-            g_absent_full = np.concatenate(
-                [~sg.valid, ~(rr.valid & rr.preempt), uc["g_absent"]]
-            )
-            run_gang_full = np.where(
-                rr.valid & rr.preempt,
-                (s_cap + np.arange(r_cap)).astype(np.int32),
-                np.int32(-1),
-            )
-            return SchedulingProblem(
-                node_total=nc["node_total"],
-                node_type=nc["node_type"],
-                node_ok=nc["node_ok"],
-                run_req=rr.req.copy(),
-                run_node=rr.node.copy(),
-                run_level=rr.level.copy(),
-                run_queue=rr.queue.copy(),
-                run_pc=rr.pc.copy(),
-                run_preemptible=rr.preempt.copy(),
-                run_gang=run_gang_full,
-                run_valid=rr.valid.copy(),
-                g_req=np.concatenate([sg.req, rr.req, uc["g_req"]]),
-                g_card=np.concatenate(
-                    [
-                        np.ones((s_cap,), np.int32),
-                        np.ones((r_cap,), np.int32),
-                        uc["g_card"],
-                    ]
-                ),
-                g_level=np.concatenate([sg.level, rr.level, uc["g_level"]]),
-                g_queue=np.concatenate([sg.queue, rr.queue, uc["g_queue"]]),
-                g_key=np.concatenate(
-                    [sg.key, np.full((r_cap,), -1, np.int32), uc["g_key"]]
-                ),
-                g_pc=np.concatenate([sg.pc, rr.pc, uc["g_pc"]]),
-                g_order=np.zeros((G,), np.int32),
-                g_run=np.concatenate(
-                    [
-                        np.full((s_cap,), -1, np.int32),
-                        np.arange(r_cap, dtype=np.int32),
-                        uc["g_run"],
-                    ]
-                ),
-                g_valid=g_valid_full,
-                g_absent=g_absent_full,
-                g_price=slot_price,
-                g_spot_price=slot_spot,
-                gq_gang=gq_gang,
-                q_start=q_start,
-                q_len=q_len,
-                q_weight=fulls["q_weight"],
-                q_cds=q_cds,
-                q_penalty=fulls["q_penalty"],
-                compat=fulls["compat"],
-                total_pool=total_pool,
-                drf_mult=drf_mult,
-                inv_scale=nc["inv_scale"],
-                round_cap=nc["round_cap"],
-                pc_queue_cap=fulls["pc_queue_cap"],
-                protected_fraction=fulls["protected_fraction"],
-                global_burst=fulls["global_burst"],
-                perq_burst=fulls["perq_burst"],
-                node_axes=nc["node_axes"],
-                float_total=nc["float_total"],
-                market=fulls["market"],
-                spot_cutoff=fulls["spot_cutoff"],
-                ban_mask=fulls["ban_mask"],
-                g_ban_row=np.concatenate(
-                    [
-                        np.zeros((s_cap,), np.int32),
-                        np.zeros((r_cap,), np.int32),
-                        uc["g_ban_row"],
-                    ]
-                ),
-                type_bias=fulls["type_bias"],
-                key_type_row=fulls["key_type_row"],
-                compat_pre_type=fulls["compat_pre_type"],
-            )
+                queued_seq = seq_s
+                queued_q = sq_kept
 
-        sig = (
-            G,
-            r_cap,
-            N,
-            Q,
-            sg.epoch,
-            rr.epoch,
-            u_cap,
-            self._node_epoch,
-            # market: a price move re-prices every slot at once; ride the
-            # full-upload fallback instead of dirtying the whole slab
-            self._price_epoch,
-        )
-        seq = self._bundle_seq
-        self._bundle_seq += 1
-        self._last_sig = sig
-        bundle = DeltaBundle(
-            sig=sig,
-            seq=seq,
-            materialize=materialize,
-            ev_base=s_cap,
-            sg_idx=sg_idx,
-            sg_cols=sg_cols,
-            rr_idx=rr_dirty,
-            rr_cols=rr_cols,
-            ev_cols=ev_cols,
-            fulls=fulls,
-            gq_splice=gq_splice,
-        )
+            ev_seq = (s_cap + rt.slot[ev_rows].astype(np.int64)).astype(np.int32)
+            pos_e = np.searchsorted(queued_q, evq, "left")
+            gq_real = np.insert(queued_seq, pos_e, ev_seq)
+            gq_q = np.insert(queued_q, pos_e, evq)
+            nreal_candidates = gq_real.shape[0]
 
-        class _SparseGroups:
-            __slots__ = ("_d",)
+            Q = _pad(Qreal, qbucket)
+            q_len64 = np.bincount(gq_q, minlength=Q)
+            q_start = np.zeros((Q,), np.int32)
+            q_start[1:] = np.cumsum(q_len64)[:-1].astype(np.int32)
+            q_len = q_len64.astype(np.int32)
+            gq_gang = np.zeros((G,), np.int32)
+            gq_gang[:nreal_candidates] = gq_real
 
-            def __init__(self, d):
-                self._d = d
-
-            def __getitem__(self, i):
-                return self._d.get(i, "")
-
-        ctx = HostContext(
-            config=cfg,
-            pool=self.pool,
-            queue_names=list(self.queue_names),
-            node_ids=list(self.node_ids),
-            gang_members=None,
-            gang_group=_SparseGroups(group_of),
-            run_job_ids=None,
-            num_real_nodes=Nreal,
-            num_real_queues=Qreal,
-            num_real_gangs=G,
-            num_real_runs=r_cap,
-            ladder=self.ladder,
-            pc_names=list(self.pc_names),
-            max_slots=S_slots,
-            slot_width=W,
-            type_names=[nt.hw_type for nt in self.ntidx.types],
-            q_demand_raw=q_demand_raw,
-            pool_total_atoms={
-                name: int(round(float(total_pool64[i]) * self.factory.resolutions[i]))
-                for i, name in enumerate(self.factory.names)
-                if total_pool64[i]
-            },
-            # Copy-on-write snapshots: a mutation landing between assemble
-            # and decode (slot reuse after remove) must not corrupt decode's
-            # ids, but eagerly copying [G] ids cost ~30ms of every assemble;
-            # now the first post-assemble id write copies instead.
-            gang_ids_vec=self._share_g_ids(),
-            gang_members_over=members_over,
-            run_ids_vec=rr.share_ids(),
-            # slab run axis IS the slot axis; lazy like the dense path (the
-            # mapping reads slot-stable state, and the production flow
-            # materializes within the decode window, before apply_outcome
-            # mutates the tables)
-            running_gangs=lambda: self._running_gang_ctx_groups(
-                lambda row: (
-                    int(s)
-                    if rr.valid[(s := int(self.runs.slot[row]))]
-                    else None
+            # --- demand -> constrained shares (assemble()'s exact math) -----------
+            C = len(self.pc_names)
+            total_pool = nc["total_pool"]
+            total_pool64 = nc["total_pool64"]
+            drf_mult = nc["drf_mult"]
+            pc_queue_cap = nc["pc_queue_cap"]
+            q_weight = np.zeros((Q,), np.float32)
+            q_weight[:Qreal] = self.queue_weight
+            q_cds = np.zeros((Q,), np.float32)
+            q_penalty = np.zeros((Q, R), np.float32)
+            if queue_penalty:
+                for qname, atoms in queue_penalty.items():
+                    qi = self.queue_by_name.get(qname)
+                    if qi is not None:
+                        q_penalty[qi] = self.factory.ceil_units(atoms).astype(np.float32)
+            q_demand_raw = [0.0] * Qreal
+            if Qreal and R:
+                demand_by_pc = (
+                    self._demand_sg[:Qreal] + self._demand_run[:Qreal] + demand_u[:Qreal]
                 )
-            ),
-        )
-        return bundle, ctx
+                with np.errstate(divide="ignore", invalid="ignore"):
+                    denom = np.maximum(total_pool, 1e-9)
+                    raw = demand_by_pc.sum(axis=1)
+                    capped = np.minimum(demand_by_pc, pc_queue_cap[None]).sum(axis=1)
+                    capped = np.minimum(capped, total_pool.astype(np.float64)[None])
+                    frac = np.where(total_pool[None] > 0, capped / denom[None], 0.0)
+                    rawfrac = np.where(total_pool[None] > 0, raw / denom[None], 0.0)
+                q_cds[:Qreal] = np.maximum(0.0, (frac * drf_mult[None]).max(axis=1))
+                q_demand_raw = [
+                    float(v)
+                    for v in np.maximum(0.0, (rawfrac * drf_mult[None]).max(axis=1))
+                ]
+
+            # --- burst caps -------------------------------------------------------
+            burst_cfg = cfg.maximum_scheduling_burst or 2**31 - 1
+            if global_tokens is not None:
+                burst_cfg = max(0, min(burst_cfg, int(global_tokens)))
+            perq_cfg = cfg.maximum_per_queue_scheduling_burst or 2**31 - 1
+            perq_burst = np.full((Q,), 2**31 - 1, np.int32)
+            for qname, qi in self.queue_by_name.items():
+                cap = perq_cfg
+                if queue_tokens is not None and qname in queue_tokens:
+                    cap = max(0, min(cap, int(queue_tokens[qname])))
+                perq_burst[qi] = min(cap, 2**31 - 1)
+
+            max_card = max((int(u["card"]) for (u, _, _, _) in kept_units), default=1)
+            if max_card > 10_000:
+                raise ValueError(f"gang cardinality {max_card} exceeds the supported 10k")
+            W = max(1, min(max_card, N))
+            S_slots = max(1, min(max(nreal_candidates, 1), burst_cfg))
+
+        # --- dirty extraction -------------------------------------------------
+        with trace.span("assemble_splice"):
+            # Two views of each dirty log: ALL dirtied slots (the gq splice and
+            # any order accounting must treat a prefetched slot as moved), and
+            # the PAYLOAD suffix -- rows not already shipped mid-cycle by
+            # prefetch_content.  A slot both prefetched and re-dirtied later
+            # appears in the suffix and re-ships (content wins by last write).
+            sg_log = (
+                np.asarray(sg.dirty_log, np.int64)
+                if sg.dirty_log
+                else np.zeros((0,), np.int64)
+            )
+            sg_dirty_all = np.unique(sg_log)
+            sg_dirty = (
+                np.unique(sg_log[self._shipped_sg :])
+                if self._shipped_sg
+                else sg_dirty_all
+            )
+            sg.dirty_log.clear()
+            self._shipped_sg = 0
+            unit_dirty = np.arange(u_base, u_base + max(u_n, self._u_prev_n), dtype=np.int64)
+            self._u_prev_n = u_n
+            sg_idx = np.concatenate([sg_dirty, unit_dirty])
+            rr_log = (
+                np.asarray(rr.dirty_log, np.int64)
+                if rr.dirty_log
+                else np.zeros((0,), np.int64)
+            )
+            rr_dirty_all = np.unique(rr_log)
+            rr_dirty = (
+                np.unique(rr_log[self._shipped_rr :])
+                if self._shipped_rr
+                else rr_dirty_all
+            )
+            rr.dirty_log.clear()
+            self._shipped_rr = 0
+
+            # --- gq splice: rebuild the order vector ON DEVICE from last cycle's
+            # (slab.DeltaBundle.gq_splice) instead of re-uploading 4MB.  Sound
+            # exactly when the SURVIVING candidates' relative order is unchanged
+            # (steady state: departures + arrivals, order carried by the stable
+            # tables); verified against our own previous vector -- the device's
+            # copy matches it whenever the cache takes the delta path
+            # (seq-consecutive + same sig), and any fallback re-uploads whole.
+            # Slots dirtied THIS cycle never count as survivors: a slot released
+            # by a scheduled job and re-allocated to a fresh submit keeps its id
+            # but moves position (remove old + insert new is always sound).
+            gq_splice = None
+            prev_gq, L0 = self._prev_gq, self._prev_gq_real
+            L1 = int(nreal_candidates)
+            if prev_gq is not None and prev_gq.shape[0] == G:
+                dirty_slot = np.zeros((G,), bool)
+                # ALL dirtied slots, prefetched or not: a prefetched slot's
+                # content is on device but its ORDER position may have moved
+                # (release + re-alloc keeps the id), so it must not count as a
+                # splice survivor.
+                dirty_slot[sg_dirty_all[sg_dirty_all < G]] = True
+                dirty_slot[unit_dirty[unit_dirty < G]] = True
+                ev_dirty = s_cap + rr_dirty_all
+                dirty_slot[ev_dirty[ev_dirty < G]] = True  # evictee projection
+                prev_real = prev_gq[:L0]
+                in_new = np.zeros((G,), bool)
+                in_new[gq_real] = True
+                in_prev = np.zeros((G,), bool)
+                in_prev[prev_real] = True
+                surv = in_new & in_prev & ~dirty_slot
+                dep = ~surv[prev_real]  # departed/moved, prev positions
+                arr = ~surv[gq_real]  # arrived/moved, final positions
+                kept_prev = prev_real[~dep]
+                new_minus = gq_real[~arr]
+                if kept_prev.shape[0] == new_minus.shape[0] and np.array_equal(
+                    kept_prev, new_minus
+                ):
+                    rem = np.flatnonzero(dep)
+                    ins = np.flatnonzero(arr)
+                    vals = gq_real[ins]
+                    # padded-tail zeros shift with the real-region length
+                    if L1 > L0:  # fewer tail zeros: drop from the prev tail
+                        rem = np.concatenate([rem, np.arange(G - (L1 - L0), G)])
+                    elif L0 > L1:  # more tail zeros: insert at the final tail
+                        ins = np.concatenate([ins, np.arange(G - (L0 - L1), G)])
+                        vals = np.concatenate(
+                            [vals, np.zeros((L0 - L1,), vals.dtype)]
+                        )
+                    # a big splice costs more than the 4MB it saves
+                    if rem.shape[0] + ins.shape[0] <= max(4096, G // 8):
+                        gq_splice = (
+                            rem.astype(np.int32),
+                            ins.astype(np.int32),
+                            vals.astype(np.int32),
+                        )
+            # gq_gang is freshly allocated per cycle and never mutated after
+            # this point: keep the reference, no 4MB copy
+            self._prev_gq = gq_gang
+            self._prev_gq_real = L1
+
+        with trace.span("assemble_bundle"):
+            is_unit = sg_idx >= u_base
+            i_sing = sg_idx[~is_unit]
+            i_unit = sg_idx[is_unit] - u_base
+            k = sg_idx.shape[0]
+
+            def sg_field(name, sing_vals):
+                out = np.zeros((k,) + sing_vals.shape[1:], uc[name].dtype)
+                out[~is_unit] = sing_vals
+                out[is_unit] = uc[name][i_unit]
+                return out
+
+            sc = self._single_content_cols(i_sing, prices)
+            sg_cols = {name: sg_field(name, vals) for name, vals in sc.items()}
+            rr_cols, ev_cols = self._run_content_cols(rr_dirty, s_cap, prices)
+            type_bias, key_type_row, compat_pre_type = self._type_tables()
+
+            fulls = {
+                # omitted when the splice carries the order (a few KB vs 4MB)
+                **({} if gq_splice is not None else {"gq_gang": gq_gang}),
+                "q_start": q_start,
+                "q_len": q_len,
+                "q_weight": self._stable("q_weight", q_weight),
+                "q_cds": q_cds,
+                "q_penalty": self._stable("q_penalty", q_penalty),
+                "compat": self._compat_matrix(),
+                "type_bias": type_bias,
+                "key_type_row": key_type_row,
+                "compat_pre_type": compat_pre_type,
+                "total_pool": total_pool,
+                "drf_mult": drf_mult,
+                "inv_scale": nc["inv_scale"],
+                "round_cap": nc["round_cap"],
+                "pc_queue_cap": pc_queue_cap.astype(np.float32)
+                if pc_queue_cap.dtype != np.float32
+                else pc_queue_cap,
+                "protected_fraction": self._stable(
+                    "protected_fraction",
+                    np.float32(cfg.protected_fraction_of_fair_share),
+                ),
+                "global_burst": self._stable(
+                    "global_burst", np.int32(min(burst_cfg, 2**31 - 1))
+                ),
+                "perq_burst": self._stable("perq_burst", perq_burst),
+                "node_axes": nc["node_axes"],
+                "float_total": nc["float_total"],
+                "market": self._stable("market", np.bool_(self.market)),
+                "spot_cutoff": self._stable("spot_cutoff", np.asarray(self.spot_cutoff)),
+                "ban_mask": self._stable("ban_mask", ban_mask),
+                "node_total": nc["node_total"],
+                "node_type": nc["node_type"],
+                "node_ok": nc["node_ok"],
+            }
+
+            def materialize():
+                """Full host problem equal to what the scatter stream maintains
+                (called on first upload / fallback; also the test oracle).  Must
+                run before further builder mutations."""
+                if prices is not None:
+                    slot_price = np.concatenate(
+                        [
+                            prices[
+                                sg.queue.astype(np.int64), sg.band.astype(np.int64)
+                            ],
+                            prices[
+                                rr.queue.astype(np.int64), rr.band.astype(np.int64)
+                            ],
+                            uc["g_price"],
+                        ]
+                    )
+                    slot_spot = np.concatenate(
+                        [
+                            slot_price[: s_cap + r_cap],
+                            uc["g_spot_price"],
+                        ]
+                    )
+                else:
+                    slot_price = np.zeros((G,), np.float32)
+                    slot_spot = slot_price
+                g_valid_full = np.concatenate(
+                    [sg.valid, rr.valid & rr.preempt, uc["g_valid"]]
+                )
+                g_absent_full = np.concatenate(
+                    [~sg.valid, ~(rr.valid & rr.preempt), uc["g_absent"]]
+                )
+                run_gang_full = np.where(
+                    rr.valid & rr.preempt,
+                    (s_cap + np.arange(r_cap)).astype(np.int32),
+                    np.int32(-1),
+                )
+                return SchedulingProblem(
+                    node_total=nc["node_total"],
+                    node_type=nc["node_type"],
+                    node_ok=nc["node_ok"],
+                    run_req=rr.req.copy(),
+                    run_node=rr.node.copy(),
+                    run_level=rr.level.copy(),
+                    run_queue=rr.queue.copy(),
+                    run_pc=rr.pc.copy(),
+                    run_preemptible=rr.preempt.copy(),
+                    run_gang=run_gang_full,
+                    run_valid=rr.valid.copy(),
+                    g_req=np.concatenate([sg.req, rr.req, uc["g_req"]]),
+                    g_card=np.concatenate(
+                        [
+                            np.ones((s_cap,), np.int32),
+                            np.ones((r_cap,), np.int32),
+                            uc["g_card"],
+                        ]
+                    ),
+                    g_level=np.concatenate([sg.level, rr.level, uc["g_level"]]),
+                    g_queue=np.concatenate([sg.queue, rr.queue, uc["g_queue"]]),
+                    g_key=np.concatenate(
+                        [sg.key, np.full((r_cap,), -1, np.int32), uc["g_key"]]
+                    ),
+                    g_pc=np.concatenate([sg.pc, rr.pc, uc["g_pc"]]),
+                    g_order=np.zeros((G,), np.int32),
+                    g_run=np.concatenate(
+                        [
+                            np.full((s_cap,), -1, np.int32),
+                            np.arange(r_cap, dtype=np.int32),
+                            uc["g_run"],
+                        ]
+                    ),
+                    g_valid=g_valid_full,
+                    g_absent=g_absent_full,
+                    g_price=slot_price,
+                    g_spot_price=slot_spot,
+                    gq_gang=gq_gang,
+                    q_start=q_start,
+                    q_len=q_len,
+                    q_weight=fulls["q_weight"],
+                    q_cds=q_cds,
+                    q_penalty=fulls["q_penalty"],
+                    compat=fulls["compat"],
+                    total_pool=total_pool,
+                    drf_mult=drf_mult,
+                    inv_scale=nc["inv_scale"],
+                    round_cap=nc["round_cap"],
+                    pc_queue_cap=fulls["pc_queue_cap"],
+                    protected_fraction=fulls["protected_fraction"],
+                    global_burst=fulls["global_burst"],
+                    perq_burst=fulls["perq_burst"],
+                    node_axes=nc["node_axes"],
+                    float_total=nc["float_total"],
+                    market=fulls["market"],
+                    spot_cutoff=fulls["spot_cutoff"],
+                    ban_mask=fulls["ban_mask"],
+                    g_ban_row=np.concatenate(
+                        [
+                            np.zeros((s_cap,), np.int32),
+                            np.zeros((r_cap,), np.int32),
+                            uc["g_ban_row"],
+                        ]
+                    ),
+                    type_bias=fulls["type_bias"],
+                    key_type_row=fulls["key_type_row"],
+                    compat_pre_type=fulls["compat_pre_type"],
+                )
+
+            sig = (
+                G,
+                r_cap,
+                N,
+                Q,
+                sg.epoch,
+                rr.epoch,
+                u_cap,
+                self._node_epoch,
+                # market: a price move re-prices every slot at once; ride the
+                # full-upload fallback instead of dirtying the whole slab
+                self._price_epoch,
+            )
+            seq = self._bundle_seq
+            self._bundle_seq += 1
+            self._last_sig = sig
+            bundle = DeltaBundle(
+                sig=sig,
+                seq=seq,
+                materialize=materialize,
+                ev_base=s_cap,
+                sg_idx=sg_idx,
+                sg_cols=sg_cols,
+                rr_idx=rr_dirty,
+                rr_cols=rr_cols,
+                ev_cols=ev_cols,
+                fulls=fulls,
+                gq_splice=gq_splice,
+            )
+
+            class _SparseGroups:
+                __slots__ = ("_d",)
+
+                def __init__(self, d):
+                    self._d = d
+
+                def __getitem__(self, i):
+                    return self._d.get(i, "")
+
+            ctx = HostContext(
+                config=cfg,
+                pool=self.pool,
+                queue_names=list(self.queue_names),
+                node_ids=list(self.node_ids),
+                gang_members=None,
+                gang_group=_SparseGroups(group_of),
+                run_job_ids=None,
+                num_real_nodes=Nreal,
+                num_real_queues=Qreal,
+                num_real_gangs=G,
+                num_real_runs=r_cap,
+                ladder=self.ladder,
+                pc_names=list(self.pc_names),
+                max_slots=S_slots,
+                slot_width=W,
+                type_names=[nt.hw_type for nt in self.ntidx.types],
+                q_demand_raw=q_demand_raw,
+                pool_total_atoms={
+                    name: int(round(float(total_pool64[i]) * self.factory.resolutions[i]))
+                    for i, name in enumerate(self.factory.names)
+                    if total_pool64[i]
+                },
+                # Copy-on-write snapshots: a mutation landing between assemble
+                # and decode (slot reuse after remove) must not corrupt decode's
+                # ids, but eagerly copying [G] ids cost ~30ms of every assemble;
+                # now the first post-assemble id write copies instead.
+                gang_ids_vec=self._share_g_ids(),
+                gang_members_over=members_over,
+                run_ids_vec=rr.share_ids(),
+                # slab run axis IS the slot axis; lazy like the dense path (the
+                # mapping reads slot-stable state, and the production flow
+                # materializes within the decode window, before apply_outcome
+                # mutates the tables)
+                running_gangs=lambda: self._running_gang_ctx_groups(
+                    lambda row: (
+                        int(s)
+                        if rr.valid[(s := int(self.runs.slot[row]))]
+                        else None
+                    )
+                ),
+            )
+            return bundle, ctx
 
     # ---------------------------------------------------- gang slow path ----
 

@@ -43,6 +43,7 @@ from armada_tpu.core.keys import (
     type_score_tables,
 )
 from armada_tpu.core.types import JobSpec, NodeSpec, Queue, RunningJob
+from armada_tpu.ops.trace import recorder as _trace
 
 _INF = np.float32(3.0e38)
 
@@ -1369,7 +1370,11 @@ def _fetch_compact(result, ctx: HostContext, dispatched=None):
     if d is None:
         return None
     buf_dev, fcap, ecap = d
-    buf = np.asarray(buf_dev)
+    # The ONE blocking wait of a steady round: for the kernel, the
+    # compaction behind it and the buffer's device->host copy.  Everything
+    # after this span in fetch_decode is the host's own work.
+    with _trace().span("device_wait"):
+        buf = np.asarray(buf_dev)
     from armada_tpu.models.xfer import TRANSFER_STATS
 
     TRANSFER_STATS.count_down(buf.nbytes)
@@ -1462,7 +1467,11 @@ def begin_decode(result, ctx: HostContext):
         return box["v"]
 
     def finish() -> RoundOutcome:
-        return decode_result(result, ctx, _dispatched=dispatched, _fetched=fetch())
+        fetched = fetch()  # device_wait, unless verification already fetched
+        with _trace().span("decode"):
+            return decode_result(
+                result, ctx, _dispatched=dispatched, _fetched=fetched
+            )
 
     finish.dispatched = dispatched
     finish.fetch = fetch
@@ -1532,7 +1541,8 @@ def begin_decode_stacked(result, ctxs: list):
 
     def fetch_all() -> np.ndarray:
         if "all" not in box:
-            arr = np.asarray(buf)
+            with _trace().span("device_wait", stacked=len(ctxs)):
+                arr = np.asarray(buf)
             from armada_tpu.models.xfer import TRANSFER_STATS
 
             TRANSFER_STATS.count_down(arr.nbytes)
@@ -1555,7 +1565,8 @@ def begin_decode_stacked(result, ctxs: list):
             # dispatch each on CPU -- 17 fields x P lanes of them erased
             # the stacking win before this was lazy).
             lane = None if fetched is not None else lane_slice(result, i)
-            return decode_result(lane, ctx, _fetched=fetched)
+            with _trace().span("decode"):
+                return decode_result(lane, ctx, _fetched=fetched)
 
         finish.dispatched = (buf, fcap, ecap)
         finish.fetch = fetch

@@ -254,6 +254,7 @@ def _make_apply(out_shardings=None):
         jit_kwargs["out_shardings"] = out_shardings
 
     @functools.partial(jax.jit, **jit_kwargs)
+    @jax.named_scope("armada.scatter")
     def apply_delta(
         prev, sg_idx, sg_cols, rr_idx, rr_cols, ev_cols, fulls, gq_args,
         *, ev_base, splice,
@@ -439,17 +440,37 @@ class DeviceDeltaCache:
             self._tsan.commit(tok, "apply/steady-noop")
             return self._prev
 
+        # The compiled variant of the scatter program this apply runs is
+        # fixed by the index buckets, the splice flag and which full fields
+        # ship: it rides the span as `program` and `bucket` (rows / runs /
+        # splice / full fields), so a window that alternates between
+        # variants, or meets a new one, shows in the trace.
+        kg = _pad_bucket(bundle.sg_idx.shape[0])
+        kr = _pad_bucket(bundle.rr_idx.shape[0])
+        splice = bundle.gq_splice is not None
+        kq = (
+            _pad_bucket(max(bundle.gq_splice[0].shape[0], bundle.gq_splice[1].shape[0]))
+            if splice
+            else 0
+        )
+        # full fields whose host object changed (the others' device copies
+        # are current)
+        changed = {
+            name: arr
+            for name, arr in bundle.fulls.items()
+            if self._host_ids.get(name) is not arr
+        }
         with _trace().span(
             "devcache_apply",
             full_upload=False,
             sg_rows=int(bundle.sg_idx.shape[0]),
             rr_rows=int(bundle.rr_idx.shape[0]),
-            splice=bundle.gq_splice is not None,
+            splice=splice,
+            program="apply_delta.splice" if splice else "apply_delta",
+            bucket=f"{kg}/{kr}/{kq}/{len(changed)}",
         ):
             G = self._prev.g_req.shape[0]
             RJ = self._prev.run_req.shape[0]
-            kg = _pad_bucket(bundle.sg_idx.shape[0])
-            kr = _pad_bucket(bundle.rr_idx.shape[0])
             sg_idx = np.full((kg,), G, np.int32)
             sg_idx[: bundle.sg_idx.shape[0]] = bundle.sg_idx
             rr_idx = np.full((kr,), RJ, np.int32)
@@ -458,9 +479,7 @@ class DeviceDeltaCache:
             rr_cols = {n: _pad_rows(bundle.rr_cols[n], kr) for n in _RR_FIELDS}
             ev_cols = {n: _pad_rows(bundle.ev_cols[n], kr) for n in _EV_FIELDS}
             fulls = {}
-            for name, arr in bundle.fulls.items():
-                if self._host_ids.get(name) is arr:
-                    continue  # unchanged object, device copy is current
+            for name, arr in changed.items():
                 self._count_up(arr, name)
                 if name in _NODE_FIELDS:
                     # keep the reusable device copy current, else a later full
@@ -471,10 +490,8 @@ class DeviceDeltaCache:
                 else:
                     fulls[name] = np.asarray(arr)
                 self._host_ids[name] = arr
-            splice = bundle.gq_splice is not None
             if splice:
                 rem, ins, vals = bundle.gq_splice
-                kq = _pad_bucket(max(rem.shape[0], ins.shape[0]))
                 rem_pos = np.full((kq,), G, np.int32)
                 rem_pos[: rem.shape[0]] = rem
                 ins_pos = np.full((kq,), G, np.int32)
@@ -526,15 +543,17 @@ class DeviceDeltaCache:
             or seq != self._seq + 1
         ):
             return False
+        kg = _pad_bucket(sg_idx.shape[0])
+        kr = _pad_bucket(rr_idx.shape[0])
         with _trace().span(
             "scatter_content",
             sg_rows=int(sg_idx.shape[0]),
             rr_rows=int(rr_idx.shape[0]),
+            program="apply_delta",
+            bucket=f"{kg}/{kr}/0/0",
         ):
             G = self._prev.g_req.shape[0]
             RJ = self._prev.run_req.shape[0]
-            kg = _pad_bucket(sg_idx.shape[0])
-            kr = _pad_bucket(rr_idx.shape[0])
             sg_pad = np.full((kg,), G, np.int32)
             sg_pad[: sg_idx.shape[0]] = sg_idx
             rr_pad = np.full((kr,), RJ, np.int32)

@@ -266,7 +266,7 @@ def _kernel():
     if _KERNEL is None:
         import jax
 
-        _KERNEL = jax.jit(_verify_kernel_impl)
+        _KERNEL = jax.jit(jax.named_scope("armada.verify")(_verify_kernel_impl))
     return _KERNEL
 
 
@@ -493,7 +493,9 @@ def _kernel_stacked():
     if _KERNEL_STACKED is None:
         import jax
 
-        _KERNEL_STACKED = jax.jit(jax.vmap(_verify_kernel_impl))
+        _KERNEL_STACKED = jax.jit(
+            jax.named_scope("armada.verify")(jax.vmap(_verify_kernel_impl))
+        )
     return _KERNEL_STACKED
 
 

@@ -6,8 +6,8 @@ regression that doubles the upload payload is invisible in a CPU-only
 run's wall clock.  These counters make it legible without a TPU: the slab
 delta cache counts every
 host->device array it ships (slab.DeviceDeltaCache), the compact decode
-counts its device->host fetch (problem._fetch_compact), and bench.py /
-tools/sidecar_profile.py report the per-cycle numbers.
+counts its device->host fetch (problem._fetch_compact), and bench.py and
+perfbench/run.py (`uploads_per_cycle`) report the per-cycle numbers.
 
 Counters are process-global and single-threaded like the cycle itself;
 ``reset()`` at cycle start, ``snapshot()`` at cycle end.

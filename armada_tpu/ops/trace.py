@@ -40,15 +40,27 @@ Design constraints (all load-bearing):
   re-bases them under the caller's RPC span, yielding ONE stitched tree
   for a ``ScheduleRound`` driven by an external control plane.
 
+* **Covered by its children.**  Every span on the served cycle's path
+  has named children for what it spends (PR 25): one span per boundary
+  per cycle, never one per job -- counts ride as args.  Self time is
+  defined ONCE, :func:`self_seconds` (duration minus the union of the
+  children's intervals), and printed by :func:`top_spans`.  Garbage
+  collections that begin on a cycle's thread are its ``gc_collect``
+  children while a control plane runs (:func:`arm_gc`).
+
 Readers: ``armadactl trace`` / tools/trace_dump.py (:func:`chrome_trace`),
 /healthz's ``trace`` block (:meth:`TraceRecorder.healthz_block`), the
 prometheus gauges ``armada_cycle_stage_seconds{stage,quantile}``
 (scheduler/metrics.py, fed from :meth:`TraceRecorder.stage_snapshot`),
-and bench.py's ``stage_*_s`` keys.  docs/observability.md is the workflow.
+bench.py's ``stage_*_s`` keys, and perfbench's per-layer metrics
+(perfbench/layers/*.json read the spans by name).  docs/observability.md
+is the workflow and the span catalogue.
 """
 
 from __future__ import annotations
 
+import gc
+import itertools
 import os
 import threading
 from collections import deque
@@ -351,6 +363,12 @@ class TraceRecorder:
         span = Span(name, mono_now(), threading.get_ident(), args or None)
         parent.children.append(span)
         owner.span_count += 1
+        if self._jax_bridge:
+            # an instant on the profiler's clock too, like the spans: the
+            # transfers line up with the device trace
+            ctx = self._enter_jax(name, args)
+            if ctx is not None:
+                ctx.__exit__(None, None, None)
 
     def annotate(self, **args) -> None:
         """Attach args to the owning cycle's root (failover reason,
@@ -422,6 +440,56 @@ class TraceRecorder:
             self.registry.histogram(f"stage.{stage}").record(dur)
         self.registry.histogram("cycle").record(trace.root.dur_s)
 
+    # ---------------------------------------------------------------- gc ----
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """The ``gc.callbacks`` hook (armed by :func:`arm_gc` while a plane
+        runs): a collection that starts while a cycle is open ON THE
+        COLLECTING THREAD becomes a ``gc_collect`` child of the innermost
+        span open there, so the pause is charged to the span that paid for
+        it; a collection on any other thread is dropped (no fall back to the
+        process's primary cycle: that cycle did not allocate).  One span per
+        collection, over the interval in which it ran, under the cycle's
+        span cap like any other.  Takes no lock and reads no recorder state
+        another thread writes: a collection can start anywhere, also inside
+        this module's own critical sections."""
+        tls = self._tls
+        if phase == "start":
+            tls.gc_t0 = None
+            if getattr(tls, "stack", None) and self.enabled:
+                tls.gc_t0 = mono_now()
+                if self._jax_bridge:
+                    tls.gc_jax = self._enter_jax("gc_collect")
+            return
+        t0 = getattr(tls, "gc_t0", None)
+        if t0 is None:
+            return
+        t1 = mono_now()
+        tls.gc_t0 = None
+        ctx = getattr(tls, "gc_jax", None)
+        if ctx is not None:
+            tls.gc_jax = None
+            ctx.__exit__(None, None, None)
+        stack = getattr(tls, "stack", None)
+        owner = getattr(tls, "trace", None)
+        if owner is None:
+            owner = self._active_by_thread.get(threading.get_ident())
+        if not stack or owner is None or owner.finished:
+            return
+        if owner.span_count >= _SPAN_CAP:
+            owner.overflow += 1
+            return
+        span = Span(
+            "gc_collect", t0, threading.get_ident(),
+            {
+                "generation": int(info.get("generation", 0)),
+                "collected": int(info.get("collected", 0)),
+            },
+        )
+        span.t1 = t1
+        stack[-1].children.append(span)
+        owner.span_count += 1
+
     # ----------------------------------------------------------- readers ----
 
     def last(self, n: Optional[int] = None) -> list:
@@ -475,36 +543,62 @@ class TraceRecorder:
     # -------------------------------------------------------- jax bridge ----
 
     @staticmethod
-    def _enter_jax(name: str):
+    def _enter_jax(name: str, args: Optional[dict] = None):
         """Optional jax.profiler.TraceAnnotation bridge
-        (ARMADA_TRACE_JAX=1): host spans appear in device traces so a
-        jax-profiler capture lines up with this module's timeline."""
+        (ARMADA_TRACE_JAX=1): host spans, notes and collections appear in
+        device traces so a jax-profiler capture lines up with this
+        module's timeline.  A note's args ride as the annotation's stats."""
         from jax.profiler import TraceAnnotation
 
         try:
-            ctx = TraceAnnotation(name)
+            ctx = TraceAnnotation(name, **(args or {}))
             ctx.__enter__()
             return ctx
         except Exception:  # noqa: BLE001 - tracing must never break the cycle
             return None
 
 
+def self_seconds(span: dict) -> float:
+    """Seconds one offset-form span (Span.to_dict) spent in none of its
+    children: its duration minus the union of its children's intervals
+    clipped to it.  THE definition of self time, for `top_spans` and for
+    every reader outside this module.  Children on another thread count
+    (the watchdog's round worker is adopted under the span that waits for
+    it), overlapping children count once, and a zero-duration note counts
+    nothing."""
+    lo = float(span.get("off_s", 0.0))
+    hi = lo + float(span.get("dur_s", 0.0))
+    covered, end = 0.0, lo
+    for start, stop in sorted(
+        (float(c.get("off_s", 0.0)), float(c.get("off_s", 0.0)) + float(c.get("dur_s", 0.0)))
+        for c in span.get("children", ())
+    ):
+        start, stop = max(start, end), min(stop, hi)
+        if stop > start:
+            covered += stop - start
+            end = stop
+    return max(0.0, (hi - lo) - covered)
+
+
 def top_spans(root: dict, n: int = 12) -> list:
     """The N longest spans of one offset-form tree (Span.to_dict), each as
-    ``{"name", "depth", "dur_s"}`` -- the ONE flatten/rank implementation
-    behind the /healthz trace block and `armadactl trace --summary`."""
-    flat: list[tuple[float, str, int]] = []
+    ``{"name", "depth", "dur_s", "self_s"}`` -- the ONE flatten/rank
+    implementation behind the /healthz trace block and `armadactl trace
+    --summary`."""
+    flat: list[tuple[float, str, int, float]] = []
 
     def walk(d: dict, depth: int) -> None:
         for c in d.get("children", ()):
-            flat.append((float(c.get("dur_s", 0.0)), c.get("name", ""), depth))
+            flat.append(
+                (float(c.get("dur_s", 0.0)), c.get("name", ""), depth, self_seconds(c))
+            )
             walk(c, depth + 1)
 
     walk(root, 1)
     flat.sort(reverse=True)
     return [
-        {"name": name, "depth": depth, "dur_s": round(dur, 6)}
-        for dur, name, depth in flat[:n]
+        {"name": name, "depth": depth, "dur_s": round(dur, 6), "self_s": round(self_s, 6)}
+        for dur, name, depth, self_s in flat[:n]
     ]
 
 
@@ -604,3 +698,37 @@ def reset_recorder(ring: Optional[int] = None) -> TraceRecorder:
     with _recorder_lock:
         _recorder = TraceRecorder(ring=ring)
         return _recorder
+
+
+# ---------------------------------------------------------------------------
+# garbage collections as spans (armed by a running plane)
+# ---------------------------------------------------------------------------
+
+_gc_armed: set = set()
+_gc_tokens = itertools.count(1)
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    rec = _recorder  # no lock: a collection may start inside recorder()
+    if rec is not None:
+        rec._on_gc(phase, info)
+
+
+def arm_gc() -> int:
+    """Register the ONE ``gc.callbacks`` hook (see TraceRecorder._on_gc) for
+    as long as any token is armed; a control plane arms it at start and
+    disarms at stop, so an embedding process pays nothing before or after.
+    Returns the token for :func:`disarm_gc`."""
+    token = next(_gc_tokens)
+    with _recorder_lock:
+        if not _gc_armed:
+            gc.callbacks.append(_gc_hook)
+        _gc_armed.add(token)
+    return token
+
+
+def disarm_gc(token: int) -> None:
+    with _recorder_lock:
+        _gc_armed.discard(token)
+        if not _gc_armed and _gc_hook in gc.callbacks:
+            gc.callbacks.remove(_gc_hook)

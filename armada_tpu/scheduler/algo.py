@@ -242,23 +242,26 @@ class FairSchedulingAlgo:
             drain_shadow()
             return result
 
-        healthy = self._healthy_executors(executors, now_ns)
-        nodes: list[NodeSpec] = []
-        executor_of_node: dict[str, str] = {}
-        for ex in healthy:
-            for n in ex.nodes:
-                if n.id in quarantined_nodes and not n.unschedulable:
-                    n = dataclasses.replace(n, unschedulable=True)
-                nodes.append(n)
-                executor_of_node[n.id] = ex.id
+        # O(fleet) every round: the node list, its executor map and the
+        # pool set are rebuilt from the executor snapshots
+        with _trace().span("fleet_scan", executors=len(executors)):
+            healthy = self._healthy_executors(executors, now_ns)
+            nodes: list[NodeSpec] = []
+            executor_of_node: dict[str, str] = {}
+            for ex in healthy:
+                for n in ex.nodes:
+                    if n.id in quarantined_nodes and not n.unschedulable:
+                        n = dataclasses.replace(n, unschedulable=True)
+                    nodes.append(n)
+                    executor_of_node[n.id] = ex.id
 
-        queues = list(self._queues())
-        known_queues = {q.name for q in queues}
+            queues = list(self._queues())
+            known_queues = {q.name for q in queues}
 
-        pools = [p.name for p in self.config.pools]
-        for n in nodes:
-            if n.pool not in pools:
-                pools.append(n.pool)
+            pools = [p.name for p in self.config.pools]
+            for n in nodes:
+                if n.pool not in pools:
+                    pools.append(n.pool)
 
         incremental = self.feed is not None
         market_pools = {p.name for p in self.config.pools if p.market_driven}
@@ -535,7 +538,8 @@ class FairSchedulingAlgo:
                 )
 
         for pool in pools:
-            pool_nodes = [n for n in nodes if n.pool == pool]
+            with _trace().span("pool_nodes", pool=pool):
+                pool_nodes = [n for n in nodes if n.pool == pool]
             if not pool_nodes:
                 continue
             window_eligible = pool_parallel_ok and pool not in market_pools
@@ -548,12 +552,14 @@ class FairSchedulingAlgo:
             running = running_by_pool.get(pool, [])
             if incremental:
                 prep_t0 = mono_now()
-                b = self.feed.builder_for(pool, txn)
-                # Market prices are re-read from the provider every cycle;
-                # the builder's _prices() snapshot uses this callable.
-                b.bid_price_of = bid_price_of
-                b.set_queues(pool_queues(pool))
-                b.set_nodes(pool_nodes)
+                with _trace().span("pool_prepare", pool=pool):
+                    b = self.feed.builder_for(pool, txn)
+                    # Market prices are re-read from the provider every
+                    # cycle; the builder's _prices() snapshot uses this
+                    # callable.
+                    b.bid_price_of = bid_price_of
+                    b.set_queues(pool_queues(pool))
+                    b.set_nodes(pool_nodes)
                 num_queued = len(b.jobs.key_of_id) + len(b.gang_jobs)
                 num_running = len(b.runs.key_of_id)
                 if not num_queued and not num_running:
@@ -735,10 +741,14 @@ class FairSchedulingAlgo:
         # them.  The host's running set is refreshed with this cycle's own
         # decisions (leases added, preemptions removed) so the away round
         # cannot double-book capacity the home rounds just committed.
-        preempted_ids = {job.id for job, _ in result.preempted}
-        extra_running: dict[str, list[RunningJob]] = {}
-        for job, run in result.scheduled:
-            extra_running.setdefault(run.pool, []).append(_running_of(job, run))
+        # O(decisions) every round, whether or not any pool lends nodes
+        with _trace().span("away_prepare", scheduled=len(result.scheduled)):
+            preempted_ids = {job.id for job, _ in result.preempted}
+            extra_running: dict[str, list[RunningJob]] = {}
+            for job, run in result.scheduled:
+                extra_running.setdefault(run.pool, []).append(
+                    _running_of(job, run)
+                )
 
         def host_running(host: str) -> list[RunningJob]:
             kept = [

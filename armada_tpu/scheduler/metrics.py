@@ -244,8 +244,15 @@ class SchedulerMetrics:
         # a cycle root: sync_state/transitions/schedule/event_publish/
         # commit for scheduler cycles (assemble/round/kernel spans nest
         # INSIDE schedule -- the watchdog worker adopts the caller's span,
-        # so they never double-count as stages), feed_apply/assemble/
-        # round/apply_outcome for sidecar rounds.
+        # so they never double-count as stages); for sidecar cycles the
+        # direct children of the two roots: session_lock_wait/
+        # job_from_state/mirror_upsert/mirror_commit/prefetch_content
+        # under sidecar_sync, session_lock_wait/fleet_scan/feed_apply (the
+        # overlay)/pool_nodes/pool_prepare/assemble/round/apply_outcome/
+        # away_prepare/mirror_commit/slo_feed under sidecar_round.  The
+        # commit's feed_apply nests in mirror_commit since PR 25, so
+        # sidecar_sync has no feed_apply stage any more
+        # (docs/observability.md has the catalogue).
         self.cycle_stage_latency = g(
             "armada_cycle_stage_seconds",
             "Per-stage cycle latency percentiles (trace-span histograms)",

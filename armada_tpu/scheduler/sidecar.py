@@ -24,6 +24,7 @@ corrected by the next sync.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 import uuid
@@ -36,6 +37,7 @@ from armada_tpu.core.types import Queue
 from armada_tpu.events.convert import job_spec_from_proto
 from armada_tpu.jobdb.job import Job, JobRun
 from armada_tpu.jobdb.jobdb import JobDb
+from armada_tpu.ops.trace import recorder as _trace
 from armada_tpu.scheduler.algo import FairSchedulingAlgo, SchedulerResult
 from armada_tpu.scheduler.providers import most_specific_bid
 from armada_tpu.scheduler.executors import ExecutorSnapshot
@@ -197,13 +199,11 @@ class ScheduleSession:
         bids: Optional[dict] = None,
         trace_id: str = "",
     ) -> None:
-        from armada_tpu.ops.trace import recorder as trace_recorder
-
         # The caller's cycle is sync + round: the sync half gets its own
         # ring entry (kind "sync") under the caller's trace id so the two
-        # stitch by id in a dump (tools/sidecar_profile.py reads the split
-        # from exactly these entries).
-        with trace_recorder().cycle(
+        # stitch by id in a dump (perfbench's sync_s and round spans read
+        # the split from exactly these entries).
+        with _trace().cycle(
             "sidecar_sync",
             trace_id=trace_id,
             kind="sync",
@@ -212,10 +212,24 @@ class ScheduleSession:
         ):
             self._apply_sync_locked(jobs, deletes, executors, queues, bids)
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """The session lock; the wait for it is a span of the open cycle
+        (a sync queued behind a round, or the reverse, shows as such)."""
+        with _trace().span("session_lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def _apply_sync_locked(
         self, jobs, deletes, executors, queues, bids
     ) -> None:
-        with self._lock:
+        # One span per boundary per request, never one per job: the counts
+        # ride as span args (perfbench/layers/ reads these by name).
+        trace = _trace()
+        with self._locked():
             if jobs or deletes:
                 for m in jobs:
                     if m.terminal:
@@ -235,10 +249,17 @@ class ScheduleSession:
                 if deletes:
                     txn.delete(list(deletes))
                 if jobs:
-                    txn.upsert(
-                        [_job_from_state(m, self.factory) for m in jobs]
-                    )
-                txn.commit()
+                    with trace.span("job_from_state", n=len(jobs)):
+                        converted = [
+                            _job_from_state(m, self.factory) for m in jobs
+                        ]
+                    with trace.span("mirror_upsert", n=len(converted)):
+                        txn.upsert(converted)
+                # the commit publishes to the mirror's indexes (mirror_index)
+                # and fires the feed's subscription (feed_apply with its
+                # submit_many / remove_many / lease_many nest in here)
+                with trace.span("mirror_commit"):
+                    txn.commit()
                 if (
                     self.feed is not None
                     and pipeline_enabled()
@@ -287,7 +308,6 @@ class ScheduleSession:
     ) -> SchedulerResult:
         from armada_tpu.core.watchdog import supervisor
         from armada_tpu.ops.metrics import mono_now
-        from armada_tpu.ops.trace import recorder as trace_recorder
         from armada_tpu.scheduler.slo import recorder as slo_recorder
 
         t_start = mono_now()
@@ -298,9 +318,10 @@ class ScheduleSession:
         # arrived over the gRPC metadata (rpc/server.py): the caller grafts
         # the returned spans under its RPC span, yielding one stitched
         # cross-process tree (tests/test_trace.py pins it).
-        with trace_recorder().cycle(
+        trace = _trace()
+        with trace.cycle(
             "sidecar_round", trace_id=trace_id, kind="round", session=self.id
-        ), self._lock:
+        ), self._locked():
             txn = self.jobdb.write_txn()
             now = now_ns or self._clock_ns()
 
@@ -313,22 +334,25 @@ class ScheduleSession:
                 # see txn deletes at commit), so the pipelined round runs
                 # it in the kernel shadow; final mirror state is identical
                 # either way (tests/test_pipeline.py).
-                window = int(
-                    max(
-                        self.config.short_job_penalty_cutoffs().values(),
-                        default=0.0,
+                with trace.span(
+                    "sweep", tracked=len(self._terminal_synced)
+                ):
+                    window = int(
+                        max(
+                            self.config.short_job_penalty_cutoffs().values(),
+                            default=0.0,
+                        )
+                        * 1e9
                     )
-                    * 1e9
-                )
-                expired = [
-                    jid
-                    for jid, ns in self._terminal_synced.items()
-                    if ns == 0 or now - ns >= window
-                ]
-                if expired:
-                    txn.delete(expired)
-                    for jid in expired:
-                        self._terminal_synced.pop(jid, None)
+                    expired = [
+                        jid
+                        for jid, ns in self._terminal_synced.items()
+                        if ns == 0 or now - ns >= window
+                    ]
+                    if expired:
+                        txn.delete(expired)
+                        for jid in expired:
+                            self._terminal_synced.pop(jid, None)
 
             pipelined = pipeline_enabled()
             result = self.algo.schedule(
@@ -343,28 +367,30 @@ class ScheduleSession:
             # Commit the mirror like the in-process scheduler commits its
             # jobDb: later rounds must see this round's leases.  The caller
             # re-asserting job state via SyncState is idempotent on top.
-            txn.commit()
+            with trace.span("mirror_commit"):
+                txn.commit()
             # Sidecar rounds feed the same streaming cycle-latency SLO as
             # the in-process scheduler (TTFL/ingest-lag stay caller-side:
             # the caller owns submit timing across the boundary).  Degraded
             # = before OR fallback-delta OR after: a drill-speed re-probe
             # can promote back before the failed-over round returns, and a
             # promotion can land mid-round (scheduler.cycle's rule).
-            sup = supervisor()
-            slo_recorder().observe_cycle(
-                mono_now() - t_start,
-                degraded=degraded0
-                or sup.degraded
-                or sup.snapshot()["fallbacks"] > fallbacks0,
-            )
-            # Per-pool round latency rides the same recorder (round 17):
-            # the algo stamps each PoolStats with its round seconds + the
-            # per-round fallback-delta degraded flag.
-            for ps in result.pools:
-                if ps.round_s:
-                    slo_recorder().observe_pool_round(
-                        ps.pool, ps.round_s, degraded=ps.degraded
-                    )
+            with trace.span("slo_feed"):
+                sup = supervisor()
+                slo_recorder().observe_cycle(
+                    mono_now() - t_start,
+                    degraded=degraded0
+                    or sup.degraded
+                    or sup.snapshot()["fallbacks"] > fallbacks0,
+                )
+                # Per-pool round latency rides the same recorder (round
+                # 17): the algo stamps each PoolStats with its round seconds
+                # + the per-round fallback-delta degraded flag.
+                for ps in result.pools:
+                    if ps.round_s:
+                        slo_recorder().observe_pool_round(
+                            ps.pool, ps.round_s, degraded=ps.degraded
+                        )
             return result
 
 
@@ -492,7 +518,6 @@ class ScheduleSidecar:
         )
 
     def handle_round(self, msg, trace_id: str = ""):
-        from armada_tpu.ops.trace import recorder as trace_recorder
         from armada_tpu.rpc import rpc_pb2 as pb
 
         s = self.session(msg.session_id)
@@ -506,8 +531,7 @@ class ScheduleSidecar:
         # an untraced caller pays zero response bytes for it.
         trace_doc = None
         if trace_id:
-            rec = trace_recorder()
-            for t in reversed(rec.last()):
+            for t in reversed(_trace().last()):
                 if t.trace_id == trace_id and t.kind == "round":
                     d = t.root.to_dict(t.root.t0)
                     d.setdefault("args", {})["pid"] = t.pid

@@ -18,6 +18,7 @@
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import threading
 
@@ -424,3 +425,387 @@ def test_pipeline_bit_equality_with_tracing_armed(monkeypatch):
     # ...and the armed run actually recorded round cycles
     rec = trace_mod.recorder()
     assert any(t.kind == "round" for t in rec.last())
+
+
+# --- 6. the served cycle's tree is closed (PR 25) ------------------------------
+
+
+def _names(span, into=None):
+    """Span names under `span` (itself excluded), with multiplicity."""
+    into = [] if into is None else into
+    for c in span.children:
+        into.append(c.name)
+        _names(c, into)
+    return into
+
+
+def _find(span, name):
+    return [c for c in _walk(span) if c.name == name]
+
+
+def _walk(span):
+    for c in span.children:
+        yield c
+        yield from _walk(c)
+
+
+def _served_session(maximum_scheduling_burst=16, num_nodes=12):
+    """An in-process sidecar session at the tiny served size, with the
+    fleet and the queues synced: (sidecar, session id, factory)."""
+    from tests.test_pipeline import NOW_NS, make_config, make_world
+    from armada_tpu.rpc import rpc_pb2 as pb  # noqa: F401 - builds the wire module
+    from armada_tpu.scheduler.executors import ExecutorSnapshot
+    from armada_tpu.scheduler.sidecar import ScheduleSidecar
+
+    cfg = dataclasses.replace(
+        make_config(incremental_problem_build=True),
+        maximum_scheduling_burst=maximum_scheduling_burst,
+    )
+    F, nodes, queues = make_world(cfg, num_nodes=num_nodes)
+    sidecar = ScheduleSidecar(cfg, clock_ns=lambda: NOW_NS)
+    sid = sidecar.create_session("t")
+    sidecar.session(sid).apply_sync(
+        executors=[
+            ExecutorSnapshot(
+                id="ex1", pool="default", nodes=tuple(nodes), last_update_ns=NOW_NS
+            )
+        ],
+        queues=queues,
+    )
+    return sidecar, sid, F
+
+
+def _served_cycle(sidecar, sid, F, first, n):
+    """One SyncState (n fresh jobs) + one ScheduleRound through the wire
+    handlers; returns the cycle's (sync root, round root, response)."""
+    from tests.test_pipeline import NOW_NS, make_job
+    from armada_tpu.jobdb.job import Job
+    from armada_tpu.ops.trace import recorder
+    from armada_tpu.rpc import rpc_pb2 as pb
+    from armada_tpu.rpc.client import job_state_of
+
+    states = [
+        job_state_of(
+            Job(spec=make_job(F, first + i, f"q{i % 3}", cpu=1), queued=True, validated=True)
+        )
+        for i in range(n)
+    ]
+    sidecar.handle_sync(pb.SyncStateRequest(session_id=sid, jobs=states))
+    resp = sidecar.handle_round(pb.ScheduleRoundRequest(session_id=sid, now_ns=NOW_NS))
+    sync, rnd = recorder().last()[-2:]
+    assert (sync.kind, rnd.kind) == ("sync", "round")
+    return sync.root, rnd.root, resp
+
+
+def test_served_cycle_roots_are_covered_by_named_children(_fresh_recorder):
+    sidecar, sid, F = _served_session()
+    sync, rnd, resp = _served_cycle(sidecar, sid, F, 0, 6)
+    assert len(resp.scheduled) == 6
+    # the sync half: lock, convert, upsert, commit -- the feed nests in the commit
+    assert [c.name for c in sync.children if c.name != "gc_collect"] == [
+        "session_lock_wait", "job_from_state", "mirror_upsert", "mirror_commit",
+    ]
+    by_name = {c.name: c for c in sync.children}
+    assert by_name["job_from_state"].args == {"n": 6}
+    assert by_name["mirror_upsert"].args == {"n": 6}
+    commit = [c.name for c in by_name["mirror_commit"].children if c.name != "gc_collect"]
+    assert commit == ["mirror_index", "feed_apply"]
+    assert "submit_many" in _names(by_name["mirror_commit"])
+    # the round half
+    top = [c.name for c in rnd.children if c.name != "gc_collect"]
+    assert top[0] == "session_lock_wait" and top[-2:] == ["mirror_commit", "slo_feed"]
+    assert {
+        "fleet_scan", "pool_nodes", "pool_prepare", "assemble", "round", "apply_outcome", "away_prepare",
+    } <= set(top)
+    (assemble,) = _find(rnd, "assemble")
+    assert [c.name for c in assemble.children if c.name != "gc_collect"] == [
+        "assemble_order", "assemble_splice", "assemble_bundle",
+    ]
+    (thunk,) = _find(rnd, "shadow_thunk")
+    assert [c.name for c in thunk.children if c.name != "gc_collect"] == ["sweep"]
+    (fetch,) = _find(rnd, "fetch_decode")
+    inside = [c.name for c in fetch.children if c.name not in ("gc_collect", "xfer_down")]
+    # verification is armed by serve, not by a bare sidecar: no verify_fetch here
+    assert inside == ["device_wait", "decode"]
+    wait, decode = (c for c in fetch.children if c.name in ("device_wait", "decode"))
+    assert wait.t1 <= decode.t0 and fetch.t0 <= wait.t0 and decode.t1 <= fetch.t1
+    (apply_,) = _find(rnd, "devcache_apply")
+    assert apply_.args["full_upload"] is True  # the first round uploads whole
+
+
+def test_scatter_spans_name_the_compiled_variant(_fresh_recorder):
+    sidecar, sid, F = _served_session()
+    _served_cycle(sidecar, sid, F, 0, 6)
+    _, rnd, _ = _served_cycle(sidecar, sid, F, 100, 6)
+    (apply_,) = _find(rnd, "devcache_apply")
+    assert apply_.args["program"] in ("apply_delta", "apply_delta.splice")
+    rows, runs, splice, fulls = (int(v) for v in apply_.args["bucket"].split("/"))
+    assert rows >= apply_.args["sg_rows"] and runs >= apply_.args["rr_rows"]
+    assert (splice > 0) == apply_.args["splice"] and fulls >= 0
+
+
+def test_g_ids_copy_only_on_a_cycle_that_copies(_fresh_recorder):
+    sidecar, sid, F = _served_session()
+    sync1, round1, _ = _served_cycle(sidecar, sid, F, 0, 6)
+    # before any round nothing shares the id vector: the first sync's submits
+    # write in place; the round's assemble shares it, and its own leases
+    # leaving the backlog pay the one copy
+    assert not _find(sync1, "g_ids_copy")
+    copies = _find(round1, "g_ids_copy")
+    assert len(copies) == 1 and copies[0].args["bytes"] > 0
+    sync2, round2, resp2 = _served_cycle(sidecar, sid, F, 100, 6)
+    assert not _find(sync2, "g_ids_copy")  # owned since round 1's copy
+    assert len(resp2.scheduled) == 6 and len(_find(round2, "g_ids_copy")) == 1
+    # a sync and a round that change nothing copy nothing
+    from tests.test_pipeline import NOW_NS
+    from armada_tpu.ops.trace import recorder
+    from armada_tpu.rpc import rpc_pb2 as pb
+
+    for _ in range(2):  # the second round finds the vector still shared
+        sidecar.handle_round(pb.ScheduleRoundRequest(session_id=sid, now_ns=NOW_NS))
+    assert not _find(recorder().last()[-1].root, "g_ids_copy")
+
+
+@pytest.mark.parametrize("burst", [10, 100])
+def test_spans_per_cycle_do_not_depend_on_the_burst(_fresh_recorder, burst):
+    """Nothing is recorded per job: a cycle of 10 jobs and one of 100 leave
+    the same spans (gc_collect apart: one per collection, which follows the
+    allocator)."""
+    import collections
+
+    # a burst cap above both cycles together: the rate limiter never binds
+    sidecar, sid, F = _served_session(maximum_scheduling_burst=1000, num_nodes=40)
+    _served_cycle(sidecar, sid, F, 0, burst)  # full upload, first compiles
+    sync, rnd, resp = _served_cycle(sidecar, sid, F, 1000, burst)
+    assert len(resp.scheduled) == burst
+    got = collections.Counter(n for n in _names(sync) + _names(rnd) if n != "gc_collect")
+    # one note per array the scatter ships (index vectors, columns, the full
+    # fields that changed): a count of fields, never of rows
+    assert 30 <= got.pop("xfer_up") <= 45
+    assert got == _EXPECTED_STEADY_SPANS, got
+
+
+# The steady cycle's spans at the tiny served size on the CPU (no prefetch
+# there, no verification without serve), whatever the burst.
+_EXPECTED_STEADY_SPANS = {
+    "session_lock_wait": 2, "job_from_state": 1, "mirror_upsert": 1, "mirror_commit": 2,
+    "mirror_index": 2, "feed_apply": 4, "submit_many": 1, "fleet_scan": 1, "pool_nodes": 1,
+    "pool_prepare": 1, "assemble": 1, "assemble_order": 1, "assemble_splice": 1,
+    "assemble_bundle": 1, "round": 1, "devcache_apply": 1, "kernel_dispatch": 1,
+    "decode_dispatch": 1, "shadow": 1, "shadow_thunk": 1, "sweep": 1, "fetch_decode": 1,
+    "device_wait": 1, "decode": 1, "apply_outcome": 1, "remove_many": 1, "table_remove": 1,
+    "g_ids_copy": 1, "lease_many": 1, "away_prepare": 1, "slo_feed": 1, "xfer_down": 1,
+}
+
+
+def test_gc_collect_is_charged_to_the_span_that_paid(_fresh_recorder):
+    import gc
+
+    from armada_tpu.ops.trace import arm_gc, disarm_gc
+
+    rec = _fresh_recorder
+    before = len(gc.callbacks)
+    token = arm_gc()
+    other = arm_gc()  # a second plane: still one hook
+    assert len(gc.callbacks) == before + 1
+    try:
+        gc.collect()  # no cycle open anywhere: dropped
+        assert not rec.last()
+        with rec.cycle("cyc"):
+            with rec.span("work"):
+                gc.collect()
+                gc.collect()
+                gc.collect(0)
+            t = threading.Thread(target=gc.collect)  # a thread with no cycle
+            t.start()
+            t.join()
+        (trace,) = rec.last()
+        (work,) = trace.root.children  # nothing landed on the root
+        # one span per collection, over the interval in which it ran
+        assert [c.name for c in work.children] == ["gc_collect"] * 3
+        assert [c.args["generation"] for c in work.children] == [2, 2, 0]
+        first, second, young = work.children
+        assert work.t0 <= first.t0 < first.t1 <= second.t0 < second.t1 <= young.t0
+        assert young.t1 <= work.t1 and "collected" in first.args
+        gc.collect()  # the cycle is finished: dropped
+        assert len(work.children) == 3
+    finally:
+        disarm_gc(token)
+        assert len(gc.callbacks) == before + 1
+        disarm_gc(other)
+    assert len(gc.callbacks) == before
+
+
+def test_self_time_of_a_parent_with_overlapping_children_on_two_threads():
+    from armada_tpu.ops.trace import Span, self_seconds, top_spans
+
+    parent = Span("round", 10.0, 1, None)
+    parent.t1 = 11.0
+    for name, t0, t1, tid in (
+        ("kernel_dispatch", 10.1, 10.4, 2),  # the watchdog's worker, adopted
+        ("shadow", 10.3, 10.6, 1),  # overlaps it on the waiting thread
+        ("fetch_decode", 10.9, 11.5, 2),  # runs past the parent: clipped
+        ("xfer_down", 10.7, 10.7, 1),  # a note covers nothing
+    ):
+        child = Span(name, t0, tid, None)
+        child.t1 = t1
+        parent.children.append(child)
+    doc = parent.to_dict(10.0)
+    # 1.0 - ([.1,.6] + [.9,1.0]) = 0.4
+    assert self_seconds(doc) == pytest.approx(0.4)
+    assert self_seconds(doc["children"][0]) == pytest.approx(0.3)  # a leaf: all of it
+    root = Span("cyc", 10.0, 1, None)
+    root.t1 = 11.0
+    root.children.append(parent)
+    ranked = {s["name"]: s for s in top_spans(root.to_dict(10.0))}
+    assert ranked["round"]["self_s"] == pytest.approx(0.4)
+    assert ranked["round"]["dur_s"] == pytest.approx(1.0)
+
+
+def test_notes_and_collections_pass_through_the_profiler_bridge(monkeypatch):
+    import gc
+
+    from armada_tpu.ops.trace import arm_gc, disarm_gc
+
+    monkeypatch.setenv("ARMADA_TRACE_JAX", "1")
+    rec = reset_recorder()
+    seen = []
+
+    class _Ctx:
+        def __init__(self, name):
+            self.name = name
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    def enter(name, args=None):
+        seen.append(("enter", name, dict(args or {})))
+        return _Ctx(name)
+
+    monkeypatch.setattr(rec, "_enter_jax", enter)
+    token = arm_gc()
+    try:
+        with rec.cycle("cyc"):
+            rec.note("xfer_up", bytes=42)
+            gc.collect()
+    finally:
+        disarm_gc(token)
+    assert ("enter", "xfer_up", {"bytes": 42}) in seen
+    assert seen.index(("exit", "xfer_up")) == seen.index(("enter", "xfer_up", {"bytes": 42})) + 1
+    assert ("enter", "gc_collect", {}) in seen and ("exit", "gc_collect") in seen
+
+
+# --- 7. the device programs carry names, and only names (PR 25) ----------------
+
+
+def _canonical_hlo(text: str) -> str:
+    """Optimized HLO with operation metadata and the source tables (four
+    blocks between the module's header and its computations) dropped and
+    every instruction renamed by order of appearance (named scopes shift the
+    compiler's name counters)."""
+    import re
+
+    text = re.sub(r",?\s*metadata=\{[^}]*\}", "", text)
+    text, tables = re.subn(
+        r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*", "\n", text
+    )
+    assert tables == 4 and "ENTRY" in text and "while(" in text
+    names: dict = {}
+
+    def rename(m):
+        return names.setdefault(m.group(0), f"%{len(names)}")
+
+    return re.sub(r"%[\w.\-]+", rename, text)
+
+
+def test_named_scopes_change_only_metadata(_fresh_recorder, monkeypatch):
+    import contextlib
+
+    import jax
+
+    from armada_tpu.models import fair_scheduler as fs
+
+    captured = {}
+    real = fs._schedule_round_jit
+
+    def spy(p, **statics):
+        captured.setdefault("call", (p, statics))
+        return real(p, **statics)
+
+    monkeypatch.setattr(fs, "_schedule_round_jit", spy)
+    sidecar, sid, F = _served_session()
+    _served_cycle(sidecar, sid, F, 0, 6)
+    p, statics = captured["call"]
+    named = real.lower(p, **statics).compile().as_text()
+    for scope in (
+        "armada.round/armada.round.evict", "armada.round/armada.round.loop/while",
+        "armada.round.loop/while/body/select", "armada.round.loop/while/body/fit",
+        "armada.round.loop/while/body/commit", "armada.round/armada.round.repair",
+    ):
+        assert scope in named, scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+
+    # under jit's wrapper sits named_scope("armada.round")'s, then the function
+    bare_round = real.__wrapped__.__wrapped__
+
+    def unnamed(p, **kw):  # a fresh function: nothing traced under the names is reused
+        return bare_round(p, **kw)
+
+    unnamed.__name__ = bare_round.__name__  # the module is named after it
+    bare_fn = jax.jit(unnamed, static_argnames=tuple(statics))
+    bare = bare_fn.lower(p, **statics).compile().as_text()
+    assert "armada." not in bare
+    assert _canonical_hlo(named) == _canonical_hlo(bare)
+
+
+# The profiler names a call of a device program "jit_" + the jitted function
+# (its `XLA Modules` line) and an operation by its HLO text alone, so a trace
+# reader finds programs by the first and scopes through the compiled text.
+_DEVICE_PROGRAMS = {
+    "armada.round": "_schedule_round_jit",
+    "armada.compact": "compact_result",
+    "armada.verify": "_verify_kernel_impl",
+    "armada.explain": "_explain_kernel_impl",
+    "armada.scatter": "apply_delta",
+}
+
+
+@pytest.mark.parametrize("scope", sorted(_DEVICE_PROGRAMS))
+def test_device_programs_keep_their_names(scope, _fresh_recorder, monkeypatch):
+    """Each program of the serving path is one jitted function under one
+    scope: the function's name is what the profiler calls the program."""
+    import jax
+
+    from armada_tpu.models import explain, fair_scheduler as fs, slab, verify
+
+    name = _DEVICE_PROGRAMS[scope]
+    if scope in ("armada.round", "armada.compact"):
+        # wrapped at import: read the scope off the program a served cycle ran
+        real = getattr(fs, name)
+        calls = []
+
+        def spy(*args, **kw):
+            calls.append((args, kw))
+            return real(*args, **kw)
+
+        monkeypatch.setattr(fs, name, spy)
+        sidecar, sid, F = _served_session()
+        _served_cycle(sidecar, sid, F, 0, 6)
+        args, kw = calls[0]
+        text = real.lower(*args, **kw).compile().as_text()
+        assert text.startswith(f"HloModule jit_{name}")
+        assert f'op_name="jit({name})/{scope}/' in text
+        return
+    seen = []
+    named_scope = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: seen.append(name) or named_scope(name)
+    )
+    if scope == "armada.scatter":
+        fn = slab._make_apply()
+    else:
+        module = verify if scope == "armada.verify" else explain
+        monkeypatch.setattr(module, "_KERNEL", None)
+        fn = module._kernel()
+    assert seen == [scope]
+    assert fn.__name__ == name
