@@ -994,6 +994,7 @@ def _finish_body(h: _RoundHandle):
     if outcome.kernel_iters:
         trace.annotate(
             kernel_iters=outcome.kernel_iters,
+            window_refills=outcome.window_refills,
             commits_per_iter=round(
                 outcome.num_iterations / outcome.kernel_iters, 2
             ),

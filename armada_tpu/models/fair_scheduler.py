@@ -99,10 +99,15 @@ class RoundResult(NamedTuple):
     # multi-commit work shrinks (commits_per_iter = iterations/kernel_iters).
     # Excluded from the bit-equality contract the parity suites pin.
     kernel_iters: jax.Array  # i32
+    # Trips on which more queues moved their cursor than the body rebuilds
+    # row by row, so the carried skip window was gathered whole again (see
+    # _make_place_iteration).  An observability counter like kernel_iters,
+    # and excluded from the bit-equality contract with it.
+    window_refills: jax.Array  # i32
 
 
 # Header slots of the packed decode buffer (see compact_result).
-_COMPACT_HEADER = 9
+_COMPACT_HEADER = 10
 
 
 @functools.partial(jax.jit, static_argnames=("fcap", "ecap"))
@@ -119,8 +124,8 @@ def compact_result(result: RoundResult, num_real_gangs, num_real_runs, *, fcap: 
     key-retirement rounds) and falls back to the full pull.
 
     Layout (i32): [n_slots, iterations, termination, sched_count,
-    spot_price_bits, n_failed, n_pre, n_res, kernel_iters] ++ slot_gang[S]
-    ++ slot_nodes[S*W] ++ slot_counts[S*W] ++ failed_idx[fcap] ++
+    spot_price_bits, n_failed, n_pre, n_res, kernel_iters, window_refills]
+    ++ slot_gang[S] ++ slot_nodes[S*W] ++ slot_counts[S*W] ++ failed_idx[fcap] ++
     pre_idx[ecap] ++ res_idx[ecap].
     """
     g = result.g_state
@@ -152,6 +157,7 @@ def compact_result(result: RoundResult, num_real_gangs, num_real_runs, *, fcap: 
             n_pre,
             n_res,
             result.kernel_iters.astype(jnp.int32),
+            result.window_refills.astype(jnp.int32),
         ]
     )
     return jnp.concatenate(
@@ -174,6 +180,11 @@ class _Carry(NamedTuple):
     q_killed: jax.Array
     q_sched: jax.Array
     q_head: jax.Array  # i32[Q] cursor into the (queue, order)-sorted gang index
+    # The skip window at q_head ([Q, _SKIP_WINDOW]; see _skip_window), carried
+    # and patched by what each trip changed instead of gathered every trip.
+    w_gang: jax.Array  # i32: gang ids of the window's entries
+    w_key: jax.Array  # i32: their scheduling keys
+    w_skip: jax.Array  # bool: decided (g_state != 0) or key registered unfeasible
     g_state: jax.Array
     key_bad: jax.Array
     run_rescheduled: jax.Array
@@ -187,6 +198,7 @@ class _Carry(NamedTuple):
     new_blocked: jax.Array
     iterations: jax.Array
     kernel_iters: jax.Array  # physical body applications (see RoundResult)
+    window_refills: jax.Array  # trips that gathered the whole window again
     done: jax.Array
     termination: jax.Array
     spot_price: jax.Array  # f32; -1 = unset
@@ -219,6 +231,29 @@ class _Carry(NamedTuple):
 # scheduling keys) per iteration.  Skipping is the rare path -- the window just
 # bounds how fast a mass-retired run of identical jobs drains.
 _SKIP_WINDOW = 16
+
+
+def _skip_window(p: SchedulingProblem, q_start, q_head, g_state, key_bad, check_keys: bool):
+    """The skip window of the queues whose gq segments start at ``q_start``
+    with cursors ``q_head`` (both i32[A]): the next _SKIP_WINDOW entries of
+    each queue in the (queue, order)-sorted gang index, as three [A, W] tables
+    -- gang id, scheduling key, and "skippable": the gang is already decided
+    (g_state != 0) or, under check_keys, its key is registered unfeasible
+    (gang_scheduler.go:85-96 -- the reference skips these through its
+    iterator the same way).  Entries past a queue's tail (and the clip at
+    G - 1) hold whatever gang the index has there; the body masks them with
+    its own ``in_r``.  Three dependent gathers from [G] arrays: on the v5e a
+    gather costs 7-13 ns an ELEMENT over a floor of ~1 us, so all Q = 256
+    rows are 29 us apiece and one row is the floor (PERF.md, PR 26) -- the
+    loop calls this on the rows that moved, and on all of them only when it
+    must."""
+    G = p.g_req.shape[0]
+    offs = q_head[:, None] + jnp.arange(_SKIP_WINDOW, dtype=jnp.int32)[None, :]
+    slot = jnp.clip(q_start[:, None] + offs, 0, G - 1)
+    wg = p.gq_gang[slot]
+    wkey = p.g_key[wg]
+    wbad = jnp.bool_(check_keys) & (wkey >= 0) & key_bad[jnp.maximum(wkey, 0)]
+    return wg, wkey, (g_state[wg] != 0) | wbad
 
 
 def _level_mask(num_levels: int, level, lo):
@@ -282,7 +317,34 @@ def _make_place_iteration(
     batch_k: int = 1,
     commit_k: int = 1,
 ):
-    """prefer_large is a STATIC flag (like check_keys): the default compile
+    """One trip of the placement loop: select (cursor advance, candidate,
+    queue order, gates), fit, commit, and the upkeep of the carried window.
+
+    THE CARRIED SKIP WINDOW.  A queue's cursor may skip entries whose gang is
+    already decided or whose key is registered unfeasible; what it looks at
+    is the [Q, W] window of _skip_window.  Gathering it costs three dependent
+    [Q, W] gathers from [G] arrays, which on the v5e is 7 ns an ELEMENT
+    (29 us each at Q = 256, against a per-operation floor of 1-2 us: PERF.md,
+    PR 26), and a trip changes almost none of it.  So the window is loop
+    state (_Carry.w_gang / w_key / w_skip), with one invariant:
+    AT THE TOP OF EVERY TRIP THE CARRIED TABLES EQUAL
+    _skip_window(p, p.q_start, c.q_head, c.g_state, c.key_bad, check_keys).
+    Everything the body derives from them (in_r, skippable, nskip, q_head,
+    cand, head_visible, and parked / nn / tail_known / hidden of the batch_k
+    and commit_k extensions) is therefore the same expression of the same
+    values as when the window was gathered every trip.  The `window` scope
+    at the body's end keeps it: what the trip decided is patched
+    elementwise (g_state changes at the committed gangs, key_bad at one
+    key); a queue whose cursor moved has its row gathered at the new head,
+    `rows` of them (one per gang a trip can commit); a trip that moves more
+    cursors than that -- a key registration retiring several queues' heads
+    -- takes a lax.cond to the full gather and counts in window_refills.
+    Loop state that changes at one index a trip is carried and patched,
+    never gathered again (CLAUDE.md).  Under jax.vmap (the stacked round)
+    the cond is a select and both branches run: that form keeps the full
+    gather's cost.
+
+    prefer_large is a STATIC flag (like check_keys): the default compile
     carries none of the alternate-ordering work.  q_budget is the per-queue
     weighted budget from the round's fair-share computation (passed in so the
     water-filling loop is not traced twice).  cache_slots sizes the
@@ -345,6 +407,9 @@ def _make_place_iteration(
     Q = p.q_weight.shape[0]
     RJ = p.run_req.shape[0]
     S = cache_slots
+    # Queues whose cursor one trip can move without a key registration: the
+    # head pick's, plus one per committed extension lane.
+    rows = min(max(commit_k, batch_k, 1), Q)
 
     # Loop-invariant masked request tables, gathered per iteration: computing
     # req * node_axes inside the body would depend on the gathered row and
@@ -373,9 +438,10 @@ def _make_place_iteration(
         ) * p.q_weight[p.g_queue]
 
     def body(c: _Carry) -> _Carry:
-        # A trip's three phases carry names for the profiler: select
-        # (cursor advance, candidate, queue order, gates), fit (fit + node
-        # selection), commit (commit, gang state, cache maintenance).
+        # A trip's phases carry names for the profiler: select (cursor
+        # advance, candidate, queue order, gates), fit (fit + node
+        # selection), commit (commit, gang state, cache maintenance), window
+        # (the carried skip window's upkeep).
         # Names are operation metadata only: the compiled program is the
         # same operations (tests/test_trace.py).
         with jax.named_scope("select"):
@@ -386,18 +452,15 @@ def _make_place_iteration(
             else:
                 active = jnp.bool_(True)
             # --- advance per-queue cursors past retired/unfeasible heads ------------
-            # Window gather into the (queue, order)-sorted gang index: O(Q*W), never
-            # O(G).  An entry is skippable if its gang was already decided (state!=0)
-            # or its scheduling key is registered unfeasible (gang_scheduler.go:85-96
-            # -- the reference skips these through its iterator the same way).
+            # The window into the (queue, order)-sorted gang index is CARRIED
+            # (see the docstring's invariant): O(Q*W) elementwise work, no
+            # gather.
             W = _SKIP_WINDOW
             offs = c.q_head[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]  # [Q, W]
             in_r = offs < p.q_len[:, None]
-            slot = jnp.clip(p.q_start[:, None] + offs, 0, G - 1)
-            wg = p.gq_gang[slot]  # [Q, W] gang ids
-            wkey = p.g_key[wg]
-            wbad = jnp.bool_(check_keys) & (wkey >= 0) & c.key_bad[jnp.maximum(wkey, 0)]
-            skippable = in_r & ((c.g_state[wg] != 0) | wbad)
+            wg = c.w_gang  # [Q, W] gang ids
+            wkey = c.w_key
+            skippable = in_r & c.w_skip
             lead = jnp.cumprod(skippable.astype(jnp.int32), axis=1)  # leading-True run
             nskip = jnp.sum(lead, axis=1).astype(jnp.int32) * active.astype(jnp.int32)
             q_head = c.q_head + nskip
@@ -1110,7 +1173,6 @@ def _make_place_iteration(
                 wev = wrun >= 0
                 wlevel = p.g_level[wg]
                 wpc = p.g_pc[wg]
-                wkey_g = p.g_key[wg]
                 wban = p.g_ban_row[wg]
                 wreq = p.g_req[wg]  # [Q, W, R] per-member
                 wreq_tot = wreq * wcard[..., None].astype(jnp.float32)
@@ -1212,7 +1274,7 @@ def _make_place_iteration(
                     run_j = jnp.where(ev_j, wrun[qj, i_safe], RJ)
                     lvl_j = wlevel[qj, i_safe]
                     pc_j = wpc[qj, i_safe]
-                    key_j = wkey_g[qj, i_safe]
+                    key_j = wkey[qj, i_safe]
                     ban_j = wban[qj, i_safe]
                     req_j = wreq[qj, i_safe]
                     reqn_j = wreq_node[qj, i_safe]
@@ -1484,39 +1546,84 @@ def _make_place_iteration(
                 cursor = cursor + jnp.sum(new_e.astype(jnp.int32))
                 extra_iters = jnp.sum(ex_placed.astype(jnp.int32))
 
-            return _Carry(
-                alloc=alloc,
-                q_alloc=q_alloc,
-                q_alloc_pc=q_alloc_pc,
-                q_killed=q_killed,
-                q_sched=q_sched,
-                q_head=q_head,
-                g_state=g_state,
-                key_bad=key_bad,
-                run_rescheduled=run_rescheduled,
-                slot_gang=slot_gang,
-                slot_nodes=slot_nodes,
-                slot_counts=slot_counts,
-                cursor=cursor,
-                sched_count=sched_count,
-                sched_res=sched_res,
-                float_used=float_used,
-                new_blocked=new_blocked,
-                iterations=c.iterations + active.astype(jnp.int32) + extra_iters,
-                kernel_iters=c.kernel_iters + active.astype(jnp.int32),
-                done=done,
-                termination=termination,
-                spot_price=spot_price,
-                spot_res=spot_res,
-                fitc_clean=fitc_clean,
-                fitc_lvl=fitc_lvl,
-                score_c=score_c,
-                bmc_clean=bmc_clean,
-                bmc_lvl=bmc_lvl,
-                cslot_key=cslot_key,
-                cslot_lvl=cslot_lvl,
-                cslot_req=cslot_req,
+        # --- the carried skip window: patch what this trip changed ----------------
+        with jax.named_scope("window"):
+            # State: g_state moved at the head pick (and at the extension's
+            # committed lanes), key_bad at the registered key (`register`
+            # holds check_keys) -- elementwise on [Q, W], no gather.
+            hit = (wg == g) & attempt
+            if commit_k > 1:
+                hit |= jnp.any((wg[:, :, None] == ge) & ok_e, axis=-1)
+            if batch_k > 1:
+                hit |= jnp.any((wg[:, :, None] == ex_gang) & ex_placed, axis=-1)
+            w_skip = c.w_skip | hit | (register & (wkey == key))
+            # Cursor: a queue that moved gets its row gathered at the new head
+            # (from the post-commit g_state / key_bad, so the patch above is
+            # moot for it).  A trip moves the queues whose heads the previous
+            # trip decided, at most `rows` of them; more than that (a key
+            # registration that retires several queues' heads at once) takes
+            # the branch that gathers every row, which returns the [Q, W]
+            # tables only -- a [G] array through a branch is copied per trip.
+            moved = nskip > 0
+            if rows == 1:
+                # one reduce, where top_k sorts
+                # lint: allow(full-argmin) -- [Q]-axis first moved queue, not [N]
+                qm = jnp.argmax(moved).astype(jnp.int32)[None]
+            else:
+                _, qm = jax.lax.top_k(moved.astype(jnp.int32), rows)  # lowest index first
+                qm = qm.astype(jnp.int32)
+            moved_rows = _skip_window(
+                p, p.q_start[qm], q_head[qm], g_state, key_bad, check_keys
             )
+            qm = jnp.where(moved[qm], qm, Q)  # lanes with nothing to rebuild: dropped
+            window = tuple(
+                t.at[qm].set(r, mode="drop")
+                for t, r in zip((wg, wkey, w_skip), moved_rows)
+            )
+            refill = jnp.sum(moved.astype(jnp.int32)) > rows
+            w_gang, w_key, w_skip = jax.lax.cond(
+                refill,
+                lambda: _skip_window(p, p.q_start, q_head, g_state, key_bad, check_keys),
+                lambda: window,
+            )
+
+        return _Carry(
+            alloc=alloc,
+            q_alloc=q_alloc,
+            q_alloc_pc=q_alloc_pc,
+            q_killed=q_killed,
+            q_sched=q_sched,
+            q_head=q_head,
+            w_gang=w_gang,
+            w_key=w_key,
+            w_skip=w_skip,
+            g_state=g_state,
+            key_bad=key_bad,
+            run_rescheduled=run_rescheduled,
+            slot_gang=slot_gang,
+            slot_nodes=slot_nodes,
+            slot_counts=slot_counts,
+            cursor=cursor,
+            sched_count=sched_count,
+            sched_res=sched_res,
+            float_used=float_used,
+            new_blocked=new_blocked,
+            iterations=c.iterations + active.astype(jnp.int32) + extra_iters,
+            kernel_iters=c.kernel_iters + active.astype(jnp.int32),
+            window_refills=c.window_refills + refill.astype(jnp.int32),
+            done=done,
+            termination=termination,
+            spot_price=spot_price,
+            spot_res=spot_res,
+            fitc_clean=fitc_clean,
+            fitc_lvl=fitc_lvl,
+            score_c=score_c,
+            bmc_clean=bmc_clean,
+            bmc_lvl=bmc_lvl,
+            cslot_key=cslot_key,
+            cslot_lvl=cslot_lvl,
+            cslot_req=cslot_req,
+        )
 
     return body
 
@@ -1870,7 +1977,7 @@ def _schedule_round_jit(
     metadata only): ``armada.round`` around the whole, and its three parts
     ``armada.round.evict`` (set-up, fair-share eviction, gang activation),
     ``armada.round.loop`` (the placement loop, whose body names its trip's
-    phases ``select`` / ``fit`` / ``commit``) and ``armada.round.repair``
+    phases ``select`` / ``fit`` / ``commit`` / ``window``) and ``armada.round.repair``
     (key retirement, oversubscription repair, unbinding)."""
     with jax.named_scope("armada.round.evict"):
         G = p.g_req.shape[0]
@@ -1920,15 +2027,23 @@ def _schedule_round_jit(
         # slack regions) are ABSENT, not failed: decode must never report them.
         g_state = jnp.where(p.g_absent, 3, g_state)
 
+        q_head0 = jnp.zeros((Q,), jnp.int32)
+        key_bad0 = jnp.zeros((p.compat.shape[0],), bool)
+        w_gang0, w_key0, w_skip0 = _skip_window(
+            p, p.q_start, q_head0, g_state, key_bad0, check_keys=False  # none is bad yet
+        )
         carry = _Carry(
             alloc=alloc,
             q_alloc=q_alloc,
             q_alloc_pc=q_alloc_pc,
             q_killed=~(p.q_weight > 0),
             q_sched=jnp.zeros((Q,), jnp.int32),
-            q_head=jnp.zeros((Q,), jnp.int32),
+            q_head=q_head0,
+            w_gang=w_gang0,
+            w_key=w_key0,
+            w_skip=w_skip0,
             g_state=g_state,
-            key_bad=jnp.zeros((p.compat.shape[0],), bool),
+            key_bad=key_bad0,
             run_rescheduled=jnp.zeros_like(run_evicted),
             slot_gang=jnp.zeros((max_slots,), jnp.int32),
             slot_nodes=jnp.full((max_slots, slot_width), N, jnp.int32),
@@ -1940,6 +2055,7 @@ def _schedule_round_jit(
             new_blocked=jnp.bool_(False),
             iterations=jnp.int32(0),
             kernel_iters=jnp.int32(0),
+            window_refills=jnp.int32(0),
             done=jnp.bool_(False),
             termination=jnp.int32(TERM_EXHAUSTED),
             spot_price=jnp.float32(-1.0),
@@ -2036,4 +2152,5 @@ def _schedule_round_jit(
             spot_price=carry.spot_price,
             q_killed=carry.q_killed,
             kernel_iters=carry.kernel_iters,
+            window_refills=carry.window_refills,
         )

@@ -228,6 +228,10 @@ class RoundOutcome:
     # multi-commit kernel (ARMADA_COMMIT_K); equal when K=1.  0 = unknown
     # (synthetic outcomes).
     kernel_iters: int = 0
+    # Trips on which the kernel gathered its whole skip window again
+    # (RoundResult.window_refills); a small share of kernel_iters unless key
+    # registrations retire several queues' heads at once.
+    window_refills: int = 0
     # queue name -> {weight, fair_share, adjusted_fair_share, actual_share,
     # demand_share} (feeds cycle metrics + reports; the reference's
     # QueueSchedulingContext numbers, cycle_metrics.go:71-170).
@@ -1404,7 +1408,7 @@ def _parse_compact(buf: np.ndarray, ctx: HostContext, fcap: int, ecap: int):
     ctx.last_compact_np = buf
     (
         n_slots, iterations, termination, _sched_count, spot_bits, n_failed,
-        n_pre, n_res, kernel_iters,
+        n_pre, n_res, kernel_iters, window_refills,
     ) = (int(v) for v in buf[:_COMPACT_HEADER])
     if n_failed > fcap or n_pre > ecap or n_res > ecap:
         return None
@@ -1433,7 +1437,7 @@ def _parse_compact(buf: np.ndarray, ctx: HostContext, fcap: int, ecap: int):
 
     return (
         n_slots, slot_gang, slot_nodes, slot_counts, g2, pre_idx, res_idx,
-        state_of, iterations, termination, spot, kernel_iters,
+        state_of, iterations, termination, spot, kernel_iters, window_refills,
     )
 
 
@@ -1620,7 +1624,7 @@ def decode_result(
     if compact is not None:
         (
             n_slots, slot_gang, slot_nodes, slot_counts, g2, pre_idx, res_idx,
-            state_of, iterations, termination, spot, kernel_iters,
+            state_of, iterations, termination, spot, kernel_iters, window_refills,
         ) = compact
     else:
         g_state = np.asarray(result.g_state)
@@ -1642,6 +1646,7 @@ def decode_result(
         state_of = lambda gi: int(g_state[gi])  # noqa: E731
         iterations = int(result.iterations)
         kernel_iters = int(result.kernel_iters)
+        window_refills = int(result.window_refills)
         termination = int(result.termination)
         spot = float(result.spot_price)
 
@@ -1717,6 +1722,7 @@ def decode_result(
         failed=failed,
         num_iterations=iterations,
         kernel_iters=kernel_iters,
+        window_refills=window_refills,
         termination=_TERMINATIONS[termination],
         spot_price=spot if spot >= 0 else None,
         unwound_groups=frozenset(unwound),

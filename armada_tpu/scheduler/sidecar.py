@@ -409,6 +409,8 @@ def _stats_of(result: SchedulerResult, trace: Optional[dict] = None) -> str:
             # physical while-loop trips under the multi-commit kernel
             # (ARMADA_COMMIT_K); == iterations at K=1
             "kernel_iters": getattr(s.outcome, "kernel_iters", 0),
+            # of those, the trips that gathered the whole skip window again
+            "window_refills": getattr(s.outcome, "window_refills", 0),
             "queue_stats": s.outcome.queue_stats,
         }
         if s.market:

@@ -46,14 +46,30 @@ def _bound_xla_mappings(request):
     executables make the next mmap fail -- surfacing as MemoryError with
     gigabytes of RAM free (this killed the full suite at a deterministic
     test twice in round 3).  Clearing per MODULE bounds live mappings while
-    keeping within-module recompiles at zero."""
+    keeping within-module recompiles at zero -- unless one module alone comes
+    near the limit (tests/test_pool_parallel.py's ~70 round compiles did,
+    once the round program grew a few kernels in PR 26: a segfault inside
+    the 17th test's compile): then the count itself clears them."""
     module = request.node.nodeid.split("::", 1)[0]
-    if _last_module[0] is not None and module != _last_module[0]:
+    if (
+        _last_module[0] is not None and module != _last_module[0]
+    ) or _live_mappings() > _MAX_LIVE_MAPPINGS:
         import jax
 
         jax.clear_caches()
     _last_module[0] = module
     yield
+
+
+_MAX_LIVE_MAPPINGS = 40_000  # of vm.max_map_count = 65530; a test may add ~10,000
+
+
+def _live_mappings() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:  # not Linux: no such limit to watch
+        return 0
 
 
 # --- test tiers --------------------------------------------------------------

@@ -311,7 +311,7 @@ def test_hetero_cache_and_commit_k_bit_equal():
     for cs, ck in ((8, 1), (0, 4), (8, 8)):
         got = sr(dev, **kw, cache_slots=cs, commit_k=ck)
         for name in base._fields:
-            if name == "kernel_iters":
+            if name in ("kernel_iters", "window_refills"):
                 continue  # multi-commit legitimately shrinks trips
             np.testing.assert_array_equal(
                 np.asarray(getattr(base, name)),

@@ -358,7 +358,7 @@ def test_certified_pick_chain_is_bit_exact():
         for bk in (4, 8):
             got = sr(dev, **kw, batch_k=bk)
             for name in base._fields:
-                if name == "kernel_iters":
+                if name in ("kernel_iters", "window_refills"):
                     continue  # the observability counter batching SHRINKS
                 np.testing.assert_array_equal(
                     np.asarray(getattr(base, name)),
@@ -425,7 +425,7 @@ def test_pick_chain_bit_exact_with_evictions_and_market():
         )
         a, b = sr(dev, **kw, batch_k=1), sr(dev, **kw, batch_k=8)
         for name in a._fields:
-            if name == "kernel_iters":
+            if name in ("kernel_iters", "window_refills"):
                 continue  # the observability counter batching SHRINKS
             np.testing.assert_array_equal(
                 np.asarray(getattr(a, name)),
@@ -475,7 +475,7 @@ def test_pick_chain_bit_exact_with_evictions_and_market():
 
 def _assert_rounds_bit_equal(a, b, label):
     for name in a._fields:
-        if name == "kernel_iters":
+        if name in ("kernel_iters", "window_refills"):
             continue  # the observability counter multi-commit SHRINKS
         np.testing.assert_array_equal(
             np.asarray(getattr(a, name)),
@@ -653,8 +653,9 @@ def test_multi_commit_shrinks_burst_iterations():
 
 def test_commit_k_env_resolution_and_outcome_counters():
     """ARMADA_COMMIT_K resolves outside the jit boundary per call, and the
-    decoded RoundOutcome carries kernel_iters (the compact buffer's ninth
-    header slot) so bench/reports/spans read it without a transfer."""
+    decoded RoundOutcome carries kernel_iters and window_refills (the
+    compact buffer's ninth and tenth header slots) so bench/reports/spans
+    read them without a transfer."""
     import os
 
     cfg = make_config()
@@ -680,3 +681,6 @@ def test_commit_k_env_resolution_and_outcome_counters():
     assert armed.num_iterations == plain.num_iterations
     assert 0 < armed.kernel_iters < plain.kernel_iters
     assert plain.kernel_iters == plain.num_iterations
+    # the 33rd job fits nowhere: its key retires all four queues' heads at
+    # once, more cursors than K = 1 rebuilds by rows and fewer than K = 8 does
+    assert (plain.window_refills, armed.window_refills) == (1, 0)

@@ -718,11 +718,9 @@ def _canonical_hlo(text: str) -> str:
     return re.sub(r"%[\w.\-]+", rename, text)
 
 
-def test_named_scopes_change_only_metadata(_fresh_recorder, monkeypatch):
-    import contextlib
-
-    import jax
-
+def _served_round_call(monkeypatch):
+    """The round program of a served cycle at the tiny size: (the jitted
+    function, its problem, its statics)."""
     from armada_tpu.models import fair_scheduler as fs
 
     captured = {}
@@ -735,7 +733,15 @@ def test_named_scopes_change_only_metadata(_fresh_recorder, monkeypatch):
     monkeypatch.setattr(fs, "_schedule_round_jit", spy)
     sidecar, sid, F = _served_session()
     _served_cycle(sidecar, sid, F, 0, 6)
-    p, statics = captured["call"]
+    return (real,) + captured["call"]
+
+
+def test_named_scopes_change_only_metadata(_fresh_recorder, monkeypatch):
+    import contextlib
+
+    import jax
+
+    real, p, statics = _served_round_call(monkeypatch)
     named = real.lower(p, **statics).compile().as_text()
     for scope in (
         "armada.round/armada.round.evict", "armada.round/armada.round.loop/while",
@@ -756,6 +762,82 @@ def test_named_scopes_change_only_metadata(_fresh_recorder, monkeypatch):
     bare = bare_fn.lower(p, **statics).compile().as_text()
     assert "armada." not in bare
     assert _canonical_hlo(named) == _canonical_hlo(bare)
+
+
+def _hlo_computations(text: str) -> dict:
+    """name -> the instruction lines of each computation of an HLO module."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line)
+    return comps
+
+
+def _gathers(comps: dict, root: str, skip=()) -> list:
+    """(operand's leading dimension, result elements) of every gather in
+    computation `root` and in what it calls (fusions, calls, branches,
+    nested loops), `skip` aside."""
+    import math
+    import re
+
+    dims = r"\w+\[([\d,]*)\]"
+    found, seen, todo = [], set(skip), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        shape_of = {}
+        for line in comps[name]:
+            m = re.match(rf"\s*(?:ROOT )?(%[\w.\-]+) = {dims}", line)
+            if m:
+                shape_of[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+            m = re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = {dims}\S* gather\((%[\w.\-]+),", line)
+            if m:
+                result = math.prod(int(d) for d in m.group(1).split(",") if d)
+                found.append((shape_of[m.group(2)][0], result))
+            todo.extend(re.findall(r"(?:calls|to_apply|body|condition|\w+_computation)=(%[\w.\-]+)", line))
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo.extend(group.split(", "))
+    return found
+
+
+def test_loop_body_gathers_the_skip_window_only_in_the_refill_branch(_fresh_recorder, monkeypatch):
+    """PR 26: the placement loop carries its [Q, W] skip window.  In the
+    compiled round the loop's body holds no gather from a [G] table with a
+    [Q, W] result, but in ONE branch of the `window` conditional: the refill,
+    which is the three gathers every trip used to make."""
+    import re
+
+    from armada_tpu.models import fair_scheduler as fs
+
+    real, p, statics = _served_round_call(monkeypatch)
+    comps = _hlo_computations(real.lower(p, **statics).compile().as_text())
+    window = (p.g_req.shape[0], p.q_weight.shape[0] * fs._SKIP_WINDOW)
+    assert window[0] not in (window[1], p.compat.shape[0])  # [G] is not mistaken
+    (body,) = {
+        re.search(r"body=(%[\w.\-]+)", line).group(1)
+        for name in comps
+        for line in comps[name]
+        if " while(" in line and 'armada.round.loop/while"' in line
+    }
+    (cond,) = [
+        line for line in comps[body] if " conditional(" in line and "/body/window/cond" in line
+    ]
+    branches = re.findall(r"(?:true|false)_computation=(%[\w.\-]+)", cond) or re.findall(
+        r"%[\w.\-]+", cond.split("branch_computations={")[1].split("}")[0]
+    )
+    assert len(branches) == 2
+    assert sorted(_gathers(comps, b).count(window) for b in branches) == [0, 3]
+    outside = _gathers(comps, body, skip=branches)
+    assert len(outside) > 10 and window not in outside
 
 
 # The profiler names a call of a device program "jit_" + the jitted function
