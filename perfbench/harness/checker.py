@@ -14,24 +14,37 @@ Invariants (README.md lists them for users):
   2. every lease names a node of the fleet;
   3. after each round no node holds more than its capacity, in any resource,
      counting the initial running set, every lease so far, minus every run
-     the client has reported finished;
+     the client has reported finished and every run the scheduler preempted;
+     checked on every node that a lease or a preemption of the round touched;
   4. a round leases at most the per-round cap, and at most the per-queue cap
      from any one queue, and says which queue each job belongs to correctly;
   5. the scheduler's own count of running jobs moves by exactly
-     leases - completions from round to round, and its count of queued jobs
-     by submits - leases (what it acknowledged, it keeps).
+     leases - completions - preemptions from round to round, and its count of
+     queued jobs by submits - leases (what it acknowledged, it keeps; a
+     preempted job does not come back to the backlog);
+  6. a preempted job is terminal, as it is for the program (it fails the job)
+     and for the reference scheduler: it held a live lease, or is a live run
+     of the initial running set; its node's room is freed; it is never leased
+     again, never preempted again, and the client never reports it finished;
+  7. only a job of a preemptible priority class (the configuration's
+     `priorityClasses`) is preempted, and no job is both leased and preempted
+     in one round;
+  8. the initial running jobs are known by id, node, shape and class: a
+     preemption of `r00000017` frees that run's room on that run's node.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-QUEUED, LEASED, DONE = 0, 1, 2
+QUEUED, LEASED, DONE, PREEMPTED = 0, 1, 2, 3
 MAX_REPORTED = 20
 
 
 class Checker:
-    def __init__(self, world, cap: int, queue_cap: int):
+    def __init__(self, world, cap: int, queue_cap: int, priority_classes: dict):
+        """`priority_classes` is the configuration's `priorityClasses` block
+        (class name -> {"preemptible": bool, ...})."""
         self.world = world
         self.cap = cap
         self.queue_cap = queue_cap
@@ -45,7 +58,14 @@ class Checker:
         self.status = np.full(world.num_jobs, QUEUED, np.int8)
         self.submitted = int(world.sizes["queued_jobs"])  # numbers below are known
         self.node_of = {}  # leased job number -> node index
+        self.run_live = np.ones(len(world.run_shape), bool)  # initial runs not preempted
         self.prev_counts = None
+
+        def may_preempt(flag: bool) -> bool:
+            return bool(priority_classes[world.class_name(flag)].get("preemptible", False))
+
+        self.shape_preemptible = np.array([may_preempt(s[2]) for s in world.shapes], bool)
+        self.run_shape_preemptible = np.array([may_preempt(s[2]) for s in world.run_shapes], bool)
 
     def _bad(self, n: int, msg: str) -> None:
         self.bad_cycles.add(n)
@@ -54,6 +74,41 @@ class Checker:
             self.violations.append(msg)
         elif len(self.violations) == MAX_REPORTED:
             self.violations.append("... more violations not listed")
+
+    def _preempt(self, n: int, job_id: str):
+        """One preemption: the node whose room it freed, or None when it broke
+        invariant 6 or 7 (reported)."""
+        w = self.world
+        try:
+            i, initial = w.job_number(job_id), False
+        except KeyError:
+            try:
+                i, initial = w.run_number(job_id), True
+            except KeyError:
+                self._bad(n, f"preempted unknown job {job_id!r}")
+                return None
+        if initial:
+            if not self.run_live[i]:
+                self._bad(n, f"preempted initial run {job_id} twice")
+                return None
+            shape, node = w.run_shape[i], int(w.run_node[i])
+            if not self.run_shape_preemptible[shape]:
+                self._bad(n, f"preempted {job_id} of a class that is not preemptible")
+                return None
+            self.run_live[i] = False
+            self.used[node] -= w.run_shape_req[shape]
+            return node
+        if self.status[i] != LEASED:
+            what = "twice" if self.status[i] == PREEMPTED else "that holds no lease"
+            self._bad(n, f"preempted job {job_id} {what}")
+            return None
+        if not self.shape_preemptible[w.job_shape[i]]:
+            self._bad(n, f"preempted {job_id} of a class that is not preemptible")
+            return None
+        self.status[i] = PREEMPTED
+        node = self.node_of.pop(i)
+        self.used[node] -= w.shape_req[w.job_shape[i]]
+        return node
 
     def cycle(self, n: int, record: dict) -> None:
         """One cycle's record: `submitted` (job numbers the SyncState carried),
@@ -68,16 +123,26 @@ class Checker:
         self.submitted = max(self.submitted, max(record["submitted"], default=-1) + 1)
         for i in record["completed"]:
             if self.status[i] != LEASED:
-                self._bad(n, f"the client completed job {i} that holds no lease")
+                what = "that was preempted" if self.status[i] == PREEMPTED else "that holds no lease"
+                self._bad(n, f"the client completed job {i} {what}")
                 continue
             self.status[i] = DONE
             self.used[self.node_of.pop(i)] -= w.shape_req[w.job_shape[i]]
 
         leases = record["leases"]
+        # the round evicts, then places: its preemptions free room first
+        touched = []
+        leased_now = {job_id for job_id, _, _ in leases}
+        for job_id in record["preempted"]:
+            if job_id in leased_now:
+                self._bad(n, f"job {job_id} leased and preempted in one round")
+                continue
+            node = self._preempt(n, job_id)
+            if node is not None:
+                touched.append(node)
         if len(leases) > self.cap:
             self._bad(n, f"{len(leases)} leases, over the per-round cap {self.cap}")
         per_queue: dict = {}
-        touched = []
         for job_id, node_id, queue in leases:
             try:
                 i = w.job_number(job_id)
@@ -88,7 +153,9 @@ class Checker:
                 self._bad(n, f"leased job {job_id} before it was submitted")
                 continue
             if self.status[i] != QUEUED:
-                what = "twice" if self.status[i] == LEASED else "after it finished"
+                what = {LEASED: "twice", DONE: "after it finished"}.get(
+                    int(self.status[i]), "after it was preempted"
+                )
                 self._bad(n, f"job {job_id} leased {what}")
                 continue
             node = w.node_index.get(node_id)
@@ -105,19 +172,6 @@ class Checker:
         for queue, k in per_queue.items():
             if k > self.queue_cap:
                 self._bad(n, f"{k} leases from {queue}, over the per-queue cap")
-        for job_id in record["preempted"]:
-            # nothing in these cells fills the fleet; a preemption frees the
-            # run's resources and requeues the job
-            try:
-                i = w.job_number(job_id)
-            except KeyError:
-                self._bad(n, f"preempted unknown job {job_id!r}")
-                continue
-            if self.status[i] != LEASED:
-                self._bad(n, f"preempted job {job_id} that holds no lease")
-                continue
-            self.status[i] = QUEUED
-            self.used[self.node_of.pop(i)] -= w.shape_req[w.job_shape[i]]
         if touched:
             idx = np.unique(np.asarray(touched))
             over = (self.used[idx] > w.node_total[idx]).any(axis=1)
@@ -132,7 +186,7 @@ class Checker:
         counts = (record.get("num_queued"), record.get("num_running"))
         if None not in counts and self.prev_counts is not None:
             (q0, r0), prev = self.prev_counts
-            want_q = q0 + len(record["submitted"]) - prev["leased"] + prev["preempted"]
+            want_q = q0 + len(record["submitted"]) - prev["leased"]
             want_r = r0 + prev["leased"] - prev["preempted"] - len(record["completed"])
             if counts != (want_q, want_r):
                 self._bad(

@@ -2,7 +2,9 @@
 `layers/<name>.json`, whose `read` object says where the number comes from:
 
     {"kind": "cycle_field", "field": "wire_s", "reduce": "median"}
-        a field of the per-cycle record (counters, host clocks)
+        a field of the per-cycle record (counters, host clocks); a dotted name
+        goes into a group of the record: `pool.<key>` is any scalar of the
+        round's own stats JSON, `scatter_rows.sg_rows` an arg of a span
     {"kind": "span_sum", "spans": ["assemble"], "reduce": "median"}
         per cycle, the seconds inside the named program spans (a span nested
         in another named span counts once), then the reduction over cycles;
@@ -56,6 +58,13 @@ def span_seconds(tree: dict, names) -> float:
     return sum(span_seconds(c, names) for c in tree.get("children", ()))
 
 
+def field_of(record: dict, dotted: str):
+    """`record["a"]["b"]` for "a.b"; None where a part is missing."""
+    for key in dotted.split("."):
+        record = record.get(key) if isinstance(record, dict) else None
+    return record
+
+
 def read(spec: dict, ctx: dict):
     """The value `spec` describes, or None when there is nothing to read.
     `ctx` holds the window's per-cycle records (`cycles`), the device-trace
@@ -66,7 +75,7 @@ def read(spec: dict, ctx: dict):
     if spec.get("cycles") == "traced":
         cycles = [c for c in cycles if c.get("traced")]
     if kind == "cycle_field":
-        xs = [c[spec["field"]] for c in cycles if c.get(spec["field"]) is not None]
+        xs = [x for x in (field_of(c, spec["field"]) for c in cycles) if x is not None]
         return _REDUCE[spec.get("reduce", "median")](xs) if xs else None
     if kind == "span_sum":
         names = set(spec["spans"])

@@ -115,7 +115,8 @@ class Run:
         self.seen_traces: set = set()
         self.cycles: list = []  # every cycle's record, warm-ups included
         self.requests: dict = {}  # cycle number -> prebuilt SyncStateRequest
-        self.leased: dict = {}  # cycle number -> [(job number, RoundLease)]
+        self.leased: dict = {}  # cycle number -> {job number: RoundLease}, live leases only
+        self.leased_at: dict = {}  # job number -> the cycle that leased it
         self.k = 0
         self.diag: dict = {}
 
@@ -143,6 +144,7 @@ class Run:
             self.world,
             cap=int(block["maximumSchedulingBurst"]),
             queue_cap=int(block["maximumPerQueueSchedulingBurst"]),
+            priority_classes=block["priorityClasses"],
         )
 
     def load_mirror(self) -> None:
@@ -190,15 +192,30 @@ class Run:
 
     def prepare(self, k: int):
         """Between cycles: cycle k's request, with the terminal states of the
-        jobs leased `lifetime` cycles earlier appended."""
+        jobs leased `lifetime` cycles earlier and still running appended (a job
+        the scheduler preempted since has left the books: `forget`)."""
         if k not in self.requests:
             self.prebuild(1)  # the estimate of cycles per window fell short
         req, submitted = self.requests.pop(k)
-        done = self.leased.pop(k - self.lifetime, [])
+        done = self.leased.pop(k - self.lifetime, {})
         ran_from = NOW0_NS + (k - self.lifetime + 1) * self.step_ns
-        for i, lease in done:
+        for i, lease in done.items():
+            del self.leased_at[i]
             req.jobs.append(self.world.terminal_state(i, lease, ran_from))
-        return req, submitted, [i for i, _ in done]
+        return req, submitted, list(done)
+
+    def forget(self, job_id: str) -> None:
+        """A job the round reports preempted: the scheduler ended its run and
+        failed the job in its own mirror, so the client owes it no terminal
+        state and sends nothing more for it.  (An initial running job was
+        never in these books: the client completes none of them.)"""
+        try:
+            i = self.world.job_number(job_id)
+        except KeyError:
+            return  # an initial run, or an id the checker reports
+        k = self.leased_at.pop(i, None)
+        if k is not None:
+            del self.leased[k][i]
 
     def cycle(self, traced: bool = False) -> dict:
         """One SyncState + one ScheduleRound, timed from the client's side
@@ -251,20 +268,25 @@ class Run:
         rec["preempted"] = [m.job_id for m in resp.preempted]
         stats = json.loads(resp.pool_stats_json)
         pool = stats["pools"][0] if stats.get("pools") else {}
-        for key in ("num_queued", "num_running", "num_nodes", "iterations", "kernel_iters"):
-            rec[key] = pool.get(key)
-        rec["termination"] = pool.get("termination")
+        # every scalar of the round's own stats: together as `pool`, and each
+        # under its own name where the record has not used it (`preempted` is
+        # the list of ids; a `cycle_field` reads the count as `pool.preempted`)
+        rec["pool"] = {key: v for key, v in pool.items() if not isinstance(v, (dict, list))}
+        for key, v in rec["pool"].items():
+            rec.setdefault(key, v)
         rec["device"] = {
             key: stats["device"].get(key)
             for key in ("backend", "platform", "device_kind", "device_count", "fallbacks")
         }
-        known = []
+        known = self.leased[k] = {}
         for m in resp.scheduled:
             try:
-                known.append((self.world.job_number(m.job_id), m))
+                known[self.world.job_number(m.job_id)] = m
             except KeyError:
                 pass  # the checker reports it
-        self.leased[k] = known
+        self.leased_at.update(dict.fromkeys(known, k))
+        for job_id in rec["preempted"]:
+            self.forget(job_id)
         # the sidecar's two roots of this cycle (the plane's own idle
         # scheduler loop also leaves traces in the ring: not this cycle's)
         rec["spans"] = [
@@ -375,8 +397,24 @@ def histogram(values, bins: int = 12) -> list:
     return [[round(lo + i * width, 4), c] for i, c in enumerate(counts)]
 
 
+def drift(window: list) -> tuple:
+    """How far `num_queued` and `num_running` moved between the window's first
+    and last cycle, each as {"value", "limit"} with the limit 0 (the window
+    stands still), and the problem if one moved."""
+    first, last = ((c.get("num_queued"), c.get("num_running")) for c in (window[0], window[-1]))
+    moved = f"not stationary: queued, running {first} -> {last}"
+    if None in first or None in last:
+        return {}, moved
+    out = {
+        name: {"value": abs(b - a), "limit": 0}
+        for name, a, b in zip(("queued_drift", "running_drift"), first, last)
+    }
+    return out, (moved if first != last else None)
+
+
 def verdict(run: Run, window: list, on_tpu: bool) -> tuple:
-    """(failed cycles {k: why}, problems that make the whole run incorrect)."""
+    """(failed cycles {k: why}, problems that make the whole run incorrect,
+    every number compared with its limit {name: {"value", "limit"}})."""
     for n, c in enumerate(run.cycles):
         run.checker.cycle(n, c)
     failed = {}
@@ -389,6 +427,7 @@ def verdict(run: Run, window: list, on_tpu: bool) -> tuple:
             failed.setdefault(n, "checker")
     verify = run.verify
     problems = list(run.checker.violations)
+    checks = {"checker_violations": {"value": len(problems), "limit": 0}}
     compiled = sum(c["compiles"] for c in window)
     if compiled:
         problems.append(f"{compiled} programs compiled or fetched inside the window")
@@ -400,10 +439,17 @@ def verdict(run: Run, window: list, on_tpu: bool) -> tuple:
         )
     if not run.diag["warm_clean"]:
         problems.append("warm-up never reached three cycles in a row without a compile")
-    ends = [(c.get("num_queued"), c.get("num_running")) for c in (window[0], window[-1])]
-    if ends[0] != ends[1]:
-        problems.append(f"not stationary: queued, running {ends[0]} -> {ends[1]}")
-    return failed, problems
+    drifts, moved = drift(window)
+    if moved:
+        problems.append(moved)
+    checks.update(
+        failed_cycles={"value": len(failed), "limit": 0},
+        compiles_in_window={"value": compiled, "limit": 0},
+        verify_failures={"value": verify["failures"], "limit": 0},
+        rounds_unverified={"value": max(0, len(run.cycles) - verify["rounds_verified"]), "limit": 0},
+        **drifts,
+    )
+    return failed, problems, checks
 
 
 def write_record(run: Run, summary: dict, out_dir: str, on_tpu: bool) -> str:
@@ -412,12 +458,13 @@ def write_record(run: Run, summary: dict, out_dir: str, on_tpu: bool) -> str:
         say(f"{key}: {json.dumps(summary[key])}")
     cache = {k: v for k, v in summary["compile_cache"].items() if k != "backend_compiles"}
     say(f"setup {json.dumps(summary['setup'])}; compile cache {json.dumps(cache)}")
-    drop = ("spans", "leases", "preempted", "submitted", "completed", "t_start", "t_end")
+    drop = ("spans", "leases", "preempted", "submitted", "completed", "t_start", "t_end", "pool")
     per_cycle = []
     for c in run.cycles:
         row = {key: v for key, v in c.items() if key not in drop}
         row["leases"] = len(c["leases"])
         row["completions"] = len(c["completed"])
+        row["preempted"] = len(c["preempted"])
         row["at_s"] = c["t_start"] - run.t_window
         row["span_s"] = {}
         for tree in c.get("spans", ()):
@@ -478,7 +525,7 @@ def run_cell(args, t0: float) -> tuple:
             raw_trace = tracered.load_xplane(files[-1], names)
     setup_s = run.setup_end - t0
     window = [c for c in run.cycles if c["phase"] == "window"]
-    failed, problems = verdict(run, window, on_tpu)
+    failed, problems, checks = verdict(run, window, on_tpu)
 
     trace = {}
     if args.trace:
@@ -541,6 +588,7 @@ def run_cell(args, t0: float) -> tuple:
             "device_ops": trace["device_ops"],
             "idle_gaps": trace["idle_gaps"],
         }
+    result["checks"] = checks  # every number compared, beside its limit: the line's last key
 
     def prefix_stats(limit):
         xs = [c["wall_s"] for c in window if c["t_end"] - run.t_window <= limit]
@@ -580,6 +628,11 @@ def run_cell(args, t0: float) -> tuple:
         "verify": run.verify,
         "failed_cycles": failed,
         "problems": problems,
+        "books": {
+            "live_leases": len(run.leased_at),
+            "initial_runs_live": int(run.checker.run_live.sum()),
+            "preempted_in_window": sum(len(c["preempted"]) for c in window),
+        },
         "histograms": run.world.histograms(),
         "trace_reduction": trace,
         "result": result,

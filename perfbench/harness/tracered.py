@@ -12,9 +12,9 @@ events are the `TraceAnnotation`s whose names the caller asks for (the
 program's spans, bridged by its `ARMADA_TRACE_JAX=1`, and the harness's own
 `perfbench_cycle` marker).  Both are on the profiler's clock.
 
-No operation in the program carries a stable name yet (no `jax.named_scope`
-under `armada_tpu/`), so the round kernel is found structurally: of the
-top-level `while` operations on the device, the one with the most device time.
+The profiler puts no scope on a device event (PERF.md section 3), so the round
+kernel is found structurally: of the top-level `while` operations on the
+device, the one with the most device time.
 """
 
 from __future__ import annotations
@@ -71,15 +71,25 @@ def covered(merged, lo: float, hi: float) -> float:
     return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in merged)
 
 
+def _ns(seconds: float) -> int:
+    """Whole nanoseconds: the profiler's own grid (`load_xplane` made the
+    seconds from integer `start_ns` / `duration_ns`, and this recovers them)."""
+    return round(seconds * 1e9)
+
+
 def top_level(events) -> list:
     """Events not inside another event of the same line (a `while`'s body ops
-    are traced as events nested in it)."""
+    are traced as events nested in it).  Nesting is decided on whole
+    nanoseconds: the device runs operations back to back on a 1 ns grid, and a
+    float `start + dur` rounds above the next event's start often enough to
+    take a top-level call for a child of the one before it."""
     out = []
-    end = float("-inf")
+    end = None
     for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
-        if start >= end:
+        at = _ns(start)
+        if end is None or at >= end:
             out.append([name, start, dur])
-            end = start + dur
+            end = at + _ns(dur)
     return out
 
 

@@ -12,6 +12,11 @@ differences that make runs repeat:
 * the wire messages are built straight from the tables (one `JobSpec` template
   per distinct shape), with no intermediate object per job.
 
+The initial running set is `running_jobs` spread evenly over the nodes, or,
+where the `world` block has `running_fill`, as many as fill every node to that
+share of its own size (`_fill`); `running_queue_demand` "1/k" gives the first
+queues more of it than their fair share.
+
 Nothing here imports the scheduler: the tables are also what the plain checker
 (`checker.py`) holds the program's answers against.
 """
@@ -94,7 +99,6 @@ class World:
         self.extend(int(sizes["queued_jobs"]), 0.0)
 
         # the initial running set
-        n_runs = int(sizes["running_jobs"])
         run_shapes, run_w = [], []
         rp = float(sizes["running_preemptible_share"])
         for cpu in sizes["running_cpu_milli"]:
@@ -105,13 +109,66 @@ class World:
         self.run_shape_req = np.asarray(
             [(cpu, mem * MILLI) for cpu, mem, _ in run_shapes], dtype=np.int64
         )
-        self.run_shape = stratified(rng, n_runs, run_w).astype(np.int32)
-        self.run_queue = stratified(rng, n_runs, np.ones(n_queues)).astype(np.int32)
-        # spread over the fleet: a seeded order of the nodes, wrapped
-        self.run_node = np.resize(rng.permutation(n_nodes), n_runs).astype(np.int64)
+        demand = sizes.get("running_queue_demand", "uniform")
+        if demand not in ("uniform", "1/k"):
+            raise ValueError(f"running_queue_demand {demand!r}: 'uniform' or '1/k'")
+        fill = sizes.get("running_fill")
+        if fill is None:
+            self.run_shape = stratified(rng, int(sizes["running_jobs"]), run_w).astype(np.int32)
+        elif "running_jobs" in sizes:
+            raise ValueError("running_fill derives running_jobs: give one of the two")
+        else:
+            self.run_shape, self.run_node = self._fill(rng, float(fill), rp)
+        n_runs = len(self.run_shape)
+        self.run_queue = stratified(
+            rng, n_runs, self.queue_weights if demand == "1/k" else np.ones(n_queues)
+        ).astype(np.int32)
+        if fill is None:
+            # spread over the fleet: a seeded order of the nodes, wrapped (drawn
+            # after the queues: the accepted configurations' order of draws)
+            self.run_node = np.resize(rng.permutation(n_nodes), n_runs).astype(np.int64)
 
         self._spec_templates = None
         self._run_spec_templates = None
+
+    def _fill(self, rng, fill: float, preemptible_share: float) -> tuple:
+        """The running set of a fleet filled by capacity: (run_shape, run_node).
+
+        Every node of one size carries the same running jobs, whatever the
+        seed: the running cpu sizes taken in turn, largest first, each added
+        while it fits into `fill` of the node's cores and memory, until the
+        smallest no longer does (so a node is within one smallest job of its
+        share, and never over it).  The seed decides which node has which size
+        (`node_cores`) and, per cpu size with exact counts, which of the runs
+        are preemptible."""
+        if not 0.0 <= fill <= 1.0:
+            raise ValueError(f"running_fill {fill}: a share from 0 to 1")
+        cpus = sorted({cpu for cpu, _, _ in self.run_shapes}, reverse=True)
+        mem = self.run_shape_req[0, 1]  # every running shape has `running_memory`
+        per_size = {}  # cores -> the cpu sizes one such node carries
+        for cores in np.unique(self.node_cores):
+            room = np.floor(fill * np.array([cores * MILLI, cores * int(self.sizes["memory_per_core"]) * MILLI]))
+            carried, turn = [], 0
+            while min(cpus) <= room[0] and mem <= room[1]:
+                cpu = cpus[turn % len(cpus)]
+                turn += 1
+                if cpu <= room[0]:
+                    carried.append(cpu)
+                    room -= (cpu, mem)
+            per_size[int(cores)] = carried
+        counts = np.array([len(per_size[int(c)]) for c in self.node_cores], dtype=np.int64)
+        run_node = np.repeat(np.arange(len(self.node_cores), dtype=np.int64), counts)
+        run_cpu = np.array(
+            [cpu for c in self.node_cores for cpu in per_size[int(c)]], dtype=np.int64
+        )
+        # shape index of (cpu, preemptible): the order the shapes were listed in
+        index = {(cpu, pre): i for i, (cpu, _, pre) in enumerate(self.run_shapes)}
+        run_shape = np.zeros(len(run_cpu), np.int32)
+        for cpu in sorted(cpus):
+            mine = np.flatnonzero(run_cpu == cpu)
+            pre = stratified(rng, len(mine), [preemptible_share, 1.0 - preemptible_share]) == 0
+            run_shape[mine] = np.where(pre, index[(cpu, True)], index[(cpu, False)])
+        return run_shape, run_node
 
     # ---------------------------------------------------------- tables ----
 
@@ -145,24 +202,48 @@ class World:
 
     def job_number(self, job_id: str) -> int:
         """The number of a backlog job, or KeyError for an id this world never
-        made (an initial running job is not a backlog job either)."""
+        made (an initial running job is not a backlog job: `run_number`)."""
         if len(job_id) == 10 and job_id[0] == "j" and job_id[1:].isdigit():
             i = int(job_id[1:])
             if i < self.num_jobs:
                 return i
         raise KeyError(job_id)
 
+    def run_number(self, job_id: str) -> int:
+        """The number of an initial running job, or KeyError."""
+        if len(job_id) == 9 and job_id[0] == "r" and job_id[1:].isdigit():
+            i = int(job_id[1:])
+            if i < len(self.run_shape):
+                return i
+        raise KeyError(job_id)
+
+    @staticmethod
+    def class_name(preemptible: bool) -> str:
+        """The priority class a shape's `preemptible` flag stands for."""
+        return "batch" if preemptible else "prod"
+
     def histograms(self) -> dict:
         """What a seed may not change: counts per category."""
         n0 = int(self.sizes["queued_jobs"])
-        return {
+        per_node = np.bincount(self.run_node, minlength=len(self.node_cores))
+        out = {
             "node_cores": np.bincount(self.node_cores).tolist(),
             "job_queue": np.bincount(self.job_queue[:n0], minlength=len(self.queue_names)).tolist(),
             "job_shape": np.bincount(self.job_shape[:n0], minlength=len(self.shapes)).tolist(),
             "run_shape": np.bincount(self.run_shape, minlength=len(self.run_shapes)).tolist(),
             "run_queue": np.bincount(self.run_queue, minlength=len(self.queue_names)).tolist(),
-            "runs_per_node_max": int(np.bincount(self.run_node).max()) if len(self.run_node) else 0,
+            "runs_per_node_max": int(per_node.max()) if len(per_node) else 0,
+            "running_jobs": len(self.run_shape),
+            # nodes by how many running jobs they carry and, in a fleet filled
+            # by capacity, by the share of their cores those jobs hold (whole %)
+            "runs_per_node": np.bincount(per_node).tolist(),
         }
+        if "running_fill" in self.sizes:
+            used_cpu = np.bincount(
+                self.run_node, weights=self.run_shape_req[self.run_shape, 0], minlength=len(per_node)
+            ).astype(np.int64)
+            out["node_fill_pct"] = np.bincount(100 * used_cpu // self.node_total[:, 0]).tolist()
+        return out
 
     # ------------------------------------------------------------ wire ----
     # Only these methods touch the program's protobuf modules (the wire
@@ -175,7 +256,7 @@ class World:
 
             def spec(cpu, mem, preemptible):
                 return epb.JobSpec(
-                    priority_class="batch" if preemptible else "prod",
+                    priority_class=self.class_name(preemptible),
                     resources=epb.Resources(
                         milli={"cpu": int(cpu), "memory": int(mem) * MILLI}
                     ),
@@ -253,7 +334,7 @@ class World:
                         node_id=node,
                         node_name=node,
                         pool=POOL,
-                        scheduled_at_priority=priorities["batch" if preemptible else "prod"],
+                        scheduled_at_priority=priorities[self.class_name(preemptible)],
                         has_scheduled_at_priority=True,
                         running=True,
                         running_ns=now_ns - 10**9,

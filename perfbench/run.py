@@ -4,7 +4,8 @@
     python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The last line of standard output is one JSON object (`correct`, `attempted`,
-`failed`, `metrics`, `device`, and `breakdown` when traced); earlier lines
+`failed`, `metrics`, `device`, `breakdown` when traced, and last `checks`:
+every number `correct` compared, beside its limit); earlier lines
 are diagnostics, and the per-cycle record goes to `perfbench_out/`.  With no
 TPU it exits 2 and prints no result.  See README.md beside this file.
 """
@@ -66,6 +67,10 @@ def main(argv=None) -> int:
         return 2
     if result is not None:
         print(json.dumps(result), flush=True)
+        # the run's last words on standard error: each number compared, beside its limit
+        for name, c in result["checks"].items():
+            print(f"perfbench check {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)
+        sys.stderr.flush()
     return code
 
 

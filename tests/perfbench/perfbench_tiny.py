@@ -19,9 +19,14 @@ def load(*parts):
         return json.load(f)
 
 
-def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3):
+def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3, full=None):
     """A benchmark root under `root` with one tiny cell, `tiny.steady-40`,
-    added as files only; returns the path of its BENCHMARK.json."""
+    added as files only; returns the path of its BENCHMARK.json.
+
+    `full` (a dict) makes it the tiny FULL-FLEET cell instead: the fleet
+    filled by capacity with the first queues over their share, so that rounds
+    preempt.  Its keys: `world` (updates of the world block, over
+    `running_fill` 1.0 and `running_queue_demand` "1/k")."""
     root = str(root)
     data = os.path.join(root, "perfbench")
     shutil.copytree(os.path.join(ROOT, "perfbench", "layers"), os.path.join(data, "layers"))
@@ -35,6 +40,13 @@ def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3):
         executors=2, mirror_chunk=1000,
     )
     config["scheduling"]["shapeBucket"] = 256
+    if full is not None:
+        del config["world"]["running_jobs"]
+        config["world"].update(
+            running_fill=1.0, running_queue_demand="1/k", running_cpu_milli=[4000, 2000],
+            running_memory=8,
+        )
+        config["world"].update(full.get("world", {}))
     traffic = load("perfbench", "traffic", "steady-1k.json")
     traffic.update(
         name="steady-40", submits_per_cycle=burst, cap=burst,
@@ -45,10 +57,16 @@ def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3):
         "better": "lower", "moves": "cycle_p50_s", "source": "program_counter",
         "read": {"kind": "cycle_field", "field": "downloads", "reduce": "median"},
     }
+    # a counter of the round's own stats JSON, read as a file and no code
+    pooled = dict(
+        extra, name="preempted_per_cycle",
+        read={"kind": "cycle_field", "field": "pool.preempted", "reduce": "max"},
+    )
     for path, doc in (
         (os.path.join(data, "configs", "tiny.json"), config),
         (os.path.join(data, "traffic", "steady-40.json"), traffic),
         (os.path.join(data, "layers", "downloads_per_cycle.json"), extra),
+        (os.path.join(data, "layers", "preempted_per_cycle.json"), pooled),
     ):
         with open(path, "w", encoding="utf-8") as f:
             json.dump(doc, f)
@@ -62,7 +80,8 @@ def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3):
     ]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
-    bench["per_layer"].append({k: extra[k] for k in ("name", "unit", "better", "source", "layer", "moves")})
+    for added in (extra, pooled):
+        bench["per_layer"].append({k: added[k] for k in ("name", "unit", "better", "source", "layer", "moves")})
     path = os.path.join(root, "BENCHMARK.json")
     with open(path, "w", encoding="utf-8") as f:
         json.dump(bench, f)

@@ -11,6 +11,7 @@ SIZES = dict(
     load("perfbench", "configs", "cluster-100k-5k.json")["world"],
     nodes=50, queued_jobs=400, running_jobs=20, queues=4,
 )
+CLASSES = load("perfbench", "configs", "cluster-100k-5k.json")["scheduling"]["priorityClasses"]
 
 
 def honest(world, cap=10, cycles=4):
@@ -38,7 +39,7 @@ def honest(world, cap=10, cycles=4):
 
 
 def run(world, records, cap=10):
-    c = Checker(world, cap=cap, queue_cap=cap)
+    c = Checker(world, cap=cap, queue_cap=cap, priority_classes=CLASSES)
     for n, r in enumerate(records):
         c.cycle(n, r)
     return c
@@ -112,7 +113,156 @@ def test_doctored_response_is_rejected(doctor, expect):
 
 def test_per_queue_cap():
     w = World(SIZES, 3)
-    c = Checker(w, cap=10, queue_cap=2)
+    c = Checker(w, cap=10, queue_cap=2, priority_classes=CLASSES)
     for n, r in enumerate(honest(w)):
         c.cycle(n, r)
     assert any("per-queue cap" in v for v in c.violations)
+
+
+# ---- preemption: invariants 6-8 and the corrected count arithmetic ----
+
+
+def preempting(world, cap=10, cycles=5):
+    """`honest`, with a round that preempts: cycle 1 ends the run of a `batch`
+    job leased in cycle 0 and of a `batch` job of the initial running set.  The
+    client then never completes the first, and the scheduler's running count
+    is one lower from cycle 2 on (two runs ended, one completion fewer)."""
+    recs = honest(world, cap, cycles)
+    victim = next(i for i in range(cap) if world.shapes[world.job_shape[i]][2])
+    initial = next(r for r, s in enumerate(world.run_shape) if world.run_shapes[s][2])
+    recs[1]["preempted"] = [world.job_id(victim), f"r{initial:08d}"]
+    recs[2]["completed"].remove(victim)
+    for r in recs[2:]:
+        r["num_running"] -= 1
+    return recs, victim, initial
+
+
+def check(world, records, cap=10):
+    c = Checker(world, cap=cap, queue_cap=cap, priority_classes=CLASSES)
+    for n, r in enumerate(records):
+        c.cycle(n, r)
+    return c
+
+
+def test_honest_preemption_passes_and_frees_the_right_nodes():
+    w = World(SIZES, 3)
+    recs, victim, initial = preempting(w)
+    before = Checker(w, cap=10, queue_cap=10, priority_classes=CLASSES).used.copy()
+    c = check(w, recs[:2])
+    assert c.violations == []
+    # the initial run's room is back on ITS node, the leased job's on the node it was leased to
+    node = w.run_node[initial]
+    leased_to = w.node_index[recs[0]["leases"][victim][1]]
+    expect = before.copy()
+    for k in (0, 1):
+        for job_id, node_id, _ in recs[k]["leases"]:
+            expect[w.node_index[node_id]] += w.shape_req[w.job_shape[w.job_number(job_id)]]
+    expect[node] -= w.run_shape_req[w.run_shape[initial]]
+    expect[leased_to] -= w.shape_req[w.job_shape[victim]]
+    assert (c.used == expect).all()
+    assert not c.run_live[initial] and c.run_live.sum() == len(w.run_shape) - 1
+    assert check(w, recs).violations == []  # counts: queued by submits - leases, running less the preempted
+
+
+def _then_completed(w, recs, victim, initial):
+    recs[2]["completed"].append(victim)
+
+
+def _then_leased(w, recs, victim, initial):
+    recs[3]["leases"][0] = recs[0]["leases"][victim]
+
+
+def _prod_preempted(w, recs, victim, initial):
+    prod = next(i for i in range(10) if not w.shapes[w.job_shape[i]][2])
+    recs[1]["preempted"][0] = w.job_id(prod)
+
+
+def _prod_initial_preempted(w, recs, victim, initial):
+    prod = next(r for r, s in enumerate(w.run_shape) if not w.run_shapes[s][2])
+    recs[1]["preempted"][1] = f"r{prod:08d}"
+
+
+def _leased_and_preempted(w, recs, victim, initial):
+    recs[1]["preempted"].append(recs[1]["leases"][0][0])
+
+
+def _preempted_twice(w, recs, victim, initial):
+    recs[2]["preempted"] = [w.job_id(victim)]
+
+
+def _initial_preempted_twice(w, recs, victim, initial):
+    recs[2]["preempted"] = [f"r{initial:08d}"]
+
+
+def _never_leased(w, recs, victim, initial):
+    recs[1]["preempted"][0] = w.job_id(399)  # queued, holds no lease
+
+
+def _unknown_initial(w, recs, victim, initial):
+    recs[1]["preempted"][1] = "r00000020"  # the running set has 20: r00000000..19
+
+
+def _requeued(w, recs, victim, initial):
+    for r in recs[2:]:
+        r["num_queued"] += 1  # the accepted checker's arithmetic: a preempted job back in the backlog
+
+
+def _still_running(w, recs, victim, initial):
+    for r in recs[2:]:
+        r["num_running"] += 2  # the mirror kept the runs the round says it ended
+
+
+@pytest.mark.parametrize(
+    "doctor,expect",
+    [
+        (_then_completed, "that was preempted"),
+        (_then_leased, "leased after it was preempted"),
+        (_prod_preempted, "not preemptible"),
+        (_prod_initial_preempted, "not preemptible"),
+        (_leased_and_preempted, "leased and preempted in one round"),
+        (_preempted_twice, "twice"),
+        (_initial_preempted_twice, "twice"),
+        (_never_leased, "holds no lease"),
+        (_unknown_initial, "unknown job"),
+        (_requeued, "scheduler counts"),
+        (_still_running, "scheduler counts"),
+    ],
+)
+def test_doctored_preemption_is_rejected(doctor, expect):
+    w = World(SIZES, 3)
+    recs, victim, initial = preempting(w)
+    doctor(w, recs, victim, initial)
+    c = check(w, recs)
+    assert any(expect in v for v in c.violations), c.violations
+    assert c.bad_cycles
+
+
+def test_capacity_is_checked_where_a_preemption_freed_room():
+    """A node filled to the brim by its initial runs: a lease there passes only
+    in the round that preempts enough of them, and the node is looked at even
+    in a round that only preempts on it."""
+    sizes = {k: v for k, v in SIZES.items() if k != "running_jobs"}
+    sizes.update(running_fill=1.0, running_cpu_milli=[8000], running_memory=32)
+    w = World(sizes, 3)
+    victim = next(r for r, s in enumerate(w.run_shape) if w.run_shapes[s][2])
+    node = w.node_ids[w.run_node[victim]]
+    job = next(i for i in range(400) if w.shapes[w.job_shape[i]][0] == 4000)
+    lease = (w.job_id(job), node, w.queue_names[w.job_queue[job]])
+    rec = dict(submitted=[], completed=[], leases=[lease], preempted=[], num_queued=400, num_running=len(w.run_shape))
+    assert any("holds" in v for v in check(w, [rec]).violations)
+    assert check(w, [dict(rec, preempted=[f"r{victim:08d}"])]).violations == []
+    # a preemption alone touches the node: an overfull node shows in that round
+    c = Checker(w, cap=10, queue_cap=10, priority_classes=CLASSES)
+    c.used[w.run_node[victim]] += 2 * w.run_shape_req[w.run_shape[victim]]
+    c.cycle(0, dict(rec, leases=[], preempted=[f"r{victim:08d}"]))
+    assert any("holds" in v for v in c.violations)
+
+
+def test_the_configuration_decides_which_class_is_preemptible():
+    w = World(SIZES, 3)
+    recs, victim, initial = preempting(w)
+    none = {name: dict(pc, preemptible=False) for name, pc in CLASSES.items()}
+    c = Checker(w, cap=10, queue_cap=10, priority_classes=none)
+    for n, r in enumerate(recs):
+        c.cycle(n, r)
+    assert sum("not preemptible" in v for v in c.violations) == 2

@@ -3,6 +3,7 @@ files only."""
 
 import json
 import os
+import re
 
 import pytest
 
@@ -107,3 +108,78 @@ def test_cell_mix_and_span_sum_metric_added_as_files_only(tmp_path):
     assert layers.read(found["submit_many_s"]["read"], CTX) == pytest.approx(0.3)
     with pytest.raises(CellError):
         Cell(bench, "no.such-cell")
+
+
+def test_window_refill_share_reads_the_two_counters():
+    from perfbench_tiny import load
+
+    spec = load("perfbench", "layers", "window_refill_share.json")["read"]
+    cycles = [dict(c, window_refills=n) for c, n in zip(CYCLES, (14, 16, 12))]
+    got = layers.read(spec, dict(CTX, cycles=cycles))
+    assert got == pytest.approx(100.0 * (14 + 16 + 12) / (1000 + 1010 + 1005))
+    # a program without the counter: nothing to read, the metric is left out
+    assert layers.read(spec, CTX) is None
+
+
+POOL_CYCLES = [
+    {"preempted": ["r00000001", "j000000007"], "pool": {"preempted": 2, "scheduled": 40, "termination": "cap"},
+     "scatter_rows": {"sg_rows": 512}},
+    {"preempted": [], "pool": {"preempted": 0, "scheduled": 38, "brand_new_counter": 5}, "scatter_rows": None},
+    {"preempted": [], "error": "UNAVAILABLE"},  # a cycle with no response has no stats
+]
+
+
+@pytest.mark.parametrize(
+    "field,reduce,want",
+    [
+        ("pool.preempted", "sum", 2),  # the record's own `preempted` is the list of ids
+        ("pool.scheduled", "min", 38),
+        ("pool.brand_new_counter", "max", 5),  # a counter a later program adds: no code, a file
+        ("scatter_rows.sg_rows", "max", 512),
+        ("pool.no_such_counter", "sum", None),  # nothing to read: the metric is left out
+        ("pool.preempted.deeper", "sum", None),
+        ("no_such_group.x", "sum", None),
+    ],
+)
+def test_cycle_field_reads_into_a_group_of_the_record(field, reduce, want):
+    spec = {"kind": "cycle_field", "field": field, "reduce": reduce}
+    assert layers.read(spec, dict(CTX, cycles=POOL_CYCLES)) == want
+
+
+def test_no_shipped_layer_file_reads_a_field_the_record_lacks(tmp_path):
+    """Every counter a shipped layer file reads as a `cycle_field` is in a
+    cycle's record as the runner builds it (the tiny cell's first round)."""
+    import glob
+
+    from perfbench_tiny import ROOT
+    from perfbench.harness.runner import Run
+
+    def fields(spec):
+        if isinstance(spec, dict):
+            if spec.get("kind") == "cycle_field":
+                yield spec["field"]
+            for v in spec.values():
+                yield from fields(v)
+
+    run = Run(Cell(make_tiny(tmp_path), "tiny.steady-40"), 3, 1.0, False)
+    run.start(str(tmp_path / "data"))
+    try:
+        run.load_mirror()
+        rec = run.cycle()
+    finally:
+        run.stop()
+    assert rec["pool"]["preempted"] == 0 and rec["pool"]["scheduled"] == 40 == rec["scheduled"]
+    assert rec["preempted"] == [] and rec["termination"] == rec["pool"]["termination"]
+    assert not [k for k, v in rec["pool"].items() if isinstance(v, (dict, list))]
+    for path in glob.glob(os.path.join(ROOT, "perfbench", "layers", "*.json")):
+        for field in fields(json.load(open(path))):
+            if not field.endswith("_s"):  # the clocks: some are the window's to take
+                assert layers.field_of(rec, field) is not None, (path, field)
+
+
+def test_the_checker_imports_nothing_of_the_program():
+    from perfbench_tiny import ROOT
+
+    for name in ("checker.py", "layers.py", "roofline.py"):
+        src = open(os.path.join(ROOT, "perfbench", "harness", name), encoding="utf-8").read()
+        assert not re.search(r"^\s*(from|import)\s+armada_tpu|__import__|importlib", src, re.M), name

@@ -115,3 +115,33 @@ def test_reduction_of_the_recorded_chip_trace():
         assert r[key] == pytest.approx(want[key], rel=1e-6), key
     assert 0 < r["device_idle_share_pct"] < 100
     assert [n for n, _ in r["idle_gaps"]][:3] == want["top_gaps"]
+
+
+def _back_to_back():
+    """Two events on the device's 1 ns grid, the second starting on the very
+    nanosecond the first ends, whose float seconds say otherwise: start + dur
+    of the first rounds above the start of the second."""
+    for start_ns in range(733_546_492, 733_546_492 + 5000):
+        for dur_ns in (216_059_336, 95_000_003, 1_234_567):
+            s, d, nxt = start_ns * 1e-9, dur_ns * 1e-9, (start_ns + dur_ns) * 1e-9
+            if s + d > nxt:
+                return s, d, nxt
+    raise AssertionError("no such pair in the range searched")
+
+
+def test_top_level_decides_nesting_on_whole_nanoseconds():
+    s, d, nxt = _back_to_back()
+    assert s + d > nxt  # what `start >= end` on float seconds tripped over
+    events = [["fusion.1", s, d], ["while.39", nxt, 0.09], ["fusion.7", nxt + 1e-3, 0.01]]
+    top = tracered.top_level(events)
+    assert [e[0] for e in top] == ["fusion.1", "while.39"]  # the call is kept, its body op nested
+    # an event that starts one nanosecond before the other ends IS inside it
+    inside = [["while.39", s, d], ["fusion.7", nxt - 1e-9, 1e-9]]
+    assert [e[0] for e in tracered.top_level(inside)] == ["while.39"]
+    # and the kernel's seconds count every call
+    trace = {
+        "device": {"/device:TPU:0": [["fusion.1", s, d], ["while.39", nxt, 0.09]]},
+        "host": [[M, s - 0.01, d + 0.2]],
+    }
+    r = tracered.reduce_trace(trace)
+    assert r["kernel_calls"] == 1 and r["kernel_device_s_total"] == pytest.approx(0.09)
