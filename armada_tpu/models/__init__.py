@@ -1084,6 +1084,11 @@ def _finish_body(h: _RoundHandle):
                 exp_dispatched, ctx, outcome
             )
     outcome.pool_totals = ctx.pool_total_atoms
+    outcome.queue_axis = {
+        "queues": ctx.num_real_queues,
+        "queues_padded": ctx.queues_padded,
+        "queues_pending": ctx.queues_pending,
+    }
     return result, outcome
 
 
@@ -1164,8 +1169,11 @@ def collect_round_stats(result, problem, ctx, config, outcome) -> None:
     if callable(result):
         result = result()
     from armada_tpu.models.problem import queue_stats_from_result
+    from armada_tpu.ops.trace import recorder as _trace
 
-    outcome.queue_stats = queue_stats_from_result(result, problem, ctx)
+    with _trace().span("queue_stats", queues=ctx.num_real_queues):
+        outcome.queue_stats, iterations = queue_stats_from_result(result, problem, ctx)
+    outcome.queue_axis["fair_share_iterations"] = iterations
     if config.indicative_share_base_priorities:
         from armada_tpu.ops.fairness import theoretical_share
 

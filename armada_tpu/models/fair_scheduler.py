@@ -246,7 +246,11 @@ def _skip_window(p: SchedulingProblem, q_start, q_head, g_state, key_bad, check_
     gather costs 7-13 ns an ELEMENT over a floor of ~1 us, so all Q = 256
     rows are 29 us apiece and one row is the floor (PERF.md, PR 26) -- the
     loop calls this on the rows that moved, and on all of them only when it
-    must."""
+    must.  At Q = 1,024 (925 queues; PERF.md, PR 28) a round of 1,002 trips
+    takes the full gather TWICE, where 64 queues take it ~15 times: the
+    `window` conditional averages 14.4 us a trip there against 13.5, so
+    the refills are not what a thousand queues pay for; the body's [Q]- and
+    [Q, W]-wide fusions are (a trip is 200 us for 135)."""
     G = p.g_req.shape[0]
     offs = q_head[:, None] + jnp.arange(_SKIP_WINDOW, dtype=jnp.int32)[None, :]
     slot = jnp.clip(q_start[:, None] + offs, 0, G - 1)
@@ -325,8 +329,10 @@ def _make_place_iteration(
     is the [Q, W] window of _skip_window.  Gathering it costs three dependent
     [Q, W] gathers from [G] arrays, which on the v5e is 7 ns an ELEMENT
     (29 us each at Q = 256, against a per-operation floor of 1-2 us: PERF.md,
-    PR 26), and a trip changes almost none of it.  So the window is loop
-    state (_Carry.w_gang / w_key / w_skip), with one invariant:
+    PR 26; four times the elements at Q = 1,024, where PR 28 measured two
+    refills in a round's 1,002 trips), and a trip changes almost none of
+    it.  So the window is loop state (_Carry.w_gang / w_key / w_skip), with
+    one invariant:
     AT THE TOP OF EVERY TRIP THE CARRIED TABLES EQUAL
     _skip_window(p, p.q_start, c.q_head, c.g_state, c.key_bad, check_keys).
     Everything the body derives from them (in_r, skippable, nskip, q_head,

@@ -56,6 +56,7 @@ from armada_tpu.models.problem import (
     HostContext,
     SchedulingProblem,
     _pad,
+    queues_pending,
 )
 from armada_tpu.ops.trace import recorder as _trace
 
@@ -1814,11 +1815,12 @@ class IncrementalBuilder:
             burst_cfg = max(0, min(burst_cfg, int(global_tokens)))
         perq_cfg = cfg.maximum_per_queue_scheduling_burst or 2**31 - 1
         perq_burst = np.full((Q,), 2**31 - 1, np.int32)
-        for qname, qi in self.queue_by_name.items():
-            cap = perq_cfg
-            if queue_tokens is not None and qname in queue_tokens:
-                cap = max(0, min(cap, int(queue_tokens[qname])))
-            perq_burst[qi] = min(cap, 2**31 - 1)
+        with _trace().span("queue_caps", queues=Qreal):
+            for qname, qi in self.queue_by_name.items():
+                cap = perq_cfg
+                if queue_tokens is not None and qname in queue_tokens:
+                    cap = max(0, min(cap, int(queue_tokens[qname])))
+                perq_burst[qi] = min(cap, 2**31 - 1)
 
         max_card = int(g_card[:nreal_g].max()) if nreal_g else 1
         if max_card > 10_000:
@@ -1906,6 +1908,8 @@ class IncrementalBuilder:
             slot_width=W,
             type_names=[nt.hw_type for nt in self.ntidx.types],
             q_demand_raw=q_demand_raw,
+            queues_padded=Q,
+            queues_pending=queues_pending(q_len64, evq),
             pool_total_atoms={
                 name: int(round(float(total_pool64[i]) * self.factory.resolutions[i]))
                 for i, name in enumerate(self.factory.names)
@@ -2435,11 +2439,12 @@ class IncrementalBuilder:
                 burst_cfg = max(0, min(burst_cfg, int(global_tokens)))
             perq_cfg = cfg.maximum_per_queue_scheduling_burst or 2**31 - 1
             perq_burst = np.full((Q,), 2**31 - 1, np.int32)
-            for qname, qi in self.queue_by_name.items():
-                cap = perq_cfg
-                if queue_tokens is not None and qname in queue_tokens:
-                    cap = max(0, min(cap, int(queue_tokens[qname])))
-                perq_burst[qi] = min(cap, 2**31 - 1)
+            with trace.span("queue_caps", queues=Qreal):
+                for qname, qi in self.queue_by_name.items():
+                    cap = perq_cfg
+                    if queue_tokens is not None and qname in queue_tokens:
+                        cap = max(0, min(cap, int(queue_tokens[qname])))
+                    perq_burst[qi] = min(cap, 2**31 - 1)
 
             max_card = max((int(u["card"]) for (u, _, _, _) in kept_units), default=1)
             if max_card > 10_000:
@@ -2760,6 +2765,8 @@ class IncrementalBuilder:
                 slot_width=W,
                 type_names=[nt.hw_type for nt in self.ntidx.types],
                 q_demand_raw=q_demand_raw,
+                queues_padded=Q,
+                queues_pending=queues_pending(q_len64, evq),
                 pool_total_atoms={
                     name: int(round(float(total_pool64[i]) * self.factory.resolutions[i]))
                     for i, name in enumerate(self.factory.names)

@@ -167,6 +167,12 @@ class HostContext:
     # the denominator every published share is a fraction of).
     q_demand_raw: list = dataclasses.field(default_factory=list)
     pool_total_atoms: dict = dataclasses.field(default_factory=dict)
+    # The queue axis, for the round's counters (RoundOutcome.queue_axis):
+    # the kernel's padded Q, and the queues with at least one QUEUED
+    # candidate at the round's start (evictee slots, running jobs offered
+    # for re-placement, do not count).
+    queues_padded: int = 0
+    queues_pending: int = 0
     # Vectorized decode support (models/incremental.py): per-gang single job
     # id as bytes (b"" for evictee slots and multi-member units), overrides
     # for multi-member units, and per-run job ids as bytes.  When set,
@@ -236,6 +242,13 @@ class RoundOutcome:
     # demand_share} (feeds cycle metrics + reports; the reference's
     # QueueSchedulingContext numbers, cycle_metrics.go:71-170).
     queue_stats: dict = dataclasses.field(default_factory=dict)
+    # The round's queue-axis counters, for the stats JSON and the `round`
+    # span: `queues` (declared), `queues_padded` (the kernel's Q),
+    # `queues_pending` (HostContext's); `fair_share_iterations` (trips of
+    # the water-filling behind queue_stats) only where stats are collected;
+    # `queues_scheduled` (distinct queues leased from) is the caller's to
+    # add, who knows each job's queue (scheduler/algo.py).
+    queue_axis: dict = dataclasses.field(default_factory=dict)
     # Market pools: bid price of the gang that crossed the spot cutoff this
     # round (queue_scheduler.go:135-150); None when unset/not market.
     spot_price: Optional[float] = None
@@ -374,6 +387,15 @@ class ChainedJobIds:
 
     def __repr__(self):
         return f"ChainedJobIds(n={len(self)})"
+
+
+def queues_pending(q_len: np.ndarray, evictee_queue: np.ndarray) -> int:
+    """Queues holding a queued candidate: the per-queue candidate counts
+    `q_len[Q]` less the evictee slots among them (one bincount of the few
+    evictees, never a pass over the backlog)."""
+    return int(
+        np.count_nonzero(q_len > np.bincount(evictee_queue, minlength=q_len.shape[0]))
+    )
 
 
 def queue_ordered_gang_index(
@@ -1146,8 +1168,10 @@ def build_problem(
                 q_penalty[qi] = factory.ceil_units(atoms).astype(np.float32)
     demand_by_pc = np.zeros((len(sorted_queues), C, R), np.float64)
     nreal = len(gangs)
+    n_pending = 0
     if nreal:
         queued_mask = g_run[:nreal] < 0
+        n_pending = queues_pending(q_len, g_queue[:nreal][~queued_mask])
         contrib = g_req[:nreal].astype(np.float64) * g_card[:nreal, None]
         np.add.at(
             demand_by_pc,
@@ -1271,6 +1295,8 @@ def build_problem(
         max_slots=S,
         slot_width=W,
         q_demand_raw=q_demand_raw,
+        queues_padded=Q,
+        queues_pending=n_pending,
         pool_total_atoms={
             name: int(round(float(total_pool64[i]) * factory.resolutions[i]))
             for i, name in enumerate(factory.names)
@@ -1289,9 +1315,10 @@ def build_problem(
 _TERMINATIONS = ["exhausted", "global_burst", "round_resource_cap", "max_iterations"]
 
 
-def queue_stats_from_result(result, problem: SchedulingProblem, ctx: HostContext) -> dict:
-    """Per-queue share numbers from the final round state (fair shares are
-    recomputed host-side from the same inputs the kernel used)."""
+def queue_stats_from_result(result, problem: SchedulingProblem, ctx: HostContext) -> tuple:
+    """(per-queue share numbers from the final round state, trips of the
+    water-filling loop): fair shares are recomputed host-side from the same
+    inputs the kernel used."""
     from armada_tpu.ops.fairness import fair_shares, unweighted_drf_cost
 
     Q = int(problem.q_weight.shape[0])
@@ -1326,7 +1353,7 @@ def queue_stats_from_result(result, problem: SchedulingProblem, ctx: HostContext
             # cycle_metrics.go:443: unweighted cost of the penalty RL.
             "short_job_penalty": float(penalty[qi]),
         }
-    return out
+    return out, int(shares.iterations)
 
 
 # Caps for the packed single-transfer decode (decode_result fast path); a

@@ -44,6 +44,9 @@ class FairShares(NamedTuple):
     fair_share: jax.Array  # weight / sum-of-weights
     demand_capped_adjusted_fair_share: jax.Array  # share given current demand
     uncapped_adjusted_fair_share: jax.Array  # share if demand were infinite
+    # trips of the water-filling loop (1 when every queue wants its share or
+    # more; more where queues under their share hand capacity on)
+    iterations: jax.Array
 
 
 def theoretical_share(weights, constrained_demand_share, priority: float) -> float:
@@ -139,5 +142,5 @@ def _fair_shares_jit(weights, cds, *, max_iterations: int) -> FairShares:
         zeros,
         zeros,
     )
-    _, _, _, _, _, dcafs, ucafs = jax.lax.while_loop(cond, body, init)
-    return FairShares(fair_share, dcafs, ucafs)
+    iterations, _, _, _, _, dcafs, ucafs = jax.lax.while_loop(cond, body, init)
+    return FairShares(fair_share, dcafs, ucafs, iterations)

@@ -92,6 +92,13 @@ class Span:
     def dur_s(self) -> float:
         return max(0.0, self.t1 - self.t0)
 
+    def annotate(self, **args) -> None:
+        """Args known only once the span's work is done (bytes encoded,
+        queues leased from): ``with span(...) as s: ...; s.annotate(n=n)``."""
+        if self.args is None:
+            self.args = {}
+        self.args.update(args)
+
     def to_dict(self, base: float) -> dict:
         """Offset-based serialization (relative to ``base``): monotonic
         epochs differ across processes, so the wire form carries only
@@ -157,6 +164,9 @@ class _NoopSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def annotate(self, **args) -> None:
+        pass
 
 
 _NOOP = _NoopSpan()

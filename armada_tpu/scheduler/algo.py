@@ -83,6 +83,9 @@ class SchedulerResult:
     # LazyJobIds).
     failed: "object" = None
     pools: list = dataclasses.field(default_factory=list)  # list[PoolStats]
+    # The sidecar's stats JSON of `pools` (sidecar._stats_of), encoded inside
+    # the round's trace; None anywhere else.
+    stats_json: Optional[str] = None
 
     def __post_init__(self):
         if self.failed is None:
@@ -354,25 +357,32 @@ class FairSchedulingAlgo:
 
             bid_price_of = _pool_pricer("")
 
+        # The round's per-queue host work outside assemble, both under one
+        # span name (`queue_tokens`, twice a pool a round: the overrides,
+        # then the tokens), never a span a queue.
         def pool_queues(pool: str) -> list:
             if self.priority_overrides is None:
                 return queues
-            return [
-                (
-                    Queue(q.name, ov)
-                    if (ov := self.priority_overrides.override(pool, q.name))
-                    is not None
-                    else q
-                )
-                for q in queues
-            ]
+            with _trace().span("queue_tokens", pool=pool, queues=len(queues)):
+                return [
+                    (
+                        Queue(q.name, ov)
+                        if (ov := self.priority_overrides.override(pool, q.name))
+                        is not None
+                        else q
+                    )
+                    for q in queues
+                ]
 
         queue_names = [q.name for q in queues]
 
         def round_tokens():
-            return self.rate_limiters.tokens(queue_names)
+            with _trace().span("queue_tokens", queues=len(queue_names)):
+                return self.rate_limiters.tokens(queue_names)
 
-        def consume_round(outcome):
+        def consume_round(outcome) -> int:
+            """Charge the rate limiters; returns the distinct queues leased
+            from."""
             by_queue: dict[str, int] = {}
             for jid in outcome.scheduled:
                 job = job_of_spec.get(jid) or txn.get(jid)
@@ -380,9 +390,10 @@ class FairSchedulingAlgo:
                     by_queue[job.queue] = by_queue.get(job.queue, 0) + 1
             if by_queue:
                 self.rate_limiters.consume(by_queue)
+            return len(by_queue)
 
         def commit_outcome(
-            pool, outcome, *, num_queued, num_running, pool_nodes,
+            pool, outcome, *, num_queued, num_running, pool_nodes, round_span,
             market_b=None, running=(), bid_price_of=None, round_s=0.0,
             degraded=False,
         ):
@@ -391,7 +402,10 @@ class FairSchedulingAlgo:
             phase.  ALWAYS called in pool-list order: the cross-pool apply
             order (and so the event order) is identical in every mode."""
             nonlocal queued_jobs
-            consume_round(outcome)
+            # the queue-axis counters ride the stats JSON (sidecar._stats_of)
+            # and, like kernel_iters, the trace: here as args of `round`
+            outcome.queue_axis["queues_scheduled"] = consume_round(outcome)
+            round_span.annotate(**outcome.queue_axis)
             with _trace().span(
                 "apply_outcome",
                 pool=pool,
@@ -483,7 +497,7 @@ class FairSchedulingAlgo:
             pool = entry["pool"]
             sup = _supervisor()
             t0 = mono_now()
-            with _trace().span("round", pool=pool, parallel=True):
+            with _trace().span("round", pool=pool, parallel=True) as round_span:
                 res, outcome = fin()
             if self.collect_stats:
                 collect_round_stats(
@@ -506,6 +520,7 @@ class FairSchedulingAlgo:
                 pool_nodes=entry["pool_nodes"],
                 round_s=dt,
                 degraded=deg0 or failed or fb_now > fb_seen[0],
+                round_span=round_span,
             )
             fb_seen[0] = fb_now
 
@@ -660,7 +675,7 @@ class FairSchedulingAlgo:
                 sup = _supervisor()
                 deg0 = sup.degraded
                 fb0 = sup.fallbacks  # plain counter read: snapshot() takes the lock
-                with _trace().span("round", pool=pool, **span_kw):
+                with _trace().span("round", pool=pool, **span_kw) as round_span:
                     res, outcome = run_round_on_device(
                         pview,
                         ctx,
@@ -681,6 +696,7 @@ class FairSchedulingAlgo:
                     market_b=b, running=running, bid_price_of=bid_price_of,
                     round_s=dt,
                     degraded=deg0 or sup.fallbacks > fb0,
+                    round_span=round_span,
                 )
             else:
                 if not queued_jobs and not running:
@@ -691,7 +707,7 @@ class FairSchedulingAlgo:
                 deg0 = sup.degraded
                 fb0 = sup.fallbacks  # plain counter read: snapshot() takes the lock
                 t0 = mono_now()
-                with _trace().span("round", pool=pool, legacy=True):
+                with _trace().span("round", pool=pool, legacy=True) as round_span:
                     outcome = run_scheduling_round(
                         self.config,
                         pool=pool,
@@ -713,6 +729,7 @@ class FairSchedulingAlgo:
                     num_running=num_running, pool_nodes=pool_nodes,
                     running=running, bid_price_of=bid_price_of, round_s=dt,
                     degraded=deg0 or sup.fallbacks > fb0,
+                    round_span=round_span,
                 )
         flush_window()
         if pool_round_s:

@@ -391,6 +391,13 @@ class ScheduleSession:
                         slo_recorder().observe_pool_round(
                             ps.pool, ps.round_s, degraded=ps.degraded
                         )
+            # The response's stats JSON is encoded HERE, inside the round's
+            # trace, so that its cost (it grows with the queue count once
+            # queue_stats is collected) is a span of the cycle and not
+            # untraced time between the root and the wire.
+            with trace.span("stats_encode", pools=len(result.pools)) as enc:
+                result.stats_json = _stats_of(result)
+                enc.annotate(bytes=len(result.stats_json))
             return result
 
 
@@ -412,6 +419,9 @@ def _stats_of(result: SchedulerResult, trace: Optional[dict] = None) -> str:
             # of those, the trips that gathered the whole skip window again
             "window_refills": getattr(s.outcome, "window_refills", 0),
             "queue_stats": s.outcome.queue_stats,
+            # queues, queues_padded, queues_pending, queues_scheduled and,
+            # where queue_stats is collected, fair_share_iterations
+            **s.outcome.queue_axis,
         }
         if s.market:
             entry["indicative_prices"] = s.indicative_prices
@@ -539,8 +549,14 @@ class ScheduleSidecar:
                     d.setdefault("args", {})["pid"] = t.pid
                     trace_doc = d
                     break
+        # encoded inside the round's trace (schedule_round); only a caller
+        # that stitches pays a second dump, with the span tree in it
         resp = pb.ScheduleRoundResponse(
-            pool_stats_json=_stats_of(result, trace=trace_doc)
+            pool_stats_json=(
+                _stats_of(result, trace=trace_doc)
+                if trace_doc is not None
+                else result.stats_json
+            )
         )
         for job, run in result.scheduled:
             resp.scheduled.append(

@@ -14,7 +14,10 @@ solo / stacked x check_keys:
   and rescheduled runs, per-queue allocation, termination -- against the
   oracle, and all fields bit for bit against the K=1 solo round
   (``kernel_iters`` and ``window_refills`` count trips and differ with K);
-* ``window_refills``, pinned per world.
+* ``window_refills``, pinned per world;
+* the same at Q = 512 (PR 28): 300 queues whose heads all hold one key, so
+  that its registration retires 299 other heads at once and the whole
+  [512, W] window is gathered again.
 """
 
 import dataclasses
@@ -45,15 +48,16 @@ class _World:
     whole-node job fits a node's shape and never its free capacity), ten
     queues, and exactly GANGS gang slots."""
 
-    def __init__(self, config=CFG):
+    def __init__(self, config=CFG, nq=NQ, gangs=GANGS, nodes=8):
         self.config = config
+        self.nq, self.gangs = nq, gangs
         self.nodes = [
             NodeSpec(id=f"n{i:02d}", pool="default", total_resources=F.from_mapping({"cpu": 16, "memory": 64}))
-            for i in range(8)
+            for i in range(nodes)
         ]
-        self.queues = [Queue(f"q{i}", 1.0) for i in range(NQ)]
+        self.queues = [Queue(f"q{i}", 1.0) for i in range(nq)]
         self.jobs = []
-        self.running = [self._run(f"pin{i}", "q9", f"n{i:02d}", "high") for i in range(8)]
+        self.running = [self._run(f"pin{i}", "q9", f"n{i:02d}", "high") for i in range(nodes)]
 
     def _run(self, jid, queue, node, pc):
         spec = JobSpec(id=jid, queue=queue, priority_class=pc, submit_time=-100.0 + len(jid),
@@ -73,8 +77,8 @@ class _World:
         """1-core low jobs up to GANGS gang slots: every world has the same three keys
         (whole-node high, 1-core high, 1-core low) and so the same array shapes."""
         slots = len(self.jobs) + sum(r.job.priority_class == "low" for r in self.running)
-        assert slots < GANGS
-        return self.add(queue, 1, GANGS - slots, pc="low")
+        assert slots < self.gangs
+        return self.add(queue, 1, self.gangs - slots, pc="low")
 
 
 def _deep_skip():
@@ -127,10 +131,30 @@ def _exhausted():
     return w.fill(queue=7)
 
 
+WIDE_NQ, WIDE_GANGS, WIDE_NODES = 300, 768, 40  # Q = 512 and G = 768 under a shape bucket of 256
+
+
+def _wide_shared_key():
+    """`_shared_key` on the queue axis past its first 256-bucket: 300 queues, every head the
+    same whole-node job, so ONE failed fit retires 299 other queues' heads and the carried
+    [512, W] window is gathered whole; behind each head one 1-core job, and room for every
+    1-core job (40 nodes), so that the set placed does not depend on the order of trips
+    (without keys the 300 heads fail one by one, between other queues' placements)."""
+    w = _World(
+        dataclasses.replace(CFG, shape_bucket=256), nq=WIDE_NQ, gangs=WIDE_GANGS, nodes=WIDE_NODES
+    )
+    for q in range(WIDE_NQ):
+        w.add(q, WHOLE, 1)
+    for q in range(WIDE_NQ):
+        w.add(q, 1, 1)
+    return w.fill(queue=WIDE_NQ - 1)
+
+
 WORLDS = {
     "deep_skip": _deep_skip, "shared_key": _shared_key, "tails": _tails,
     "evictees": _evictees, "exhausted": _exhausted,
 }
+WIDE_WORLDS = {"wide_shared_key": _wide_shared_key}  # other shapes: never stacked with WORLDS
 # window_refills[world][(K, check_keys)].  A trip rebuilds K rows.  With K = 1 two cursors
 # move at once only after a key registration: one that retires another queue's head too,
 # or one whose queue is still skipping (its window skipped whole) while the next decided
@@ -141,16 +165,17 @@ REFILLS = {
     "tails": {(1, True): 0, (1, False): 0, (8, True): 0, (8, False): 0},
     "evictees": {(1, True): 1, (1, False): 0, (8, True): 0, (8, False): 0},
     "exhausted": {(1, True): 0, (1, False): 0, (8, True): 0, (8, False): 0},
+    "wide_shared_key": {(1, True): 1, (1, False): 0, (8, True): 1, (8, False): 0},
 }
 
 
 @functools.lru_cache(maxsize=None)
 def _built(name):
-    w = WORLDS[name]()
+    w = {**WORLDS, **WIDE_WORLDS}[name]()
     problem, ctx = build_problem(
         w.config, pool="default", nodes=w.nodes, queues=w.queues, queued_jobs=w.jobs, running=w.running
     )
-    assert ctx.num_real_gangs == GANGS == problem.g_req.shape[0], name
+    assert ctx.num_real_gangs == w.gangs == problem.g_req.shape[0], name
     oracle = _Oracle(w.config, w.nodes, w.queues, w.jobs, w.running)
     scheduled, preempted, rescheduled = oracle.run()
     q_alloc = np.stack([oracle.alloc[q.name] for q in sorted(w.queues, key=lambda q: q.name)])
@@ -162,7 +187,7 @@ def _statics(dev, ctx, commit_k):
     return dict(
         num_levels=len(ctx.ladder) + 2, max_slots=ctx.max_slots, slot_width=ctx.slot_width,
         **fs._resolve_round_statics(
-            compat_rows=dev.compat.shape[0], G=GANGS, Q=dev.q_weight.shape[0], max_iterations=0,
+            compat_rows=dev.compat.shape[0], G=dev.g_req.shape[0], Q=dev.q_weight.shape[0], max_iterations=0,
             prefer_large=False, cache_slots=0, unroll=1, batch_k=1, commit_k=commit_k,
         ),
     )
@@ -211,7 +236,7 @@ def _assert_oracle(name, result, ctx, expected):
     assert set(outcome.scheduled) == set(scheduled), name
     assert int(result.scheduled_count) == len(scheduled), name
     assert set(outcome.preempted) == preempted and set(outcome.rescheduled) == rescheduled, name
-    np.testing.assert_array_equal(np.asarray(result.q_alloc)[:NQ], q_alloc, err_msg=name)
+    np.testing.assert_array_equal(np.asarray(result.q_alloc)[: len(q_alloc)], q_alloc, err_msg=name)
     assert outcome.termination == "exhausted", name
 
 
@@ -251,6 +276,35 @@ def test_carried_window_is_the_gathered_window(commit_k, stacked, check_keys):
             )
         assert int(got.window_refills) == REFILLS[name][(commit_k, check_keys)], name
         assert int(got.window_refills) <= int(got.kernel_iters)
+
+
+@pytest.mark.parametrize("check_keys", [True, False], ids=["keys", "nokeys"])
+@pytest.mark.parametrize("commit_k", [1, 8], ids=["k1", "k8"])
+def test_carried_window_is_the_gathered_window_at_512_queues(commit_k, check_keys):
+    """The invariant, the oracle's decisions and the K = 1 round's fields on a queue axis
+    of 512 (300 real queues), through refills that gather all 512 x W entries again."""
+    name = "wide_shared_key"
+    dev, ctx, expected = _built(name)
+    assert (ctx.num_real_queues, dev.q_weight.shape[0]) == (WIDE_NQ, 512)
+    assert dev.g_req.shape[0] == WIDE_GANGS
+    run, trips = _round(commit_k, False, check_keys)
+    del trips[:]
+    got = jax.block_until_ready(run(dev, **_statics(dev, ctx, commit_k)))
+    jax.effects_barrier()
+    assert len(trips) == int(got.kernel_iters) > 0
+    assert np.all(trips), np.argwhere(~np.stack(trips))
+    _assert_oracle(name, got, ctx, expected)
+    assert int(got.scheduled_count) == WIDE_GANGS - WIDE_NQ  # every 1-core job, no whole-node job
+    ref_run, _ = _round(1, False, check_keys)
+    ref = ref_run(dev, **_statics(dev, ctx, 1))
+    for field in got._fields:
+        if field not in ("kernel_iters", "window_refills"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, field)), np.asarray(getattr(ref, field)), err_msg=field
+            )
+    assert int(got.window_refills) == REFILLS[name][(commit_k, check_keys)]
+    # with keys, a registration moves hundreds of cursors at once: the window is refilled
+    assert (int(got.window_refills) > 0) == check_keys
 
 
 def test_the_worlds_reach_the_edges():

@@ -449,7 +449,7 @@ def _walk(span):
         yield from _walk(c)
 
 
-def _served_session(maximum_scheduling_burst=16, num_nodes=12):
+def _served_session(maximum_scheduling_burst=16, num_nodes=12, num_queues=3, **config):
     """An in-process sidecar session at the tiny served size, with the
     fleet and the queues synced: (sidecar, session id, factory)."""
     from tests.test_pipeline import NOW_NS, make_config, make_world
@@ -458,10 +458,10 @@ def _served_session(maximum_scheduling_burst=16, num_nodes=12):
     from armada_tpu.scheduler.sidecar import ScheduleSidecar
 
     cfg = dataclasses.replace(
-        make_config(incremental_problem_build=True),
+        make_config(incremental_problem_build=True, **config),
         maximum_scheduling_burst=maximum_scheduling_burst,
     )
-    F, nodes, queues = make_world(cfg, num_nodes=num_nodes)
+    F, nodes, queues = make_world(cfg, num_nodes=num_nodes, num_queues=num_queues)
     sidecar = ScheduleSidecar(cfg, clock_ns=lambda: NOW_NS)
     sid = sidecar.create_session("t")
     sidecar.session(sid).apply_sync(
@@ -513,7 +513,9 @@ def test_served_cycle_roots_are_covered_by_named_children(_fresh_recorder):
     assert "submit_many" in _names(by_name["mirror_commit"])
     # the round half
     top = [c.name for c in rnd.children if c.name != "gc_collect"]
-    assert top[0] == "session_lock_wait" and top[-2:] == ["mirror_commit", "slo_feed"]
+    assert top[0] == "session_lock_wait"
+    # the response's stats JSON is dumped inside the root (PR 28), last
+    assert top[-3:] == ["mirror_commit", "slo_feed", "stats_encode"]
     assert {
         "fleet_scan", "pool_nodes", "pool_prepare", "assemble", "round", "apply_outcome", "away_prepare",
     } <= set(top)
@@ -595,7 +597,51 @@ _EXPECTED_STEADY_SPANS = {
     "decode_dispatch": 1, "shadow": 1, "shadow_thunk": 1, "sweep": 1, "fetch_decode": 1,
     "device_wait": 1, "decode": 1, "apply_outcome": 1, "remove_many": 1, "table_remove": 1,
     "g_ids_copy": 1, "lease_many": 1, "away_prepare": 1, "slo_feed": 1, "xfer_down": 1,
+    # the queue axis (PR 28): one span a boundary, whatever the queue count
+    "queue_tokens": 1, "queue_caps": 1, "stats_encode": 1,
 }
+
+
+@pytest.mark.parametrize("stats", [False, True], ids=["served", "stats-collected"])
+@pytest.mark.parametrize("queues", [8, 300])
+def test_queue_axis_spans_once_a_cycle_whatever_the_queue_count(_fresh_recorder, queues, stats):
+    """The O(queues) host work of a round has one span a boundary and never
+    one a queue: `queue_tokens` (the rate limiters' per-queue tokens; the
+    priority overrides too where a provider is set), `queue_caps` (assemble's
+    per-queue burst caps), `stats_encode` (the response's stats JSON) and,
+    only where the session collects them (a sidecar session does not, unless
+    its config publishes metric events or runs the optimiser), `queue_stats`.
+    The round's counters ride the `round` span and the stats JSON alike."""
+    import collections
+
+    sidecar, sid, F = _served_session(
+        num_queues=queues, maximum_scheduling_burst=1000, publish_metric_events=stats
+    )
+    _served_cycle(sidecar, sid, F, 0, 6)
+    _, rnd, resp = _served_cycle(sidecar, sid, F, 100, 6)
+    got = collections.Counter(_names(rnd))
+    assert {n: got[n] for n in ("queue_tokens", "queue_caps", "stats_encode", "queue_stats")} == {
+        "queue_tokens": 1, "queue_caps": 1, "stats_encode": 1, "queue_stats": int(stats),
+    }
+    (caps,), (tokens,), (encode,) = (_find(rnd, n) for n in ("queue_caps", "queue_tokens", "stats_encode"))
+    assert caps.args == {"queues": queues} and tokens.args == {"queues": queues}
+    assert encode.args == {"pools": 1, "bytes": len(resp.pool_stats_json)}
+    pool = json.loads(resp.pool_stats_json)["pools"][0]
+    # _served_cycle submits to q0..q2 only: three queues hold jobs, three are leased from
+    # the queue axis pads to min(shape_bucket, 256): 64 with make_config's bucket
+    axis = {"queues": queues, "queues_padded": -(-queues // 64) * 64,
+            "queues_pending": 3, "queues_scheduled": 3}
+    if stats:
+        (span,) = _find(rnd, "queue_stats")
+        assert span.args == {"queues": queues}
+        assert set(pool["queue_stats"]) == {f"q{i}" for i in range(queues)}
+        assert 1 <= pool["fair_share_iterations"] <= 10
+        axis["fair_share_iterations"] = pool["fair_share_iterations"]
+    else:
+        assert pool["queue_stats"] == {} and "fair_share_iterations" not in pool
+    assert {k: pool[k] for k in axis} == axis
+    (round_span,) = _find(rnd, "round")
+    assert {k: round_span.args[k] for k in axis} == axis
 
 
 def test_gc_collect_is_charged_to_the_span_that_paid(_fresh_recorder):
