@@ -257,9 +257,10 @@ def run_round_on_device(
     the blocking fetch -- the KERNEL SHADOW.  Anything that neither reads
     this round's outcome nor mutates what decode still needs is sound here
     (submit-side table inserts and prefetch_content are; the ctx id
-    snapshots are copy-on-write precisely for this).  The thunks run ONCE,
-    before the first decode -- gang-rollback re-runs never repeat them, and
-    a watchdog failover resumes after the last thunk that started.
+    vectors are snapshots, slab.SnapshotIds, precisely for this).  The
+    thunks run ONCE, before the first decode -- gang-rollback re-runs never
+    repeat them, and a watchdog failover resumes after the last thunk that
+    started.
 
     `host_problem`: the host-array ground truth for CPU failover (a
     SchedulingProblem or a thunk building one, e.g. DeltaBundle.materialize).
@@ -1089,6 +1090,7 @@ def _finish_body(h: _RoundHandle):
         "queues_padded": ctx.queues_padded,
         "queues_pending": ctx.queues_pending,
     }
+    outcome.assemble = ctx.assemble_stats
     return result, outcome
 
 

@@ -58,6 +58,7 @@ from armada_tpu.models.problem import (
     _pad,
     queues_pending,
 )
+from armada_tpu.models.slab import SnapshotIds
 from armada_tpu.ops.trace import recorder as _trace
 
 
@@ -216,6 +217,9 @@ class _SortedTable:
         self.key_of_id: dict[bytes, tuple] = {}
         # full-width rows copied by merges/compactions/growth (test guard)
         self.copied_rows = 0
+        # bumped whenever physical BASE rows are renumbered (merge, compact):
+        # what the builder's carried candidate order is keyed by
+        self.gen = 0
         self._live_cache: Optional[np.ndarray] = None
 
     def _cols(self):
@@ -341,6 +345,7 @@ class _SortedTable:
             if self.atoms is not None:
                 self.atoms[:k] = atoms if atoms is not None else 0
             self.n = self.sorted_n = k
+            self.gen += 1
         else:
             # Batched binary refinement (_lex_equal_ranges): the probe batch
             # is lex-sorted, so probes sharing a range form contiguous runs
@@ -416,6 +421,7 @@ class _SortedTable:
                 )
                 col[: self.n] = merged
         self.copied_rows += self.n
+        self.gen += 1
         self.sorted_n = self.n
         self.ov_pos = np.zeros((0,), np.int64)
 
@@ -527,6 +533,7 @@ class _SortedTable:
             if self.atoms is not None:
                 self.atoms = self.atoms[: self.n][keep]
         self.copied_rows += kept
+        self.gen += 1
         self.n = self.sorted_n = self.cap = kept
         self.dead = 0
         self._live_cache = None
@@ -573,6 +580,91 @@ class _SortedTable:
                 )
             total += int(self.alive[q_lo:lo].sum())
         return total
+
+
+def _flip_participation(slab, demand: np.ndarray, flip_on, flip_off) -> None:
+    """Slots entering / leaving the round's problem (lookback, queue and
+    node filters): their demand share moves with them and the slab marks
+    them dirty; content stays."""
+    for flips, sign in ((flip_on, 1.0), (flip_off, -1.0)):
+        if flips.size:
+            np.add.at(
+                demand,
+                (slab.queue[flips].astype(np.int64), slab.pc[flips].astype(np.int64)),
+                sign * slab.req[flips].astype(np.float64),
+            )
+    slab.set_valid(flip_on, True)
+    slab.set_valid(flip_off, False)
+
+
+def _splice_into(out, prev, L0: int, L1: int, rem, ins, vals, step, src) -> None:
+    """out[:L1] = prev[:L0] with the positions `rem` taken out and `vals`
+    put in at the final positions `ins` (both ascending), what np.delete and
+    np.insert would give -- in two sequential passes over buffers the caller
+    keeps and no allocation of the vectors' width.  Entry p of the result
+    that is not an insert comes from prev[p + shift(p)], where the shift
+    falls by one after every insert and rises by one at every removal; so
+    the source index is the running sum of `step`, which is all ones between
+    uses: one at position 0 is taken off, one added where the kept entry
+    after each removal lands, one taken off after each insert."""
+    if L1:
+        # kept entries of prev before each removal / before each insert
+        k_rem = rem - np.arange(rem.shape[0], dtype=np.int64)
+        k_ins = ins - np.arange(ins.shape[0], dtype=np.int64)
+        # where the first kept entry after each removal lands in the result
+        # lint: allow(searchsorted-dtype) -- both are int64 positions of the order
+        lands = k_rem + k_ins.searchsorted(k_rem, "right")
+        after = ins + 1
+        step[0] -= 1
+        np.add.at(step, lands, 1)
+        np.subtract.at(step, after, 1)
+        np.cumsum(step[:L1], out=src[:L1])
+        step[0] = 1
+        step[lands] = 1
+        step[after] = 1
+        np.take(prev, src[:L1], out=out[:L1], mode="clip")
+        out[ins] = vals
+
+
+class _OrderCarry:
+    """What one cycle's candidate order was built from, in the coordinates
+    that stay put between cycles: BASE rows of the jobs table (fixed until a
+    merge or compaction bumps the table's `gen`), slots, and positions in
+    the order itself.  IncrementalBuilder._order_patched reads it as "last
+    cycle" and writes the next one.
+
+    key      (table gen, s_cap, r_cap, u_cap, queues, lookback): any change
+             and the order is rebuilt
+    known    queue_known as it stood
+    alive    the base rows' alive flags as they stood (a copy the patch
+             brings forward in place: the diff against the table's is the
+             cycle's tombstones)
+    bq       [Q+1] base-row bounds of each queue
+    cut      [Q] first base row of each queue beyond its lookback
+    ao_*     the ALIVE overlay rows, in key order: slot, base position
+             (ov_pos), [Q+1] queue bounds, in the order or not, position
+    ev_*     the evictee slots (gang-axis ids) in order, and positions
+    q_start  [Q] where each queue's segment began; n_ev [Q] its evictees
+    cs, kept_q, ao_q, ao_rank   of use within the cycle that computed them:
+             the base rows' prefix count of alive flags (a kept buffer, the
+             next cycle overwrites it), the kept singles a queue, the alive
+             overlay rows' queue and rank in it"""
+
+    __slots__ = (
+        "key", "known", "alive", "bq", "cut",
+        "ao_slot", "ao_pos", "ao_lo", "ao_kept", "ao_P",
+        "ev_seq", "ev_P", "q_start", "n_ev",
+        "cs", "kept_q", "ao_q", "ao_rank",
+    )
+
+
+class _OrderPatch:
+    """One patched cycle's result: the order (a buffer the builder keeps),
+    its per-queue lengths, the evictees' queues, and the delta against last
+    cycle's order as the device splice wants it (positions removed from the
+    old order, final positions and slots inserted)."""
+
+    __slots__ = ("gq_gang", "n_real", "q_len", "evq", "rem", "ins", "vals")
 
 
 class IncrementalBuilder:
@@ -705,9 +797,10 @@ class IncrementalBuilder:
         self._br_cap = 1
         self._u_prev_n = 0
         self._unit_cols: dict[str, np.ndarray] = {}
-        # Device-visible gang ids across all regions ([G] grows with caps).
-        self._g_ids = np.zeros((0,), self.jobs.ids.dtype)
-        self._g_ids_shared = False  # copy-on-write, see _own_g_ids
+        # Device-visible gang ids across all regions ([G] grows with caps),
+        # written through SnapshotIds so that the HostContext's snapshot
+        # costs what a cycle overwrites and never a [G] copy.
+        self._g_ids = SnapshotIds()
         # Exact integral demand accounting per (queue, pc): resolution units
         # are integers, so incremental float64 +=/-= is exact and
         # order-independent (matches assemble()'s fresh bincounts).
@@ -741,6 +834,18 @@ class IncrementalBuilder:
         # every cycle was the dominant per-cycle upload by bytes.
         self._prev_gq: Optional[np.ndarray] = None
         self._prev_gq_real = 0
+        # What the order above was built from, carried so that the next
+        # cycle PATCHES it by the tables' delta instead of rebuilding it
+        # (_order_patched); None whenever the patch cannot express the next
+        # cycle exactly, and the from-scratch order then runs as before.
+        self._order_carry: Optional[_OrderCarry] = None
+        # Buffers of backlog width the patch works in, kept across cycles
+        # (_order_buffers): nothing that wide is allocated on a steady cycle.
+        self._order_bufs: dict = {}
+        # The round's counters (HostContext.assemble_stats): rows of the
+        # order rebuilt from scratch by the last assemble and why.
+        self._rows_rebuilt = 0
+        self._rebuild_reason = ""
         # Identity-stable small tensors (re-sent only when values change).
         self._stable_smalls: dict[str, np.ndarray] = {}
         self.gang_jobs: dict[str, JobSpec] = {}  # job id -> spec (slow path)
@@ -969,9 +1074,8 @@ class IncrementalBuilder:
                 np.float64
             )
         self._sg.release(slot)
-        if slot < self._g_ids.shape[0]:
-            self._own_g_ids()
-            self._g_ids[slot] = b""
+        if slot < self._g_ids.live.shape[0]:
+            self._g_ids.write(slot, b"")
 
     def _release_run(self, info: Optional[dict]) -> None:
         if info is None:
@@ -983,31 +1087,16 @@ class IncrementalBuilder:
             )
         self._rr.release(slot)
 
-    def _own_g_ids(self) -> None:
-        """Copy-on-write for the shared [G] id snapshot (assemble_delta hands
-        self._g_ids to the HostContext; the first in-place write after that
-        copies, so mutation-free cycles pay nothing and the copy otherwise
-        runs in the overlapped decode shadow, not the assemble path)."""
-        if self._g_ids_shared:
-            # a span only when the copy happens (57 MB at 1M slots): its
-            # caller is whichever mutation came first after the round took
-            # the snapshot -- remove_many, submit_many or a single release
-            with _trace().span("g_ids_copy", bytes=int(self._g_ids.nbytes)):
-                self._g_ids = self._g_ids.copy()
-            self._g_ids_shared = False
-
-    def _share_g_ids(self) -> np.ndarray:
-        self._g_ids_shared = True
-        return self._g_ids
-
     def _ensure_g_ids(self) -> None:
         """Keep the [G] id vector covering the singles region after growth
-        (a fresh array object, so an outstanding snapshot keeps the old)."""
-        if self._g_ids.shape[0] < self._sg.cap:
-            old = self._g_ids
-            self._g_ids = np.zeros((self._sg.cap,), _ID_DTYPE)
-            self._g_ids[: old.shape[0]] = old
-            self._g_ids_shared = False
+        (a fresh array, so an outstanding snapshot keeps the old one).
+        Growth and a change of G (_assemble_delta) are the two places ids
+        are still copied whole; `id_bytes_copied` counts them."""
+        old = self._g_ids.live
+        if old.shape[0] < self._sg.cap:
+            new_ids = np.zeros((self._sg.cap,), _ID_DTYPE)
+            new_ids[: old.shape[0]] = old
+            self._g_ids.replace(new_ids, old.shape[0])
 
     def submit_many(
         self, specs: Sequence[JobSpec], banned: Optional[Mapping] = None
@@ -1130,8 +1219,7 @@ class IncrementalBuilder:
             band=np.asarray(c_band, np.int32),
         )
         self._ensure_g_ids()
-        self._own_g_ids()
-        self._g_ids[slots] = ids_arr
+        self._g_ids.write(slots, ids_arr, span="g_ids_copy")
         np.add.at(
             self._demand_sg,
             (qis, pcs),
@@ -1163,8 +1251,8 @@ class IncrementalBuilder:
             self.running_gang_specs.pop(job_id, None)
             enc.append(job_id.encode())
         qis, pcs, reqs = [], [], []
-        own_gids = False
-        gw = self._g_ids.shape[0]
+        freed: list = []
+        gw = self._g_ids.live.shape[0]
         with _trace().span("table_remove", n=len(enc)):
             infos = self.jobs.remove_many(enc)
         for info in infos:
@@ -1177,10 +1265,9 @@ class IncrementalBuilder:
                 reqs.append(info["req"])
             self._sg.release(slot)
             if slot < gw:
-                if not own_gids:
-                    self._own_g_ids()
-                    own_gids = True
-                self._g_ids[slot] = b""
+                freed.append(slot)
+        if freed:
+            self._g_ids.write(np.asarray(freed, np.int64), b"", span="g_ids_copy")
         if qis:
             np.subtract.at(
                 self._demand_sg,
@@ -2115,12 +2202,21 @@ class IncrementalBuilder:
         queue_tokens=None,
         queue_penalty: Optional[Mapping] = None,
     ):
-        with _trace().span("assemble", pool=self.pool):
-            return self._assemble_delta(
+        with _trace().span("assemble", pool=self.pool) as span:
+            bundle, ctx = self._assemble_delta(
                 global_tokens=global_tokens,
                 queue_tokens=queue_tokens,
                 queue_penalty=queue_penalty,
             )
+            # the counters that say the carried state engaged: rows of the
+            # order rebuilt from scratch (0 on a patched cycle) and the id
+            # bytes copied since the last assemble (before-images under the
+            # snapshots, growth); on the span and, through the context, in
+            # the round's stats JSON
+            span.annotate(
+                **ctx.assemble_stats, assemble_rebuild_reason=self._rebuild_reason
+            )
+            return bundle, ctx
 
     def _assemble_delta(
         self,
@@ -2180,105 +2276,86 @@ class IncrementalBuilder:
                 self._price_epoch += 1
                 self._last_prices = prices
 
-            # --- singles: live rows, (queue, order-key) table order ---------------
-            rows = jt.live_rows()
-            mask_known = np.ones(rows.shape[0], bool)
-            if Qreal and not self.queue_known.all():
-                mask_known = self.queue_known[jt.qi[rows]]
-            rows_known = rows[mask_known]
-            idx_known = np.flatnonzero(mask_known)
-            if prices is not None:
-                perm = self._market_perm(jt, rows_known, prices)
-                rows_known = rows_known[perm]
-                idx_known = idx_known[perm]
-            sq = jt.qi[rows_known].astype(np.int64)
-            counts_s = np.bincount(sq, minlength=Qreal)
-            starts_s = np.zeros((max(1, Qreal),), np.int64)
-            if Qreal:
-                starts_s[1:Qreal] = np.cumsum(counts_s)[:-1]
-            rank_s = np.arange(rows_known.shape[0], dtype=np.int64) - starts_s[sq]
-
-            # --- units merged into the per-queue order (same as assemble()) -------
-            units, unit_members, unit_ubans = self._gang_units(prices)
-            if units:
-                unit_qi = np.array([u["qi"] for u in units], np.int64)
-                unit_vrank = np.array([u["rank"] for u in units], np.int64)
-                shift = np.zeros(rows_known.shape[0], np.int64)
-                units_before = np.zeros(len(units), np.int64)
-                for q in np.unique(unit_qi):
-                    in_q = np.flatnonzero(unit_qi == q)
-                    order_q = in_q[np.argsort(unit_vrank[in_q], kind="stable")]
-                    units_before[order_q] = np.arange(in_q.shape[0])
-                    ur = np.sort(unit_vrank[in_q])
-                    sel = sq == q
-                    shift[sel] = np.searchsorted(ur, rank_s[sel], "right")
-                merged_rank_s = rank_s + shift
-                merged_rank_u = unit_vrank + units_before
+            # --- the candidate order: patched from last cycle's where the
+            # builder's own state says the delta is expressible, else rebuilt --
+            patch = self._order_patched(Qreal, Nreal)
+            self._rebuild_reason = ""
+            if isinstance(patch, str):
+                self._rebuild_reason, patch = patch, None
+            if patch is not None:
+                kept_units: list[tuple] = []
+                units = []
+                evq = patch.evq
             else:
-                merged_rank_s = rank_s
-                merged_rank_u = np.zeros((0,), np.int64)
+                # --- singles: live rows, (queue, order-key) table order ---------------
+                rows = jt.live_rows()
+                mask_known = np.ones(rows.shape[0], bool)
+                if Qreal and not self.queue_known.all():
+                    mask_known = self.queue_known[jt.qi[rows]]
+                rows_known = rows[mask_known]
+                idx_known = np.flatnonzero(mask_known)
+                if prices is not None:
+                    perm = self._market_perm(jt, rows_known, prices)
+                    rows_known = rows_known[perm]
+                    idx_known = idx_known[perm]
+                sq = jt.qi[rows_known].astype(np.int64)
+                counts_s = np.bincount(sq, minlength=Qreal)
+                starts_s = np.zeros((max(1, Qreal),), np.int64)
+                if Qreal:
+                    starts_s[1:Qreal] = np.cumsum(counts_s)[:-1]
+                rank_s = np.arange(rows_known.shape[0], dtype=np.int64) - starts_s[sq]
 
-            L = cfg.max_queue_lookback
-            keep_s = merged_rank_s < L
-            rows_kept = rows_known[keep_s]
-            sq_kept = sq[keep_s]
-            merged_rank_kept = merged_rank_s[keep_s]
-            kept_units: list[tuple] = []
-            if units:
-                cut_tags = {
-                    units[i]["tag"]
-                    for i in range(len(units))
-                    if units[i]["tag"] and merged_rank_u[i] >= L
-                }
-                for i, u in enumerate(units):
-                    if merged_rank_u[i] >= L or (u["tag"] and u["tag"] in cut_tags):
-                        continue
-                    kept_units.append((u, merged_rank_u[i], unit_members[i], unit_ubans[i]))
+                # --- units merged into the per-queue order (same as assemble()) -------
+                units, unit_members, unit_ubans = self._gang_units(prices)
+                if units:
+                    unit_qi = np.array([u["qi"] for u in units], np.int64)
+                    unit_vrank = np.array([u["rank"] for u in units], np.int64)
+                    shift = np.zeros(rows_known.shape[0], np.int64)
+                    units_before = np.zeros(len(units), np.int64)
+                    for q in np.unique(unit_qi):
+                        in_q = np.flatnonzero(unit_qi == q)
+                        order_q = in_q[np.argsort(unit_vrank[in_q], kind="stable")]
+                        units_before[order_q] = np.arange(in_q.shape[0])
+                        ur = np.sort(unit_vrank[in_q])
+                        sel = sq == q
+                        shift[sel] = np.searchsorted(ur, rank_s[sel], "right")
+                    merged_rank_s = rank_s + shift
+                    merged_rank_u = unit_vrank + units_before
+                else:
+                    merged_rank_s = rank_s
+                    merged_rank_u = np.zeros((0,), np.int64)
 
-            # --- singles participation flips -> slab validity + demand ------------
-            slots_live = jt.slot[rows].astype(np.int64)
-            valid_flags = np.zeros(rows.shape[0], bool)
-            valid_flags[idx_known[keep_s]] = True
-            cur_valid = sg.valid[slots_live]
-            flip_on = slots_live[valid_flags & ~cur_valid]
-            flip_off = slots_live[~valid_flags & cur_valid]
-            for flips, sign in ((flip_on, 1.0), (flip_off, -1.0)):
-                if flips.size:
-                    np.add.at(
-                        self._demand_sg,
-                        (sg.queue[flips].astype(np.int64), sg.pc[flips].astype(np.int64)),
-                        sign * sg.req[flips].astype(np.float64),
-                    )
-            sg.set_valid(flip_on, True)
-            sg.set_valid(flip_off, False)
+                L = cfg.max_queue_lookback
+                keep_s = merged_rank_s < L
+                rows_kept = rows_known[keep_s]
+                sq_kept = sq[keep_s]
+                merged_rank_kept = merged_rank_s[keep_s]
+                kept_units: list[tuple] = []
+                if units:
+                    cut_tags = {
+                        units[i]["tag"]
+                        for i in range(len(units))
+                        if units[i]["tag"] and merged_rank_u[i] >= L
+                    }
+                    for i, u in enumerate(units):
+                        if merged_rank_u[i] >= L or (u["tag"] and u["tag"] in cut_tags):
+                            continue
+                        kept_units.append((u, merged_rank_u[i], unit_members[i], unit_ubans[i]))
 
-            # --- runs participation flips (queue/node filters) --------------------
-            run_rows = rt.live_rows()
-            rvalid = np.ones(run_rows.shape[0], bool)
-            if Qreal and not self.queue_known.all():
-                rvalid &= self.queue_known[rt.qi[run_rows]]
-            if Nreal and not self.node_present.all():
-                rvalid &= self.node_present[rt.node[run_rows]]
-            rslots = rt.slot[run_rows].astype(np.int64)
-            cur_rvalid = rr.valid[rslots]
-            rflip_on = rslots[rvalid & ~cur_rvalid]
-            rflip_off = rslots[~rvalid & cur_rvalid]
-            for flips, sign in ((rflip_on, 1.0), (rflip_off, -1.0)):
-                if flips.size:
-                    np.add.at(
-                        self._demand_run,
-                        (rr.queue[flips].astype(np.int64), rr.pc[flips].astype(np.int64)),
-                        sign * rr.req[flips].astype(np.float64),
-                    )
-            rr.set_valid(rflip_on, True)
-            rr.set_valid(rflip_off, False)
+                # --- singles participation flips -> slab validity + demand ------------
+                slots_live = jt.slot[rows].astype(np.int64)
+                valid_flags = np.zeros(rows.shape[0], bool)
+                valid_flags[idx_known[keep_s]] = True
+                cur_valid = sg.valid[slots_live]
+                flip_on = slots_live[valid_flags & ~cur_valid]
+                flip_off = slots_live[~valid_flags & cur_valid]
+                _flip_participation(sg, self._demand_sg, flip_on, flip_off)
 
-            # evictee candidates: preemptible valid runs, table order
-            ev_mask = rt.preempt[run_rows] & rvalid
-            ev_rows = run_rows[ev_mask]
-            if prices is not None:
-                ev_rows = ev_rows[self._market_perm(rt, ev_rows, prices)]
-            evq = rt.qi[ev_rows].astype(np.int64)
+                # --- runs participation flips (queue/node filters) --------------------
+                ev_rows, evq, rflip_on, rflip_off = self._run_candidates(
+                    Qreal, Nreal, prices
+                )
+                _flip_participation(rr, self._demand_run, rflip_on, rflip_off)
 
             # --- region layout -----------------------------------------------------
             # Zero-size axes break the kernel's gathers (legacy pads to >=1
@@ -2299,11 +2376,12 @@ class IncrementalBuilder:
             u_cap = self._u_cap
             u_base = s_cap + r_cap
             G = s_cap + r_cap + u_cap
-            if self._g_ids.shape[0] != G:
+            if self._g_ids.live.shape[0] != G:
+                old_ids = self._g_ids.live
+                n_keep = min(old_ids.shape[0], s_cap)
                 new_ids = np.zeros((G,), _ID_DTYPE)
-                n_keep = min(self._g_ids.shape[0], s_cap)
-                new_ids[:n_keep] = self._g_ids[:n_keep]
-                self._g_ids = new_ids
+                new_ids[:n_keep] = old_ids[:n_keep]
+                self._g_ids.replace(new_ids, n_keep)
 
             # --- units region content (rebuilt wholesale; small) ------------------
             uc = {
@@ -2364,41 +2442,49 @@ class IncrementalBuilder:
             for i, row in enumerate(ban_rows):
                 ban_mask[i + 1] = row
 
-            # --- final candidate order: sorted merge on slot ids ------------------
-            key_s = (sq_kept << 32) | merged_rank_kept
-            seq_s = jt.slot[rows_kept].astype(np.int32)
-            if kept_units:
-                key_u = np.array(
-                    [(int(u["qi"]) << 32) | int(mr) for (u, mr, _, _) in kept_units],
-                    np.int64,
-                )
-                order_u = np.argsort(key_u, kind="stable")
-                key_u = key_u[order_u]
-                seq_u = (u_base + order_u).astype(np.int32)
-                pos = np.searchsorted(key_s, key_u)
-                queued_seq = np.insert(seq_s, pos, seq_u)
-                queued_q = np.insert(
-                    sq_kept,
-                    pos,
-                    np.array([u["qi"] for (u, _, _, _) in kept_units], np.int64)[order_u],
-                )
-            else:
-                queued_seq = seq_s
-                queued_q = sq_kept
-
-            ev_seq = (s_cap + rt.slot[ev_rows].astype(np.int64)).astype(np.int32)
-            pos_e = np.searchsorted(queued_q, evq, "left")
-            gq_real = np.insert(queued_seq, pos_e, ev_seq)
-            gq_q = np.insert(queued_q, pos_e, evq)
-            nreal_candidates = gq_real.shape[0]
-
             Q = _pad(Qreal, qbucket)
-            q_len64 = np.bincount(gq_q, minlength=Q)
+            if patch is not None:
+                gq_gang = patch.gq_gang
+                nreal_candidates = patch.n_real
+                q_len64 = np.zeros((Q,), np.int64)
+                q_len64[:Qreal] = patch.q_len
+                self._rows_rebuilt = 0
+            else:
+                # --- final candidate order: sorted merge on slot ids ------------------
+                key_s = (sq_kept << 32) | merged_rank_kept
+                seq_s = jt.slot[rows_kept].astype(np.int32)
+                if kept_units:
+                    key_u = np.array(
+                        [(int(u["qi"]) << 32) | int(mr) for (u, mr, _, _) in kept_units],
+                        np.int64,
+                    )
+                    order_u = np.argsort(key_u, kind="stable")
+                    key_u = key_u[order_u]
+                    seq_u = (u_base + order_u).astype(np.int32)
+                    pos = np.searchsorted(key_s, key_u)
+                    queued_seq = np.insert(seq_s, pos, seq_u)
+                    queued_q = np.insert(
+                        sq_kept,
+                        pos,
+                        np.array([u["qi"] for (u, _, _, _) in kept_units], np.int64)[order_u],
+                    )
+                else:
+                    queued_seq = seq_s
+                    queued_q = sq_kept
+
+                ev_seq = (s_cap + rt.slot[ev_rows].astype(np.int64)).astype(np.int32)
+                pos_e = np.searchsorted(queued_q, evq, "left")
+                gq_real = np.insert(queued_seq, pos_e, ev_seq)
+                gq_q = np.insert(queued_q, pos_e, evq)
+                nreal_candidates = gq_real.shape[0]
+
+                q_len64 = np.bincount(gq_q, minlength=Q)
+                gq_gang = np.zeros((G,), np.int32)
+                gq_gang[:nreal_candidates] = gq_real
+                self._rows_rebuilt = int(nreal_candidates)
             q_start = np.zeros((Q,), np.int32)
             q_start[1:] = np.cumsum(q_len64)[:-1].astype(np.int32)
             q_len = q_len64.astype(np.int32)
-            gq_gang = np.zeros((G,), np.int32)
-            gq_gang[:nreal_candidates] = gq_real
 
             # --- demand -> constrained shares (assemble()'s exact math) -----------
             C = len(self.pc_names)
@@ -2502,7 +2588,12 @@ class IncrementalBuilder:
             gq_splice = None
             prev_gq, L0 = self._prev_gq, self._prev_gq_real
             L1 = int(nreal_candidates)
-            if prev_gq is not None and prev_gq.shape[0] == G:
+            delta = None
+            if patch is not None:
+                # the patch IS the delta: the positions it took out of last
+                # cycle's order and those it put in, no [G]-wide diff
+                delta = (patch.rem, patch.ins, patch.vals)
+            elif prev_gq is not None and prev_gq.shape[0] == G:
                 dirty_slot = np.zeros((G,), bool)
                 # ALL dirtied slots, prefetched or not: a prefetched slot's
                 # content is on device but its ORDER position may have moved
@@ -2525,28 +2616,37 @@ class IncrementalBuilder:
                 if kept_prev.shape[0] == new_minus.shape[0] and np.array_equal(
                     kept_prev, new_minus
                 ):
-                    rem = np.flatnonzero(dep)
                     ins = np.flatnonzero(arr)
-                    vals = gq_real[ins]
-                    # padded-tail zeros shift with the real-region length
-                    if L1 > L0:  # fewer tail zeros: drop from the prev tail
-                        rem = np.concatenate([rem, np.arange(G - (L1 - L0), G)])
-                    elif L0 > L1:  # more tail zeros: insert at the final tail
-                        ins = np.concatenate([ins, np.arange(G - (L0 - L1), G)])
-                        vals = np.concatenate(
-                            [vals, np.zeros((L0 - L1,), vals.dtype)]
-                        )
-                    # a big splice costs more than the 4MB it saves
-                    if rem.shape[0] + ins.shape[0] <= max(4096, G // 8):
-                        gq_splice = (
-                            rem.astype(np.int32),
-                            ins.astype(np.int32),
-                            vals.astype(np.int32),
-                        )
-            # gq_gang is freshly allocated per cycle and never mutated after
-            # this point: keep the reference, no 4MB copy
+                    delta = (np.flatnonzero(dep), ins, gq_real[ins])
+            if delta is not None:
+                rem, ins, vals = delta
+                # padded-tail zeros shift with the real-region length
+                if L1 > L0:  # fewer tail zeros: drop from the prev tail
+                    rem = np.concatenate([rem, np.arange(G - (L1 - L0), G)])
+                elif L0 > L1:  # more tail zeros: insert at the final tail
+                    ins = np.concatenate([ins, np.arange(G - (L0 - L1), G)])
+                    vals = np.concatenate(
+                        [vals, np.zeros((L0 - L1,), vals.dtype)]
+                    )
+                # a big splice costs more than the 4MB it saves
+                if rem.shape[0] + ins.shape[0] <= max(4096, G // 8):
+                    gq_splice = (
+                        rem.astype(np.int32),
+                        ins.astype(np.int32),
+                        vals.astype(np.int32),
+                    )
+            # the rebuilt vector is freshly allocated and never mutated after
+            # this point; the patched one lives in a buffer the builder keeps
+            # and rewrites two cycles on (_order_buffers): either way the
+            # reference is kept, no 4MB copy
             self._prev_gq = gq_gang
             self._prev_gq_real = L1
+            if patch is None:
+                self._order_carry = (
+                    None
+                    if self.market or units or not Qreal
+                    else self._carry_after_rebuild(Qreal, evq, ev_seq)
+                )
 
         with trace.span("assemble_bundle"):
             is_unit = sg_idx >= u_base
@@ -2567,7 +2667,13 @@ class IncrementalBuilder:
 
             fulls = {
                 # omitted when the splice carries the order (a few KB vs 4MB)
-                **({} if gq_splice is not None else {"gq_gang": gq_gang}),
+                # (the device cache tells "unchanged" by object identity, so
+                # the patch's kept buffer goes out as a copy)
+                **(
+                    {}
+                    if gq_splice is not None
+                    else {"gq_gang": gq_gang if patch is None else gq_gang.copy()}
+                ),
                 "q_start": q_start,
                 "q_len": q_len,
                 "q_weight": self._stable("q_weight", q_weight),
@@ -2767,16 +2873,23 @@ class IncrementalBuilder:
                 q_demand_raw=q_demand_raw,
                 queues_padded=Q,
                 queues_pending=queues_pending(q_len64, evq),
+                assemble_stats={
+                    "assemble_rows_rebuilt": self._rows_rebuilt,
+                    "id_bytes_copied": self._g_ids.take_bytes_copied()
+                    + rr.take_id_bytes_copied(),
+                },
                 pool_total_atoms={
                     name: int(round(float(total_pool64[i]) * self.factory.resolutions[i]))
                     for i, name in enumerate(self.factory.names)
                     if total_pool64[i]
                 },
-                # Copy-on-write snapshots: a mutation landing between assemble
-                # and decode (slot reuse after remove) must not corrupt decode's
-                # ids, but eagerly copying [G] ids cost ~30ms of every assemble;
-                # now the first post-assemble id write copies instead.
-                gang_ids_vec=self._share_g_ids(),
+                # Snapshots, not copies (slab.SnapshotIds): a mutation landing
+                # after assemble (the round's own removals, slot reuse by the
+                # next sync's submits) leaves the old id with this context
+                # before it overwrites the slot, so decode, the lazy `failed`
+                # ids and an explain report read later all see this round's
+                # ids, and a cycle pays for the slots it rewrote, not for [G].
+                gang_ids_vec=self._g_ids.snapshot(),
                 gang_members_over=members_over,
                 run_ids_vec=rr.share_ids(),
                 # slab run axis IS the slot axis; lazy like the dense path (the
@@ -2792,6 +2905,352 @@ class IncrementalBuilder:
                 ),
             )
             return bundle, ctx
+
+    # ------------------------------------------- the carried candidate order ----
+
+    def _run_candidates(self, Qreal: int, Nreal: int, prices):
+        """(evictee rows, their queues, run slots to flip on, to flip off):
+        the run table's side of the order.  As wide as the RUNNING set, not
+        the backlog, so both the patched and the rebuilt order take it
+        whole every cycle.  Nothing is mutated here."""
+        rt, rr = self.runs, self._rr
+        run_rows = rt.live_rows()
+        rvalid = np.ones(run_rows.shape[0], bool)
+        if Qreal and not self.queue_known.all():
+            rvalid &= self.queue_known[rt.qi[run_rows]]
+        if Nreal and not self.node_present.all():
+            rvalid &= self.node_present[rt.node[run_rows]]
+        rslots = rt.slot[run_rows].astype(np.int64)
+        cur_rvalid = rr.valid[rslots]
+        rflip_on = rslots[rvalid & ~cur_rvalid]
+        rflip_off = rslots[~rvalid & cur_rvalid]
+        # evictee candidates: preemptible valid runs, table order
+        ev_rows = run_rows[rt.preempt[run_rows] & rvalid]
+        if prices is not None:
+            ev_rows = ev_rows[self._market_perm(rt, ev_rows, prices)]
+        return ev_rows, rt.qi[ev_rows].astype(np.int64), rflip_on, rflip_off
+
+    def _order_key(self, Qreal: int) -> tuple:
+        return (
+            self.jobs.gen,
+            self._sg.cap,
+            self._rr.cap,
+            self._u_cap,
+            Qreal,
+            self.config.max_queue_lookback,
+        )
+
+    def _patch_blocker(self, Qreal: int) -> str:
+        """Why this cycle's order cannot be patched from the last one's, or
+        "" when it can.  Read from the builder's own state, never a switch:
+        what the patch cannot express exactly is rebuilt."""
+        carry = self._order_carry
+        if self.market:
+            return "market"  # the order is a per-cycle permutation by price
+        if self.gang_jobs or self._u_prev_n:
+            return "gang_units"  # units are re-ranked wholesale every cycle
+        if carry is None or self._prev_gq is None:
+            return "no_previous"
+        key = self._order_key(Qreal)
+        if key[0] != carry.key[0]:
+            return "table_renumbered"  # a merge or compaction moved base rows
+        if key[1:4] != carry.key[1:4]:
+            return "caps"  # slab growth renumbers the gang axis
+        if key[4:] != carry.key[4:] or not np.array_equal(
+            carry.known, self.queue_known
+        ):
+            return "queues"  # a queue made known or unknown, or a new one
+        return ""
+
+    def _order_buffers(self, sn: int) -> dict:
+        """The backlog-wide scratch the patch works in, allocated when the
+        gang axis or the table's base outgrows it (both are rebuild cycles)
+        and otherwise kept: two order vectors (last cycle's is read while
+        this cycle's is written; a bundle's materialize() may still hold the
+        older), the splice's step and source-index vectors (_splice_into),
+        a [G] flag buffer that is all True between uses and one that is all
+        False, the base rows' prefix count of alive flags and a flag per
+        base row."""
+        b = self._order_bufs
+        G = self._prev_gq.shape[0]
+        if b.get("G") != G:
+            b["G"] = G
+            b["gq"] = [np.zeros((G,), np.int32), np.zeros((G,), np.int32)]
+            b["gq_len"] = [0, 0]
+            b["step"] = np.ones((G + 1,), np.int64)
+            b["src"] = np.zeros((G,), np.int64)
+            b["true"] = np.ones((G,), bool)
+            b["false"] = np.zeros((G,), bool)
+        if b.get("rows", -1) < sn:
+            b["rows"] = max(sn, self.jobs.cap)
+            b["cs"] = np.zeros((b["rows"] + 1,), np.int32)
+            b["neq"] = np.zeros((b["rows"],), bool)
+        return b
+
+    def _order_tables_state(self, Qreal: int, bq: Optional[np.ndarray]) -> _OrderCarry:
+        """The jobs table's side of the order, from the table as it stands: a
+        new `_OrderCarry` with per queue the lookback cut in base rows and the
+        kept count, and the alive overlay rows with their rank in their
+        queue (`ao_q`, `ao_rank`, `kept_q` and the prefix count `cs` are for
+        this cycle only).  One sequential pass over the base's alive flags
+        (`cs`, into a kept buffer) and otherwise overlay-wide or queue-wide
+        work: the rank of a base row r of queue q among the live rows is
+        cs[r] - cs[bq[q]] plus the alive overlay rows of q that sort before
+        it (ov_pos <= r), and an overlay row's is cs[ov_pos] - cs[bq[q]] plus
+        its index among the queue's alive overlay rows."""
+        jt = self.jobs
+        sn, n = jt.sorted_n, jt.n
+        st = _OrderCarry()
+        st.cs = cs = self._order_buffers(sn)["cs"]
+        cs[0] = 0
+        if sn:
+            # (a cumsum that casts on the way allocates the cast input whole)
+            np.copyto(cs[1 : sn + 1], jt.alive[:sn], casting="unsafe")
+            np.cumsum(cs[1 : sn + 1], out=cs[1 : sn + 1])
+        if bq is None:
+            probes = np.arange(Qreal + 1, dtype=jt.qi.dtype)
+            bq = jt.qi[:sn].searchsorted(probes, "left").astype(np.int64)
+        st.bq = bq
+        ov_alive = np.flatnonzero(jt.alive[sn:n])
+        ao_rows = sn + ov_alive
+        st.ao_pos = ao_pos = jt.ov_pos[ov_alive]
+        st.ao_q = ao_q = jt.qi[ao_rows].astype(np.int64)
+        st.ao_slot = jt.slot[ao_rows].astype(np.int64)
+        q_probes = np.arange(Qreal + 1, dtype=np.int64)
+        st.ao_lo = ao_lo = ao_q.searchsorted(q_probes, "left")
+        base_lo = cs[bq[:-1]].astype(np.int64)
+        st.ao_rank = (
+            cs[ao_pos]
+            - base_lo[ao_q]
+            + (np.arange(ao_q.shape[0], dtype=np.int64) - ao_lo[ao_q])
+        )
+        live_q = cs[bq[1:]] - base_lo + np.diff(ao_lo)
+        lookback = np.where(
+            self.queue_known[:Qreal], np.int64(self.config.max_queue_lookback), 0
+        )
+        st.ao_kept = st.ao_rank < lookback[ao_q]
+        st.kept_q = np.minimum(live_q, lookback)
+        # cut[q]: the first base row c of q with lookback[q] or more live rows
+        # before it (base row c is in the order iff alive and c < cut[q]);
+        # only queues longer than their lookback have one inside the queue
+        st.cut = cut = bq[1:].copy()
+        over = np.flatnonzero(live_q > lookback)
+        if over.size:
+            lo, hi = bq[over].copy(), bq[over + 1].copy()
+            o_lo, o_hi, b_lo, want = ao_lo[over], ao_lo[over + 1], base_lo[over], lookback[over]
+            while True:
+                open_ = lo < hi
+                if not open_.any():
+                    break
+                mid = (lo + hi) // 2
+                # lint: allow(searchsorted-dtype) -- ov_pos and the base-row bounds are both int64
+                over_before = ao_pos.searchsorted(mid, "right")
+                before = cs[mid] - b_lo + np.clip(over_before, o_lo, o_hi) - o_lo
+                ge = before >= want
+                hi = np.where(open_ & ge, mid, hi)
+                lo = np.where(open_ & ~ge, mid + 1, lo)
+            cut[over] = lo
+        return st
+
+    def _order_positions(self, st: _OrderCarry, Qreal: int, evq, ev_seq, n_ev) -> None:
+        """Complete `st` with the run table's side (the evictee slots
+        `ev_seq` of queues `evq`, `n_ev` a queue): where each queue's segment
+        starts and where every evictee and kept overlay row stands."""
+        q_len = n_ev + st.kept_q
+        st.q_start = q_start = np.zeros((Qreal,), np.int64)
+        q_start[1:] = np.cumsum(q_len)[:-1]
+        st.n_ev, st.ev_seq = n_ev, ev_seq
+        ev_lo = np.zeros((Qreal,), np.int64)
+        ev_lo[1:] = np.cumsum(n_ev)[:-1]
+        st.ev_P = q_start[evq] + np.arange(evq.shape[0], dtype=np.int64) - ev_lo[evq]
+        st.ao_P = q_start[st.ao_q] + n_ev[st.ao_q] + st.ao_rank
+
+    def _base_positions(self, rows: np.ndarray, st: _OrderCarry, at: _OrderCarry, gone=None):
+        """Position in the order of base rows `rows` of the jobs table: the
+        queue's start, its evictees, the alive base rows of the queue before
+        the row (`st.cs`, this cycle's prefix count), and the alive overlay
+        rows that sort before it, all but the first as `at` has them.  With
+        `gone` (this cycle's tombstoned base rows, ascending) and last
+        cycle's state as `at`, the position in LAST cycle's order: the rows
+        tombstoned since still count."""
+        rows = np.asarray(rows, np.int64)  # like `gone`, `bq` and ov_pos: no probe is cast
+        q = self.jobs.qi[rows].astype(np.int64)
+        lo = np.asarray(st.bq[q], np.int64)
+        rank = st.cs[rows].astype(np.int64) - st.cs[lo]
+        if gone is not None:
+            rank += gone.searchsorted(rows, "left") - gone.searchsorted(lo, "left")
+        o_lo = at.ao_lo[q]
+        rank += np.clip(at.ao_pos.searchsorted(rows, "right"), o_lo, at.ao_lo[q + 1]) - o_lo
+        return at.q_start[q] + at.n_ev[q] + rank
+
+    def _order_patched(self, Qreal: int, Nreal: int):
+        """This cycle's candidate order from last cycle's, by the tables'
+        delta (PR 30); an `_OrderPatch`, or the reason (a string) why not --
+        `_patch_blocker`'s, or "mismatch" where the delta did not check out
+        -- in which case NOTHING has been mutated and the caller rebuilds
+        from scratch.
+
+        The order is, queue by queue, the queue's evictee slots and then its
+        live singles in key order up to the lookback.  Between two cycles
+        without a table merge, a change of caps or of the queue set, and
+        with no gang units, only this can change it:
+
+          * base rows tombstoned since (the diff of the carried alive flags
+            against the table's): out, at the position last cycle gave them;
+          * the overlay, taken whole each cycle (it is at most a sixteenth
+            of the table): its alive rows are ranked afresh; those whose
+            slot was dirtied come out of the old order and into the new;
+          * rows crossing their queue's lookback because of the two above:
+            the base rows between the old cut and the new one, and overlay
+            rows whose kept flag moved (their flips dirty the slot);
+          * the evictees, recomputed from the run table (as wide as the
+            running set): dirtied slots out of the old list and into the new.
+
+        Every other entry survives in place, which is exactly the rebuilt
+        path's rule (a survivor is in both orders and not dirtied), so the
+        splice is the same one the [G]-wide diff would have found.  Before
+        anything is committed the delta is checked against what it must
+        explain: survivors of the overlay and of the evictees are the same
+        sequences, the lengths add up, every dirtied slot that holds a job
+        is an overlay row or a row that crossed a cut."""
+        why = self._patch_blocker(Qreal)
+        if why:
+            return why
+        old = self._order_carry
+        jt, rt, sg, rr = self.jobs, self.runs, self._sg, self._rr
+        sn, s_cap = jt.sorted_n, sg.cap
+        G = self._prev_gq.shape[0]
+        bufs = self._order_buffers(sn)
+
+        # --- the cycle's tombstones among the base rows ------------------------
+        neq = bufs["neq"][:sn]
+        np.not_equal(old.alive, jt.alive[:sn], out=neq)
+        gone = np.flatnonzero(neq)
+
+        # --- the table's side, as it stands now --------------------------------
+        new = self._order_tables_state(Qreal, old.bq)
+        new.key, new.known, new.alive = old.key, old.known, old.alive
+
+        # --- rows crossing a lookback cut --------------------------------------
+        on_rows, off_rows = [], []
+        for q in np.flatnonzero(new.cut != old.cut).tolist():
+            a, b = int(old.cut[q]), int(new.cut[q])
+            if b > a:
+                on_rows.append(a + np.flatnonzero(jt.alive[a:b]))
+            else:
+                off_rows.append(b + np.flatnonzero(jt.alive[b:a]))
+        none = np.zeros((0,), np.int64)
+        on_rows = np.concatenate(on_rows) if on_rows else none
+        off_rows = np.concatenate(off_rows) if off_rows else none
+        on_slots = jt.slot[on_rows].astype(np.int64)
+        off_slots = jt.slot[off_rows].astype(np.int64)
+        if sg.valid[on_slots].any() or not sg.valid[off_slots].all():
+            return "mismatch"
+        ao_valid = sg.valid[new.ao_slot]
+        flip_on = np.concatenate([on_slots, new.ao_slot[new.ao_kept & ~ao_valid]])
+        flip_off = np.concatenate([off_slots, new.ao_slot[~new.ao_kept & ao_valid]])
+
+        # --- the run table's side ----------------------------------------------
+        ev_rows, evq, rflip_on, rflip_off = self._run_candidates(Qreal, Nreal, None)
+        ev_seq = (s_cap + rt.slot[ev_rows].astype(np.int64)).astype(np.int32)
+        n_ev = np.bincount(evq, minlength=Qreal)
+        self._order_positions(new, Qreal, evq, ev_seq, n_ev)
+        q_len = n_ev + new.kept_q
+        L0, L1 = self._prev_gq_real, int(q_len.sum())
+
+        # --- every slot dirtied since the last order, flips included -----------
+        dirty = bufs["false"]
+        sg_dirty = np.concatenate(
+            [np.asarray(sg.dirty_log, np.int64), flip_on, flip_off]
+        )
+        ev_dirty = s_cap + np.concatenate(
+            [np.asarray(rr.dirty_log, np.int64), rflip_on, rflip_off]
+        )
+        sg_dirty = sg_dirty[sg_dirty < G]
+        ev_dirty = ev_dirty[ev_dirty < G]
+        dirty[sg_dirty] = True
+        dirty[ev_dirty] = True
+        try:
+            # --- out of the old order, into the new ----------------------------
+            gone_kept = gone[gone < old.cut[jt.qi[gone]]]
+            old_ev = dirty[old.ev_seq]
+            old_ao = old.ao_kept & dirty[old.ao_slot]
+            rem = np.concatenate(
+                [
+                    old.ev_P[old_ev],
+                    self._base_positions(
+                        np.concatenate([gone_kept, off_rows]), new, old, gone=gone
+                    ),
+                    old.ao_P[old_ao],
+                ]
+            )
+            new_ev = dirty[ev_seq]
+            new_ao = new.ao_kept & dirty[new.ao_slot]
+            ins = np.concatenate(
+                [new.ev_P[new_ev], self._base_positions(on_rows, new, new), new.ao_P[new_ao]]
+            )
+            vals = np.concatenate(
+                [
+                    ev_seq[new_ev],
+                    on_slots.astype(np.int32),
+                    new.ao_slot[new_ao].astype(np.int32),
+                ]
+            )
+            # --- does the delta explain what it must? --------------------------
+            held = sg_dirty[sg.ids[sg_dirty] != b""]
+            explained = np.concatenate([new.ao_slot, on_slots, off_slots])
+            mark = bufs["true"]
+            mark[explained] = False
+            unexplained = mark[held].any()
+            mark[explained] = True
+            if (
+                unexplained
+                or L0 - rem.shape[0] + ins.shape[0] != L1
+                or not np.array_equal(old.ev_seq[~old_ev], ev_seq[~new_ev])
+                or not np.array_equal(
+                    old.ao_slot[old.ao_kept & ~old_ao], new.ao_slot[new.ao_kept & ~new_ao]
+                )
+            ):
+                return "mismatch"
+        finally:
+            dirty[sg_dirty] = False
+            dirty[ev_dirty] = False
+        rem.sort()
+        by_pos = np.argsort(ins, kind="stable")
+        ins, vals = ins[by_pos], vals[by_pos]
+        if (rem.size and (rem[0] < 0 or rem[-1] >= L0 or (np.diff(rem) == 0).any())) or (
+            ins.size and (ins[0] < 0 or ins[-1] >= L1 or (np.diff(ins) == 0).any())
+        ):
+            return "mismatch"
+
+        # --- commit: the flips, the order vector, the carried state ------------
+        _flip_participation(sg, self._demand_sg, flip_on, flip_off)
+        _flip_participation(rr, self._demand_run, rflip_on, rflip_off)
+        which = 0 if self._prev_gq is not bufs["gq"][0] else 1
+        out = bufs["gq"][which]
+        _splice_into(out, self._prev_gq, L0, L1, rem, ins, vals, bufs["step"], bufs["src"])
+        stale = bufs["gq_len"][which]
+        if stale > L1:
+            out[L1:stale] = 0
+        bufs["gq_len"][which] = L1
+        new.alive[gone] = False
+        self._order_carry = new
+
+        patch = _OrderPatch()
+        patch.gq_gang, patch.n_real, patch.q_len, patch.evq = out, L1, q_len, evq
+        patch.rem, patch.ins, patch.vals = rem, ins, vals
+        return patch
+
+    def _carry_after_rebuild(self, Qreal: int, evq, ev_seq) -> _OrderCarry:
+        """Seed the carried state from a from-scratch order (the flips are
+        applied, `_prev_gq` is this cycle's vector): the next cycle patches
+        from here."""
+        st = self._order_tables_state(Qreal, None)
+        st.key = self._order_key(Qreal)
+        st.known = self.queue_known.copy()
+        st.alive = self.jobs.alive[: self.jobs.sorted_n].copy()
+        self._order_positions(st, Qreal, evq, ev_seq, np.bincount(evq, minlength=Qreal))
+        return st
 
     # ---------------------------------------------------- gang slow path ----
 

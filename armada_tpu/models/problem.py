@@ -173,12 +173,21 @@ class HostContext:
     # for re-placement, do not count).
     queues_padded: int = 0
     queues_pending: int = 0
+    # The assemble layer's counters for the round's stats JSON
+    # (RoundOutcome.assemble): `assemble_rows_rebuilt` (rows of the
+    # candidate order rebuilt from scratch: 0 where last cycle's order was
+    # patched) and `id_bytes_copied` (id bytes copied since the last
+    # assemble: before-images under outstanding snapshots, growth).  Only
+    # the slab path (IncrementalBuilder.assemble_delta) fills it.
+    assemble_stats: dict = dataclasses.field(default_factory=dict)
     # Vectorized decode support (models/incremental.py): per-gang single job
     # id as bytes (b"" for evictee slots and multi-member units), overrides
     # for multi-member units, and per-run job ids as bytes.  When set,
     # gang_members / run_job_ids may be None and decode_result takes the
     # numpy path -- a 1M-gang Python loop in decode would cost the time the
-    # incremental builder saves.
+    # incremental builder saves.  On the slab path both vectors are
+    # slab.IdSnapshot: they index like the array (an int, an index array)
+    # and keep giving the round's ids after the builder reuses the slots.
     gang_ids_vec: Optional[np.ndarray] = None
     gang_members_over: dict = dataclasses.field(default_factory=dict)
     run_ids_vec: Optional[np.ndarray] = None
@@ -249,6 +258,10 @@ class RoundOutcome:
     # `queues_scheduled` (distinct queues leased from) is the caller's to
     # add, who knows each job's queue (scheduler/algo.py).
     queue_axis: dict = dataclasses.field(default_factory=dict)
+    # The assemble layer's counters (HostContext.assemble_stats:
+    # `assemble_rows_rebuilt`, `id_bytes_copied`), which ride the stats JSON
+    # beside the queue axis' (sidecar._stats_of).
+    assemble: dict = dataclasses.field(default_factory=dict)
     # Market pools: bid price of the gang that crossed the spot cutoff this
     # round (queue_scheduler.go:135-150); None when unset/not market.
     spot_price: Optional[float] = None

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 
 import numpy as np
 
@@ -72,6 +73,116 @@ def _pad_rows(arr: np.ndarray, k: int) -> np.ndarray:
     return out
 
 
+class IdSnapshot:
+    """A job-id vector as it stood when the snapshot was taken, at the cost
+    of what has been overwritten since and never of its width.
+
+    `_live` is the owner's array, written in place after the snapshot;
+    `_before` holds the before-image of every slot overwritten while this
+    snapshot was outstanding (recorded by :meth:`SnapshotIds.write` BEFORE
+    the write lands).  A read takes the live value first and the overlay
+    second: a write that races the read has then either not landed (the live
+    value is still the snapshot's) or has already left its before-image.
+    Indexes like the array it stands for, as far as its readers do: an int
+    gives one id (``members_of``), a 1-D array of non-negative ints a fresh
+    array of ids (``ctx.gang_ids_vec[g2]``)."""
+
+    __slots__ = ("_live", "_before", "__weakref__")
+
+    def __init__(self, live: np.ndarray):
+        self._live = live
+        self._before: dict = {}
+
+    def __getitem__(self, i):
+        before = self._before
+        if isinstance(i, (int, np.integer)):
+            v = self._live[i]
+            b = before.get(int(i))
+            return v if b is None else b
+        idx = np.asarray(i, np.int64)
+        out = self._live[idx]
+        if before and idx.size:
+            held = np.fromiter(list(before), np.int64)
+            for j in np.flatnonzero(np.isin(idx, held)).tolist():
+                out[j] = before[int(idx[j])]
+        return out
+
+
+class SnapshotIds:
+    """A live id vector that hands out point-in-time snapshots.
+
+    Replaces the copy-on-write of the whole vector (57 MB at 1.2M slots,
+    every cycle, for a round that rewrites a thousand of them): the owner
+    writes through :meth:`write`, which first leaves the old ids of the
+    slots it is about to overwrite with every outstanding snapshot that
+    does not hold them yet.  A snapshot therefore costs what is written
+    while it is held; one that is dropped costs nothing more (the registry
+    is weak), and one that is retained pays per overwritten slot, never a
+    whole copy.  `bytes_copied` counts the before-images taken and the
+    growth copies, in bytes, since the owner last read it."""
+
+    def __init__(self, n: int = 0):
+        self.live = np.zeros((n,), _ID_DTYPE)
+        self._snaps: list = []
+        self.bytes_copied = 0
+
+    def take_bytes_copied(self) -> int:
+        n, self.bytes_copied = self.bytes_copied, 0
+        return n
+
+    def snapshot(self) -> IdSnapshot:
+        snap = IdSnapshot(self.live)
+        self._snaps = [r for r in self._snaps if r() is not None]
+        self._snaps.append(weakref.ref(snap))
+        return snap
+
+    def replace(self, new_live: np.ndarray, copied: int) -> None:
+        """A fresh array takes over (growth, a change of width), `copied`
+        ids carried into it; outstanding snapshots keep the old one, which
+        nothing writes any more."""
+        self.live = new_live
+        self._snaps = []
+        self.bytes_copied += copied * new_live.dtype.itemsize
+
+    def write(self, slots, values, span: str = "") -> None:
+        """``live[slots] = values`` (an int or an int array).  With `span`
+        set, the before-images are taken under a trace span of that name
+        carrying their `bytes`; opened only when there is something to
+        take, so a write no snapshot sees leaves no span."""
+        live = self.live
+        if self._snaps:
+            snaps = [s for s in (r() for r in self._snaps) if s is not None]
+            if len(snaps) != len(self._snaps):
+                self._snaps = [weakref.ref(s) for s in snaps]
+            if snaps:
+                slot_list = (
+                    [int(slots)]
+                    if isinstance(slots, (int, np.integer))
+                    else np.asarray(slots).tolist()
+                )
+                todo = [
+                    (s._before, [k for k in slot_list if k not in s._before])
+                    for s in snaps
+                ]
+                n = sum(len(new) for _, new in todo)
+                if n:
+                    nbytes = n * live.dtype.itemsize
+                    self.bytes_copied += nbytes
+                    if span:
+                        with _trace().span(span, bytes=nbytes):
+                            self._record(todo)
+                    else:
+                        self._record(todo)
+        live[slots] = values
+
+    def _record(self, todo) -> None:
+        live = self.live
+        for before, new in todo:
+            if new:
+                for k, old in zip(new, live[new].tolist()):
+                    before.setdefault(k, old)
+
+
 class RowSlab:
     """Append-only columnar slot store with free-list reuse.
 
@@ -89,7 +200,9 @@ class RowSlab:
         self.epoch = 0
         self._columns = dict(columns)  # name -> dtype (besides req/ids/valid)
         self.req = np.zeros((0, num_resources), np.float32)
-        self.ids = np.zeros((0,), _ID_DTYPE)
+        # The ids vector hands decode contexts snapshots (share_ids) that
+        # cost what is overwritten while they are held, never a copy.
+        self._ids = SnapshotIds()
         self.valid = np.zeros((0,), bool)
         for name, dt in self._columns.items():
             setattr(self, name, np.zeros((0,), dt))
@@ -97,11 +210,10 @@ class RowSlab:
         # it once per cycle (single consumer; a skipped bundle is caught by
         # the DeltaBundle seq guard and forces a full upload).
         self.dirty_log: list[int] = []
-        # Copy-on-write guard for the ids vector: share_ids() hands the
-        # CURRENT array to a decode context; the next in-place id write
-        # copies first, so the snapshot costs nothing on mutation-free
-        # cycles and otherwise lands in the overlapped decode shadow.
-        self._ids_shared = False
+
+    @property
+    def ids(self) -> np.ndarray:
+        return self._ids.live
 
     def _grow(self, need: int) -> None:
         # GEOMETRIC growth (>=1.5x), not fixed-bucket: the slab cap IS the
@@ -121,8 +233,8 @@ class RowSlab:
                 ((scaled + self.bucket - 1) // self.bucket) * self.bucket,
             )
         self.req = _grow2(self.req, new_cap)
-        self.ids = _grow2(self.ids, new_cap)  # fresh object: snapshots keep the old one
-        self._ids_shared = False
+        # a fresh array: outstanding snapshots keep the old one
+        self._ids.replace(_grow2(self.ids, new_cap), self.cap)
         self.valid = _grow2(self.valid, new_cap)
         for name in self._columns:
             setattr(self, name, _grow2(getattr(self, name), new_cap))
@@ -140,20 +252,18 @@ class RowSlab:
             self.hw += fresh
         return np.asarray(slots, np.int64)
 
-    def share_ids(self) -> np.ndarray:
-        """Snapshot of the ids vector for a decode context (copy-on-write)."""
-        self._ids_shared = True
-        return self.ids
+    def share_ids(self) -> IdSnapshot:
+        """Snapshot of the ids vector for a decode context."""
+        return self._ids.snapshot()
 
-    def _own_ids(self) -> None:
-        if self._ids_shared:
-            self.ids = self.ids.copy()
-            self._ids_shared = False
+    def take_id_bytes_copied(self) -> int:
+        """Id bytes copied on behalf of snapshots (and by growth) since the
+        last call."""
+        return self._ids.take_bytes_copied()
 
     def write_batch(self, slots: np.ndarray, ids, reqs, **cols) -> None:
         self.req[slots] = reqs
-        self._own_ids()
-        self.ids[slots] = ids
+        self._ids.write(slots, ids)
         self.valid[slots] = True
         for name, vals in cols.items():
             getattr(self, name)[slots] = vals
@@ -161,8 +271,7 @@ class RowSlab:
 
     def release(self, slot: int) -> None:
         self.valid[slot] = False
-        self._own_ids()
-        self.ids[slot] = b""
+        self._ids.write(slot, b"")
         self.free.append(slot)
         self.dirty_log.append(slot)
 
@@ -325,6 +434,10 @@ class DeviceDeltaCache:
         # uploads (the fleet rarely changes).
         self._host_ids: dict = {}
         self._node_dev: dict = {}
+        # The bucket sizes each scatter variant has run with on the standing
+        # device problem (_buckets_for): a delta one bucket step under one of
+        # them does not ask for its own, uncompiled one.
+        self._variants: dict = {}
         # Race harness (analysis/tsan, ARMADA_TSAN=1): every mutation must
         # commit under the generation it began under; reset() bumps.  A
         # zombie watchdog worker finishing a scatter after a device-loss
@@ -346,6 +459,7 @@ class DeviceDeltaCache:
         self._prev = None
         self._host_ids = {}
         self._node_dev = {}
+        self._variants = {}
         self.resets += 1
 
     def _to_device(self, arr, name=None):
@@ -378,7 +492,34 @@ class DeviceDeltaCache:
             _APPLY = _make_apply()
         return _APPLY
 
+    def _buckets_for(self, variant: tuple, counts: tuple) -> tuple:
+        """Padded sizes for a scatter of `counts` indices (one entry an index
+        vector): the counts' own buckets, unless this `variant` of the
+        scatter program has not run with them on the standing device problem
+        and HAS run with sizes that hold them within one bucket step (at most
+        4x the padding, a few KB); then those.  A cycle whose delta dips
+        under a bucket it is usually over (the steady envelope's splice sits
+        just above 1,024 entries and falls under it once in a few dozen
+        cycles: PERF.md section 7, in the parent as in the change) would
+        otherwise meet a scatter program nobody compiled: seconds of XLA
+        inside a served cycle.  One step and no further: after a burst (a
+        70k-row catch-up runs at 262,144) a steady 280-row delta compiles
+        its own 1,024 once, it never pads up to the burst's."""
+        want = tuple(_pad_bucket(n) for n in counts)
+        used = self._variants.setdefault(variant, set())
+        if want not in used:
+            near = [
+                v
+                for v in used
+                if all(b <= a <= _pad_bucket(b + 1) for a, b in zip(v, want))
+            ]
+            if near:
+                return min(near, key=sum)
+            used.add(want)
+        return want
+
     def _full_upload(self, problem):
+        self._variants = {}  # new shapes: every variant compiles anew anyway
         out = []
         for name, arr in zip(problem._fields, problem):
             if (
@@ -445,14 +586,7 @@ class DeviceDeltaCache:
         # ship: it rides the span as `program` and `bucket` (rows / runs /
         # splice / full fields), so a window that alternates between
         # variants, or meets a new one, shows in the trace.
-        kg = _pad_bucket(bundle.sg_idx.shape[0])
-        kr = _pad_bucket(bundle.rr_idx.shape[0])
         splice = bundle.gq_splice is not None
-        kq = (
-            _pad_bucket(max(bundle.gq_splice[0].shape[0], bundle.gq_splice[1].shape[0]))
-            if splice
-            else 0
-        )
         # full fields whose host object changed (the others' device copies
         # are current)
         changed = {
@@ -460,6 +594,13 @@ class DeviceDeltaCache:
             for name, arr in bundle.fulls.items()
             if self._host_ids.get(name) is not arr
         }
+        counts = (bundle.sg_idx.shape[0], bundle.rr_idx.shape[0]) + (
+            (max(bundle.gq_splice[0].shape[0], bundle.gq_splice[1].shape[0]),)
+            if splice
+            else ()
+        )
+        kg, kr, *kq = self._buckets_for(("apply", splice, tuple(changed)), counts)
+        kq = kq[0] if splice else 0
         with _trace().span(
             "devcache_apply",
             full_upload=False,
@@ -543,8 +684,7 @@ class DeviceDeltaCache:
             or seq != self._seq + 1
         ):
             return False
-        kg = _pad_bucket(sg_idx.shape[0])
-        kr = _pad_bucket(rr_idx.shape[0])
+        kg, kr = self._buckets_for(("content",), (sg_idx.shape[0], rr_idx.shape[0]))
         with _trace().span(
             "scatter_content",
             sg_rows=int(sg_idx.shape[0]),

@@ -422,6 +422,8 @@ def _stats_of(result: SchedulerResult, trace: Optional[dict] = None) -> str:
             # queues, queues_padded, queues_pending, queues_scheduled and,
             # where queue_stats is collected, fair_share_iterations
             **s.outcome.queue_axis,
+            # assemble_rows_rebuilt, id_bytes_copied (the slab path)
+            **s.outcome.assemble,
         }
         if s.market:
             entry["indicative_prices"] = s.indicative_prices

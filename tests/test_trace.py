@@ -549,21 +549,26 @@ def test_scatter_spans_name_the_compiled_variant(_fresh_recorder):
 def test_g_ids_copy_only_on_a_cycle_that_copies(_fresh_recorder):
     sidecar, sid, F = _served_session()
     sync1, round1, _ = _served_cycle(sidecar, sid, F, 0, 6)
-    # before any round nothing shares the id vector: the first sync's submits
-    # write in place; the round's assemble shares it, and its own leases
-    # leaving the backlog pay the one copy
+    # before any round nothing holds a snapshot of the id vector: the first
+    # sync's submits write in place; the round's assemble hands its context a
+    # snapshot, and its own leases leaving the backlog pay the one copy: the
+    # before-images of the six slots they clear (PR 30), not the vector
     assert not _find(sync1, "g_ids_copy")
     copies = _find(round1, "g_ids_copy")
-    assert len(copies) == 1 and copies[0].args["bytes"] > 0
+    assert len(copies) == 1 and copies[0].args["bytes"] == 6 * 48
     sync2, round2, resp2 = _served_cycle(sidecar, sid, F, 100, 6)
-    assert not _find(sync2, "g_ids_copy")  # owned since round 1's copy
+    # the sync's submits take the slots the round just cleared, whose
+    # before-images the snapshot already holds: nothing more to copy
+    assert not _find(sync2, "g_ids_copy")
     assert len(resp2.scheduled) == 6 and len(_find(round2, "g_ids_copy")) == 1
+    # six slots under at most the two rounds' snapshots, whatever the width
+    assert 6 * 48 <= _find(round2, "g_ids_copy")[0].args["bytes"] <= 2 * 6 * 48
     # a sync and a round that change nothing copy nothing
     from tests.test_pipeline import NOW_NS
     from armada_tpu.ops.trace import recorder
     from armada_tpu.rpc import rpc_pb2 as pb
 
-    for _ in range(2):  # the second round finds the vector still shared
+    for _ in range(2):  # the second round finds the snapshots still held
         sidecar.handle_round(pb.ScheduleRoundRequest(session_id=sid, now_ns=NOW_NS))
     assert not _find(recorder().last()[-1].root, "g_ids_copy")
 
