@@ -836,12 +836,6 @@ def cmd_serve(args):
         # the whole plane (scheduler loop, sidecar sessions) to the
         # sequential cycle order.
         os.environ["ARMADA_PIPELINE"] = "0"
-    if getattr(args, "commit_k", None) is not None:
-        # schedule_round resolves ARMADA_COMMIT_K per call OUTSIDE its jit
-        # boundary, so one env set arms every round this plane runs
-        # (scheduler loop, sidecar sessions, mesh reruns) with compile
-        # caches keyed on the resolved K.
-        os.environ["ARMADA_COMMIT_K"] = str(args.commit_k)
     if getattr(args, "pool_parallel", False):
         # Read per cycle (core/pipeline.pool_parallel_enabled), so one env
         # set arms the scheduler loop AND sidecar sessions; per-cycle
@@ -1238,16 +1232,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="external lookout database (postgres://...), the reference's "
         "second Postgres -- a FRESH database this plane owns.  Default: "
         "embedded SQLite under --data-dir",
-    )
-    srv.add_argument(
-        "--commit-k",
-        type=int,
-        dest="commit_k",
-        help="arm the conflict-free multi-commit kernel: up to K certified-"
-        "independent placements commit per while-loop iteration (sets "
-        "ARMADA_COMMIT_K process-wide, so the scheduler loop, sidecar "
-        "sessions and mesh rounds all compile the same body; default 1 = "
-        "the single-commit kernel; decisions are bit-identical at any K)",
     )
     srv.add_argument(
         "--no-pipeline",

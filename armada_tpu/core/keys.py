@@ -63,8 +63,8 @@ class SchedulingKey:
     uniformity: tuple[str, str] = ("", "")
     # Per-node-type effective-throughput map (JobSpec.node_type_scores,
     # sorted).  Part of key identity because the key must determine EVERY
-    # placement-relevant property: the per-key fit cache and commit_k's head
-    # certification key on it, and a type-sensitive job sharing a key class
+    # placement-relevant property: the per-key fit cache and the unfeasible-
+    # key registration key on it, and a type-sensitive job sharing a key class
     # with an insensitive twin would poison both (docs/lint.md ledger:
     # "key must absorb the type axis").  () = type-insensitive.
     type_scores: tuple[tuple[str, float], ...] = ()

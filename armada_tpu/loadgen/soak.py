@@ -75,7 +75,7 @@ class SoakConfig:
     # Partition-parallel ingestion (ingest/shards.py): run the soak world's
     # ingesters as this many shard workers.  None = ARMADA_INGEST_SHARDS
     # (the serve knob) or 1; the run's save/restore carries the armed value
-    # through the fault/crash legs like ARMADA_COMMIT_K.
+    # through the fault/crash legs.
     ingest_shards: Optional[int] = None
     # Sharded materialized store (ingest/storeunion.py): each ingest shard
     # leg writes its own SQLite file behind the union reader.  None =
@@ -455,12 +455,9 @@ def run_soak(cfg: SoakConfig, data_dir: str, stub_probe: bool = True) -> dict:
             "ARMADA_TSAN",
             "ARMADA_FAULT_HANG_S",
             "ARMADA_REPROBE_INTERVAL_S",
-            # The armed multi-commit width rides through the drill (and its
-            # kill/restart resume) untouched, so soak/chaos legs exercise
-            # the configuration the operator armed, not a silent K=1.
-            "ARMADA_COMMIT_K",
-            # Likewise the armed ingest-shard count (the rebuilt post-crash
-            # world must re-shard identically).
+            # The armed ingest-shard count rides through the drill (and its
+            # kill/restart resume) untouched: the rebuilt post-crash world
+            # must re-shard identically.
             "ARMADA_INGEST_SHARDS",
             # ... and the store-shard width (permanent per store dir -- a
             # post-crash rebuild at a different width would be refused).
@@ -633,11 +630,6 @@ def run_soak(cfg: SoakConfig, data_dir: str, stub_probe: bool = True) -> dict:
                 for k in ("backend", "fallbacks", "promotions")
             },
         }
-        from armada_tpu.models.fair_scheduler import resolve_commit_k
-
-        # the ARMED multi-commit width (schedule_round may clamp the
-        # effective K to the queue-axis width per pool)
-        report["commit_k"] = resolve_commit_k()
         report["ingest_shards"] = world.ingest_shards
         report["store_shards"] = world.store_shards
         # Flat headline keys (the bench-JSON soak_* shape).

@@ -107,14 +107,6 @@ def _round_env(problem, ctx, config, shadow_work, explain_enabled):
             and not bool(problem.market)
         ),
     )
-    if bool(problem.market):
-        # Market rounds bypass multi-commit DYNAMICALLY inside the body
-        # (bid order + spot crossing are order-dependent), but an armed
-        # ARMADA_COMMIT_K would still compile and pay the K-body's
-        # certification tables every trip with zero possible commits --
-        # force the single-commit compile for market pools, like
-        # prefer_large above (non-market pools keep the env resolution).
-        kernel_kwargs["commit_k"] = 1
     shadow = _ShadowOnce(shadow_work)
     explain_armed = False
     if explain_enabled:
@@ -987,18 +979,13 @@ def _finish_body(h: _RoundHandle):
         if h.ver_check is not None:
             h.ver_check()
         outcome = h.finish()
-    # Iteration-count legibility (ARMADA_COMMIT_K): the round span carries
-    # the physical trip count next to the logical one, so a multi-commit
-    # regression (certification truncating to 1) is visible in any trace
-    # without a TPU.  Values ride the compact decode buffer -- no extra
-    # transfer.
+    # The round span carries the trips of the placement loop and how many of
+    # them gathered the whole skip window again.  Values ride the compact
+    # decode buffer -- no extra transfer.
     if outcome.kernel_iters:
         trace.annotate(
             kernel_iters=outcome.kernel_iters,
             window_refills=outcome.window_refills,
-            commits_per_iter=round(
-                outcome.num_iterations / outcome.kernel_iters, 2
-            ),
         )
 
     # Gang-txn rollback (nodedb.go:347 ScheduleManyWithTxn: a gang is one txn,

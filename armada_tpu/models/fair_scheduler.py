@@ -93,11 +93,11 @@ class RoundResult(NamedTuple):
     # (models/explain.py) to attribute still-pending jobs to
     # `fairness-capped` rather than `round-terminated`.
     q_killed: jax.Array  # bool[Q]
-    # Physical while-loop body applications.  `iterations` stays the LOGICAL
-    # sequential step count (bit-identical at any commit_k/batch_k -- it
-    # feeds TERM_MAX_ITER); kernel_iters is the observability counter the
-    # multi-commit work shrinks (commits_per_iter = iterations/kernel_iters).
-    # Excluded from the bit-equality contract the parity suites pin.
+    # Trips of the placement loop.  Equal to `iterations` (which feeds
+    # TERM_MAX_ITER) since every trip is one sequential step; kept as its own
+    # counter because the stats JSON and perfbench/layers read it (ROADMAP.md
+    # names the duplicate as a debt).  Excluded from the bit-equality
+    # contract the parity suites pin.
     kernel_iters: jax.Array  # i32
     # Trips on which more queues moved their cursor than the body rebuilds
     # row by row, so the carried skip window was gathered whole again (see
@@ -318,8 +318,6 @@ def _make_place_iteration(
     q_budget=None,
     cache_slots: int = 0,
     max_iterations: int = 0,
-    batch_k: int = 1,
-    commit_k: int = 1,
 ):
     """One trip of the placement loop: select (cursor advance, candidate,
     queue order, gates), fit, commit, and the upkeep of the carried window.
@@ -336,15 +334,14 @@ def _make_place_iteration(
     AT THE TOP OF EVERY TRIP THE CARRIED TABLES EQUAL
     _skip_window(p, p.q_start, c.q_head, c.g_state, c.key_bad, check_keys).
     Everything the body derives from them (in_r, skippable, nskip, q_head,
-    cand, head_visible, and parked / nn / tail_known / hidden of the batch_k
-    and commit_k extensions) is therefore the same expression of the same
+    cand, head_visible) is therefore the same expression of the same
     values as when the window was gathered every trip.  The `window` scope
     at the body's end keeps it: what the trip decided is patched
-    elementwise (g_state changes at the committed gangs, key_bad at one
-    key); a queue whose cursor moved has its row gathered at the new head,
-    `rows` of them (one per gang a trip can commit); a trip that moves more
-    cursors than that -- a key registration retiring several queues' heads
-    -- takes a lax.cond to the full gather and counts in window_refills.
+    elementwise (g_state changes at the committed gang, key_bad at one
+    key); the one queue whose cursor moved has its row gathered at the new
+    head; a trip that moves more cursors than one -- a key registration
+    retiring several queues' heads -- takes a lax.cond to the full gather
+    and counts in window_refills.
     Loop state that changes at one index a trip is carried and patched,
     never gathered again (CLAUDE.md).  Under jax.vmap (the stacked round)
     the cond is a select and both branches run: that form keeps the full
@@ -358,64 +355,20 @@ def _make_place_iteration(
 
     max_iterations > 0 compiles an `active` gate into the body: a step past
     done/max-iterations is a true no-op (no cursor movement, no commits, no
-    iteration count), which is what lets schedule_round UNROLL several body
-    applications inside one while_loop iteration with bit-exact semantics
-    (the tail steps of the last unrolled group self-disable).
+    iteration count).  The loop's own condition already stops there, so the
+    gate is constant true now (ROADMAP.md names it as a debt).
 
-    batch_k > 1 appends the CERTIFIED BATCH extension (SURVEY section 7
-    "schedule K gangs per device step"): after the normal head placement,
-    up to batch_k-1 additional queue heads commit in the same iteration --
-    each one proven to be exactly what the sequential loop's next iteration
-    would have decided (cost order vs every placed queue's next candidate
-    with the argmin tie-break, node choice re-derived exactly at the <=K
-    nodes this batch touched, caps/burst/spot walked in commit order).
-    Anything unprovable cuts the batch and defers to the next iteration, so
-    the batch commits a certified PREFIX of the sequential order or
-    nothing; decisions are bit-identical at any batch_k.  Requires
-    cache_slots == 0 and not prefer_large (enforced by schedule_round).
-
-    commit_k > 1 appends the CONFLICT-FREE MULTI-COMMIT extension
-    (ARMADA_COMMIT_K): unlike batch_k's serial replay (K sub-picks, each
-    with its own argmin/cond chain -- K times the op count, the measured
-    r3 dead end), this takes the top-K queue heads in ONE ordered
-    selection (lax.top_k over the same order keys the argmin reads; ties
-    break to the lower index, matching argmin) and certifies the set
-    non-interacting with vectorized [K]/[KxK] checks whose op count is
-    CONSTANT in K:
-      * pairwise-distinct queues by construction (top_k ranks), so no
-        pick perturbs another's fair-share row -- and each placed queue's
-        NEXT candidate cost is proven to not precede any later pick
-        (strictly greater, or equal with a higher queue index: the exact
-        argmin tie-break), using the sequential association
-        ((q_alloc + req) + penalty) + next_req;
-      * singles only -- gangs, evictees, banned (retry anti-affinity)
-        candidates and market rounds truncate (their replay semantics are
-        order-dependent; they run as exact heads next iteration);
-      * pairwise-distinct nodes among the extension picks, no clean-fit
-        flip and no newly-dominating score at any earlier pick's node
-        (alloc deltas are [KxK]-checked against fit and the first-argmin
-        tie-break), so every pick's node choice equals the sequential
-        re-derivation;
-      * caps/burst/float walked in commit order with the sequential f32
-        accumulation; a pick that WOULD trip a gate truncates so the gate
-        (and its new_blocked/q_killed/termination side effects) fires
-        next iteration.
-    The certified prefix commits in ONE batched scatter per table
-    (constant-value / distinct-lane `mode='drop'` scatters, dummy lanes
-    pushed out of range -- never a gathered-old-value race).  commit_k=1
-    compiles the existing body; decisions are bit-identical at any K
-    (only RoundResult.kernel_iters differs).  Works with the cached-fit
-    body (the maintenance pass re-derives at every committed node);
-    requires batch_k == 1 and not prefer_large (enforced by
-    schedule_round)."""
+    History: three ways to do more than one placement a trip lived here until
+    PR 31 and went because none won on the v5e (PERF.md section 6, PR 21) --
+    `unroll` 8/16 changed nothing (~0.19 s either way), `batch_k` = 8 took
+    0.46 s against 0.19 s, `commit_k` = 8 took 1,012 -> 274 trips and 0.923 s
+    against 0.259 s.  The code:
+    git show 486be4b:armada_tpu/models/fair_scheduler.py"""
     G = p.g_req.shape[0]
     N, R = p.node_total.shape
     Q = p.q_weight.shape[0]
     RJ = p.run_req.shape[0]
     S = cache_slots
-    # Queues whose cursor one trip can move without a key registration: the
-    # head pick's, plus one per committed extension lane.
-    rows = min(max(commit_k, batch_k, 1), Q)
 
     # Loop-invariant masked request tables, gathered per iteration: computing
     # req * node_axes inside the body would depend on the gathered row and
@@ -451,8 +404,8 @@ def _make_place_iteration(
         # Names are operation metadata only: the compiled program is the
         # same operations (tests/test_trace.py).
         with jax.named_scope("select"):
-            # Unrolled-group gate: once done (or past the iteration budget) the
-            # remaining inner steps of the group are exact no-ops.
+            # Once done (or past the iteration budget) a step is an exact
+            # no-op.
             if max_iterations > 0:
                 active = (~c.done) & (c.iterations < max_iterations)
             else:
@@ -833,265 +786,6 @@ def _make_place_iteration(
             # max-iterations exit as exhaustion.
             done = jnp.where(active, ~any_q & ~advanced, c.done)
 
-            extra_iters = jnp.int32(0)
-            touched_nodes = nodes_w
-            if commit_k > 1 or batch_k > 1:
-                # Shared next-candidate cursor tables for BOTH batching shapes
-                # (they are mutually exclusive compiles, so one definition
-                # keeps the load-bearing parked semantics from drifting):
-                # the cursor parks on any undecided entry (in_r & ~skippable);
-                # nn[q, i] = first parked window index at-or-after i (W =
-                # none); a window that reaches past the queue tail proves
-                # nothing hides beyond it.
-                parked = in_r & ~skippable
-                nn = jnp.full((Q, W + 1), W, jnp.int32)
-                for i in range(W - 1, -1, -1):
-                    nn = nn.at[:, i].set(jnp.where(parked[:, i], i, nn[:, i + 1]))
-                tail_known = ~in_r[:, W - 1]
-            if commit_k > 1:
-                # --- conflict-free multi-commit extension (see docstring) --------
-                # Vectorized over the K-1 extension lanes: every check below is
-                # one op with a [E]/[E,E] axis, so the body's op count stays
-                # CONSTANT in K (the batch_k replay's failure mode).
-                E = commit_k - 1
-                S_cap = slot_gang.shape[0]
-                iota_e = jnp.arange(E, dtype=jnp.int32)
-                iota_k = jnp.arange(E + 1, dtype=jnp.int32)
-
-                # (1) ordered top-K queues by the head's own order key.  top_k is
-                # stable (equal keys -> lower index first), matching the argmin
-                # tie-break; rank 0 IS the head queue qstar.
-                _, topq = jax.lax.top_k(-order_key, E + 1)
-                topq = topq.astype(jnp.int32)
-                qe = topq[1:]  # [E] extension queues (pairwise distinct)
-                keye = order_key[qe]
-                ge = cand[qe]
-                card_e = p.g_card[ge]
-                run_e = p.g_run[ge]
-                level_e = p.g_level[ge]
-                key_e = p.g_key[ge]
-                pc_e = p.g_pc[ge]
-                ban_e = p.g_ban_row[ge]
-                req_e = p.g_req[ge]  # [E, R]; card 1 => per-member == total
-                reqn_e = g_req_node[ge]
-                flt_e = g_float_tot[ge]
-
-                # (2) batch gate: the head must have placed (its commit above is
-                # the exact sequential step); market rounds are out (bid order +
-                # spot crossing replay is order-dependent); and no queue may
-                # have skipped past its whole window -- a hidden candidate could
-                # surface mid-batch and outrank a pick.
-                hidden = jnp.any((nskip >= W) & (q_head < p.q_len))
-                batch_ok = placed & ~p.market & ~hidden
-
-                # (3) eligibility: certified picks are non-evictee, unbanned
-                # singles with a live order key; everything else truncates and
-                # runs as an exact head next iteration.
-                elig = (keye < _INF) & (card_e == 1) & (run_e < 0) & (ban_e == 0)
-                if hetero:
-                    # Type-sensitive extension candidates truncate: the
-                    # same-node-stacking proof in (7) reasons about the UNBIASED
-                    # packing score, and a per-key node offset can flip the
-                    # first-argmin between lanes of different keys.  The head
-                    # lane is the exact biased path, so sensitive picks run
-                    # solo-head next iteration (bit-exact, just fewer commits
-                    # per trip on sensitive-heavy mixes).
-                    elig &= (
-                        jnp.where(
-                            key_e >= 0, p.key_type_row[jnp.maximum(key_e, 0)], 0
-                        )
-                        == 0
-                    )
-
-                # (4) caps/burst/float in commit order.  Distinct queues mean the
-                # per-queue gates see no intra-batch accumulation; the global
-                # accumulators replicate the sequential f32 association exactly
-                # (an unrolled E-step scalar chain -- E adds, not E iterations).
-                okc = []
-                run_res, run_flt = sched_res, float_used
-                for i in range(E):
-                    nxt_res = run_res + req_e[i]
-                    nxt_flt = run_flt + flt_e[i]
-                    ci = (
-                        ((sched_count + i + 1) <= p.global_burst)
-                        & jnp.all(nxt_res <= p.round_cap)
-                        & jnp.all(nxt_flt <= p.float_total + 1e-3)
-                    )
-                    if max_iterations > 0:
-                        ci &= (c.iterations + 1 + i) < max_iterations
-                    okc.append(ci)
-                    run_res, run_flt = nxt_res, nxt_flt
-                ok_caps = jnp.stack(okc)
-                ok_caps &= (q_sched[qe] + 1) <= p.perq_burst[qe]
-                ok_caps &= jnp.all(
-                    q_alloc_pc[qe, pc_e] + req_e <= p.pc_queue_cap[pc_e], axis=1
-                )
-
-                # (5) queue-order certification: after each batch queue's head
-                # commits, its NEXT candidate's proposed cost must not precede
-                # any later pick.  Next candidates come from the shared
-                # parked/nn/tail_known tables above.
-                qk = jnp.concatenate([qstar[None], qe])  # [K] batch queues
-                npos = nn[qk, jnp.minimum(pos[qk] + 1, W)]
-                np_safe = jnp.minimum(npos, W - 1)
-                g_next = wg[qk, np_safe]
-                next_tot = p.g_req[g_next] * p.g_card[g_next][:, None].astype(
-                    jnp.float32
-                )
-                # head's commit is already in q_alloc; extension rows add their
-                # own -- the sequential ((q_alloc + req) + penalty) + next_req
-                # association either way.
-                own_req = jnp.concatenate(
-                    [jnp.zeros((1, R), jnp.float32), req_e], axis=0
-                )
-                row_k = q_alloc[qk] + own_req
-                nk = weighted_drf_cost(
-                    (row_k + p.q_penalty[qk]) + next_tot,
-                    p.total_pool, p.drf_mult, p.q_weight[qk],
-                )
-                next_new = p.g_run[g_next] < 0
-                allowed = (
-                    ~(next_new & (new_blocked | q_killed[qk]))
-                    & (p.q_weight[qk] > 0)
-                )
-                nk = jnp.where(allowed, nk, _INF)
-                nk = jnp.where(
-                    npos < W, nk, jnp.where(tail_known[qk], _INF, -_INF)
-                )
-                prior_k = iota_k[:, None] <= iota_e[None, :]  # j commits before e
-                ok_pair = (nk[:, None] > keye[None, :]) | (
-                    (nk[:, None] == keye[None, :]) & (qk[:, None] > qe[None, :])
-                )
-                ok_order = jnp.all(ok_pair | ~prior_k, axis=0)  # [E]
-
-                # (6) fit + node choice per pick against the post-head slab --
-                # the same masked-score first-argmin the cached and general
-                # single paths compute, via the blocked [NB]+[B] pair.
-                static_e = jnp.where(
-                    (key_e >= 0)[:, None],
-                    p.compat[jnp.maximum(key_e, 0)][:, p.node_type],
-                    True,
-                )
-                okn_e = static_e & p.node_ok[None, :]
-                fit0_e = okn_e & _fit_row(alloc[0][None, :, :], reqn_e[:, None, :])
-                fitl_e = okn_e & _fit_row(alloc[level_e], reqn_e[:, None, :])
-                score_lvls = node_packing_score(alloc, p.inv_scale)  # [P1, N]
-                use_clean_e = jnp.any(fit0_e, axis=1)
-                msel = jnp.where(
-                    use_clean_e[:, None],
-                    jnp.where(fit0_e, score_lvls[0][None, :], _INF),
-                    jnp.where(fitl_e, score_lvls[level_e], _INF),
-                )
-                bm_e = jnp.min(msel.reshape(E, NB, B), axis=2)
-                # lint: allow(full-argmin) -- [NB] blocked rows x [B] in-block:
-                # the sanctioned two-level pick, vectorized over the E lanes
-                b_e = jnp.argmin(bm_e, axis=1).astype(jnp.int32)
-                blk = jnp.take_along_axis(
-                    msel.reshape(E, NB, B), b_e[:, None, None], axis=1
-                )[:, 0]
-                # lint: allow(full-argmin) -- [B]-length in-block pick
-                j_in = jnp.argmin(blk, axis=1).astype(jnp.int32)
-                node_e = b_e * B + j_in
-                score_e = jnp.take_along_axis(msel, node_e[:, None], axis=1)[:, 0]
-                found_e = score_e < _INF
-                lvl_sel_e = jnp.where(use_clean_e, 0, level_e)
-
-                # (7) conflict certification with CUMULATIVE prior deltas: for
-                # pick e, every earlier extension pick k (the head's lanes are
-                # already in `alloc`, so the tables above see them exactly)
-                # subtracts its request at its node.  Same-node STACKING is the
-                # dominant best-fit pattern (consecutive same-shape picks pack
-                # the same fullest node until it fills) and certifies exactly:
-                # the node's score only drops, so it stays the first argmin
-                # while it still fits.  Requirements per pick e:
-                #   * no clean-fit flip at any prior node (use_clean provably
-                #     unchanged -- a flip means a node just filled; truncate);
-                #   * pick e's own node still fits under the cumulative delta
-                #     (sequential re-derivation lands on the same node);
-                #   * no OTHER prior node's post-commit score wins pick e's
-                #     first-argmin against its own ADJUSTED score (strictly
-                #     lower, or equal at a lower node index).
-                nj_safe = jnp.clip(node_e, 0, N - 1)
-                prior_f = (iota_e[:, None] > iota_e[None, :]).astype(
-                    jnp.float32
-                )  # [e, k]: pick k commits before pick e
-                samen = (node_e[:, None] == node_e[None, :]).astype(
-                    jnp.float32
-                )  # [j, k]: picks sharing a node
-                cum0 = jnp.einsum("ek,jk,kr->ejr", prior_f, samen, reqn_e)
-                adj0 = alloc[0][nj_safe][None, :, :] - cum0
-                post_fit0 = okn_e[:, nj_safe] & _fit_row(adj0, reqn_e[:, None, :])
-                flip0 = fit0_e[:, nj_safe] & ~post_fit0  # [E(e), E(j)]
-                applies = prior_f * (
-                    lvl_sel_e[:, None] <= level_e[None, :]
-                ).astype(jnp.float32)
-                cum_sel = jnp.einsum("ek,jk,kr->ejr", applies, samen, reqn_e)
-                adj_sel = alloc[lvl_sel_e][:, nj_safe] - cum_sel  # [E, E, R]
-                adj_fit = okn_e[:, nj_safe] & _fit_row(adj_sel, reqn_e[:, None, :])
-                adj_score = node_packing_score(adj_sel, p.inv_scale)  # [E, E]
-                # pick e's own adjusted row is the (e, j=e) diagonal: cum_sel
-                # there sums every prior at n_e with lvl_sel_e[e] in range --
-                # exactly what the sequential recompute would see.
-                diag = jnp.arange(E, dtype=jnp.int32)
-                self_fit = adj_fit[diag, diag]
-                self_score = adj_score[diag, diag]
-                beats = adj_fit & (
-                    (adj_score < self_score[:, None])
-                    | (
-                        (adj_score == self_score[:, None])
-                        & (node_e[None, :] < node_e[:, None])
-                    )
-                )
-                self_pair = node_e[:, None] == node_e[None, :]
-                prior_e = iota_e[None, :] < iota_e[:, None]
-                conflict = jnp.where(self_pair, flip0, flip0 | beats)
-                ok_nodes = self_fit & ~jnp.any(conflict & prior_e, axis=1)
-
-                # (8) the certified prefix
-                raw_ok = batch_ok & elig & ok_caps & ok_order & ok_nodes & found_e
-                ok_e = jnp.cumprod(raw_ok.astype(jnp.int32)).astype(bool)
-                okf = ok_e.astype(jnp.float32)
-                n_ext = jnp.sum(ok_e.astype(jnp.int32))
-
-                # (9) ONE batched commit per table: constant-value /
-                # distinct-lane scatters, dummy lanes pushed out of range with
-                # mode='drop' -- never a gathered-old-value write.
-                commit_nodes = jnp.where(ok_e, node_e, N)
-                lv_c = jnp.arange(num_levels, dtype=jnp.int32)
-                lm_c = (lv_c[:, None] <= level_e[None, :]).astype(jnp.float32)
-                # lint: allow(axis1-scatter) -- the multi-commit's own alloc
-                # update ([E] certified lanes into [P1,N,R]), the batched twin
-                # of the head commit above
-                alloc = alloc.at[:, commit_nodes, :].add(
-                    -lm_c[:, :, None] * (reqn_e * okf[:, None])[None, :, :],
-                    mode="drop",
-                )
-                qe_ok = jnp.where(ok_e, qe, Q)
-                q_alloc = q_alloc.at[qe_ok].add(req_e, mode="drop")
-                q_alloc_pc = q_alloc_pc.at[qe_ok, pc_e].add(req_e, mode="drop")
-                q_sched = q_sched.at[qe_ok].add(1, mode="drop")
-                sched_count = sched_count + n_ext
-                # sequential-association accumulators (they feed ordering
-                # comparisons in later iterations)
-                for i in range(E):
-                    sched_res = sched_res + req_e[i] * okf[i]
-                    float_used = float_used + flt_e[i] * okf[i]
-                    spot_res = spot_res + req_e[i] * okf[i]
-                g_state = g_state.at[jnp.where(ok_e, ge, G)].set(1, mode="drop")
-                sidx = jnp.where(ok_e, cursor + iota_e, S_cap)
-                ext_nodes_w = (
-                    jnp.full((E, slot_width), N, jnp.int32).at[:, 0].set(node_e)
-                )
-                ext_counts_w = (
-                    jnp.zeros((E, slot_width), jnp.int32).at[:, 0].set(1)
-                )
-                slot_gang = slot_gang.at[sidx].set(ge, mode="drop")
-                slot_nodes = slot_nodes.at[sidx].set(ext_nodes_w, mode="drop")
-                slot_counts = slot_counts.at[sidx].set(ext_counts_w, mode="drop")
-                cursor = cursor + n_ext
-                extra_iters = n_ext
-                touched_nodes = jnp.concatenate([nodes_w, commit_nodes])
-
             # --- cache maintenance --------------------------------------------------
             fitc_clean, fitc_lvl, score_c = c.fitc_clean, c.fitc_lvl, c.score_c
             bmc_clean, bmc_lvl = c.bmc_clean, c.bmc_lvl
@@ -1112,11 +806,10 @@ def _make_place_iteration(
                 cslot_key = cslot_key.at[wslot].set(key, mode="drop")
                 cslot_lvl = cslot_lvl.at[wslot].set(level, mode="drop")
                 cslot_req = cslot_req.at[wslot].set(req_node, mode="drop")
-                # 2. exact re-derivation at every node this iteration's commits
-                # touched -- the head's <=slot_width lanes plus the multi-commit
-                # extension's certified lanes (unplaced iterations recompute
-                # unchanged values: no-op).
-                tn = touched_nodes  # [W(+E)], N = unused sentinel (dropped below)
+                # 2. exact re-derivation at every node this iteration's commit
+                # touched -- the head's <=slot_width lanes (unplaced iterations
+                # recompute unchanged values: no-op).
+                tn = nodes_w  # [W], N = unused sentinel (dropped below)
                 tn_safe = jnp.clip(tn, 0, N - 1)
                 a_rows = alloc[:, tn_safe, :]  # [P1, W, R]
                 sc_rows = jnp.sum(a_rows * p.inv_scale[None, None, :], axis=-1)  # [P1, W]
@@ -1156,428 +849,23 @@ def _make_place_iteration(
                 bmc_clean = bmc_clean.at[bpidx].set(bm0_t, mode="drop")
                 bmc_lvl = bmc_lvl.at[bpidx].set(bml_t, mode="drop")
 
-            if batch_k > 1:
-                # --- certified pick-chain extension (see docstring) --------------
-                # After the head commit, SIMULATE the sequential loop's next
-                # picks with tiny [Q] state (per-queue keys + window cursors)
-                # and commit up to batch_k-1 of them in this iteration.  The
-                # simulation replays the exact argmin pick order -- including
-                # same-queue monopolies, the dominant pattern under DRF (the
-                # cheapest queue places many consecutive jobs) -- and every
-                # f32 expression matches the sequential path's association, so
-                # decisions are bit-identical.  Anything unprovable (gangs,
-                # window exhaustion, cap trips, float shortfalls, no-fit
-                # failures) cuts the chain and defers to the next iteration.
-                E = batch_k - 1
-                max_slots_cap = slot_gang.shape[0]
-                iota_q = jnp.arange(Q, dtype=jnp.int32)
-
-                # Window candidate tables ([Q, W] gathers; the window is the
-                # simulation horizon)
-                wcard = p.g_card[wg]
-                wrun = p.g_run[wg]
-                wev = wrun >= 0
-                wlevel = p.g_level[wg]
-                wpc = p.g_pc[wg]
-                wban = p.g_ban_row[wg]
-                wreq = p.g_req[wg]  # [Q, W, R] per-member
-                wreq_tot = wreq * wcard[..., None].astype(jnp.float32)
-                wreq_node = g_req_node[wg]
-                wfloat = g_float_tot[wg]
-                wprice = p.g_price[wg]
-                wspot = p.g_spot_price[wg]
-                wpin = jnp.where(wev, p.run_node[jnp.maximum(wrun, 0)], 0)
-                # Cursor semantics EXACTLY mirror the sequential loop: the
-                # cursor parks on any undecided entry (in_r & ~skippable),
-                # whether or not the candidate gate would allow picking it.
-                # The gate (new_blocked / q_killed / zero weight -- `has`)
-                # applies to the KEY instead: a parked-blocked queue reads +INF
-                # -- never picked, never constraining, exactly like sequential.
-                wallowed = (
-                    ~((~wev) & (c.new_blocked | c.q_killed[:, None]))
-                    & (p.q_weight > 0)[:, None]
-                )
-                # parked/nn/tail_known come from the shared tables above the
-                # commit_k block (one definition for both batching shapes)
-
-                # simulation state
-                sim_row = q_alloc  # post-head [Q, R]; value-identical to what
-                # the sequential loop reads next iteration
-                pos_clip = jnp.minimum(pos + 1, W)
-                simpos = jnp.where(
-                    iota_q == qstar, nn[iota_q, pos_clip], nn[iota_q, pos]
-                )
-                sp_safe = jnp.minimum(simpos, W - 1)
-                head_tot = jnp.take_along_axis(
-                    wreq_tot, sp_safe[:, None, None], axis=1
-                )[:, 0]
-                sim_keys = weighted_drf_cost(
-                    (sim_row + p.q_penalty) + head_tot,
-                    p.total_pool, p.drf_mult, p.q_weight,
-                )
-                head_price = jnp.take_along_axis(
-                    wprice, sp_safe[:, None], axis=1
-                )[:, 0]
-                sim_keys = jnp.where(p.market, -head_price, sim_keys)
-                head_allowed = jnp.take_along_axis(
-                    wallowed, sp_safe[:, None], axis=1
-                )[:, 0]
-                sim_keys = jnp.where(head_allowed, sim_keys, _INF)
-                # beyond-window queues: certifiable only when truly exhausted
-                sim_keys = jnp.where(
-                    simpos < W, sim_keys, jnp.where(tail_known, _INF, -_INF)
-                )
-
-                # chain accumulators
-                t_nodes = jnp.full((E,), N, jnp.int32)
-                t_lo = jnp.zeros((E,), jnp.int32)
-                t_level = jnp.zeros((E,), jnp.int32)
-                t_req = jnp.zeros((E, R), jnp.float32)
-                ex_placed = jnp.zeros((E,), bool)
-                ex_gang = jnp.zeros((E,), jnp.int32)
-                ex_queue = jnp.zeros((E,), jnp.int32)
-                ex_pcv = jnp.zeros((E,), jnp.int32)
-                ex_reqs = jnp.zeros((E, R), jnp.float32)
-                ex_floats = jnp.zeros((E, R), jnp.float32)
-                ex_evs = jnp.zeros((E,), bool)
-                ex_runs = jnp.full((E,), RJ, jnp.int32)
-                r_count, r_res, r_float = sched_count, sched_res, float_used
-                r_spot_res, r_spot = spot_res, spot_price
-                r_iter = c.iterations + active.astype(jnp.int32)
-                alive = placed
-                iota_e = jnp.arange(E, dtype=jnp.int32)
-                # one-entry within-step fit-row cache: same-key chains reuse it
-                cache_key = jnp.int32(-2)
-                cache_lvl = jnp.int32(-1)
-                cache_ban = jnp.int32(-1)
-                cache_req = jnp.full((R,), -1.0, jnp.float32)
-                zrow = jnp.zeros((N,), bool)
-                cache_fit0, cache_fitl = zrow, zrow
-                cache_m0 = jnp.full((N,), _INF, jnp.float32)
-                cache_ml = jnp.full((N,), _INF, jnp.float32)
-                cache_n0 = jnp.int32(0)
-                score_all = jnp.sum(alloc * p.inv_scale[None, None, :], axis=-1)
-
-                def deltas_at(nodes, lvl):
-                    vis = ex_placed_l & (t_lo_l <= lvl) & (lvl <= t_level_l)
-                    aff = (
-                        (nodes[:, None] == t_nodes_l[None, :]) & vis[None, :]
-                    ).astype(jnp.float32)
-                    return aff @ t_req_l
-
-                for k in range(E):
-                    # lint: allow(full-argmin) -- [Q]-axis simulated queue pick
-                    qj = jnp.argmin(sim_keys).astype(jnp.int32)
-                    kj = sim_keys[qj]
-                    i_j = simpos[qj]
-                    ok = alive & (kj < _INF) & (i_j < W) & (
-                        r_iter < max_iterations
-                    )
-                    i_safe = jnp.minimum(i_j, W - 1)
-                    g_j = wg[qj, i_safe]
-                    card_j = wcard[qj, i_safe]
-                    ev_j = wev[qj, i_safe]
-                    run_j = jnp.where(ev_j, wrun[qj, i_safe], RJ)
-                    lvl_j = wlevel[qj, i_safe]
-                    pc_j = wpc[qj, i_safe]
-                    key_j = wkey[qj, i_safe]
-                    ban_j = wban[qj, i_safe]
-                    req_j = wreq[qj, i_safe]
-                    reqn_j = wreq_node[qj, i_safe]
-                    flt_j = wfloat[qj, i_safe]
-                    pin_j = wpin[qj, i_safe]
-                    if hetero:
-                        # this pick's bias row ([N], row 0 for keyless) -- the
-                        # replay mirrors the head path's (score) + bias add
-                        tb_j = type_bias_nodes[
-                            jnp.where(
-                                key_j >= 0,
-                                p.key_type_row[jnp.maximum(key_j, 0)],
-                                0,
-                            )
-                        ]
-                    ok &= card_j == 1  # gang heads defer to the full path
-                    # running caps/bursts incl. same-queue repeats in this chain
-                    prevq = ex_placed & (ex_queue == qj) & ~ex_evs
-                    prev_cnt = jnp.sum(prevq.astype(jnp.int32))
-                    prev_pc = prevq & (ex_pcv == pc_j)
-                    prev_pc_res = jnp.sum(
-                        jnp.where(prev_pc[:, None], ex_reqs, 0.0), axis=0
-                    )
-                    # Replay gate checks over the already-committed prefix: a
-                    # mis-associated near-tie can only FAIL a gate, and a gate
-                    # trip truncates to the exact sequential head path (r15),
-                    # so decisions stay bit-equal (parity-pinned at K in {1,8}).
-                    ok &= ev_j | (
-                        (r_count + 1 <= p.global_burst)
-                        & jnp.all(r_res + req_j <= p.round_cap)
-                        # lint: allow(vectorized-accumulator-ordering) -- integer count sum (exact); gate-trip truncates to the head path
-                        & (q_sched[qj] + prev_cnt + 1 <= p.perq_burst[qj])
-                        & jnp.all(
-                            # lint: allow(vectorized-accumulator-ordering) -- gate-trip truncates to the exact head path
-                            (q_alloc_pc[qj, pc_j] + prev_pc_res) + req_j
-                            <= p.pc_queue_cap[pc_j]
-                        )
-                    )
-                    ok &= ev_j | jnp.all(
-                        r_float + flt_j <= p.float_total + 1e-3
-                    )
-
-                    # fit rows: reuse the cached (key, level, ban) rows or
-                    # recompute; either way identical to the sequential formulas
-                    ex_placed_l, t_lo_l, t_level_l = ex_placed, t_lo, t_level
-                    t_nodes_l, t_req_l = t_nodes, t_req
-                    # key AND request must match: builder problems intern the
-                    # request into the key (core/keys.py), but the kernel must
-                    # stay correct for any input (synthetic keys are labels)
-                    match = (
-                        (key_j == cache_key)
-                        & (key_j >= 0)
-                        & (lvl_j == cache_lvl)
-                        & (ban_j == cache_ban)
-                        & jnp.all(reqn_j == cache_req)
-                    )
-
-                    def fresh(_):
-                        static_j = jnp.where(
-                            key_j >= 0,
-                            p.compat[jnp.maximum(key_j, 0)][p.node_type],
-                            True,
-                        )
-                        okn = static_j & p.node_ok & ~p.ban_mask[ban_j]
-                        f0 = okn & _fit_row(alloc[0], reqn_j[None, :])
-                        fl = okn & _fit_row(alloc[lvl_j], reqn_j[None, :])
-                        s0, sl_ = score_all[0], score_all[lvl_j]
-                        if hetero:
-                            s0 = s0 + tb_j
-                            sl_ = sl_ + tb_j
-                        m0 = jnp.where(f0, s0, _INF)
-                        ml = jnp.where(fl, sl_, _INF)
-                        return f0, fl, m0, ml, jnp.sum(f0).astype(jnp.int32)
-
-                    def cached(_):
-                        return (
-                            cache_fit0, cache_fitl, cache_m0, cache_ml, cache_n0
-                        )
-
-                    fit0_j, fitl_j, m0_j, ml_j, n0_j = jax.lax.cond(
-                        match, cached, fresh, None
-                    )
-                    cache_key = jnp.where(ev_j, cache_key, key_j)
-                    cache_req = jnp.where(ev_j, cache_req, reqn_j)
-                    cache_lvl = jnp.where(ev_j, cache_lvl, lvl_j)
-                    cache_ban = jnp.where(ev_j, cache_ban, ban_j)
-                    cache_fit0 = jnp.where(ev_j, cache_fit0, fit0_j)
-                    cache_fitl = jnp.where(ev_j, cache_fitl, fitl_j)
-                    cache_m0 = jnp.where(ev_j, cache_m0, m0_j)
-                    cache_ml = jnp.where(ev_j, cache_ml, ml_j)
-                    cache_n0 = jnp.where(ev_j, cache_n0, n0_j)
-
-                    # clean-count corrections at touched nodes (fits only flip
-                    # True -> False; count distinct nodes once)
-                    tn_safe = jnp.clip(t_nodes, 0, N - 1)
-                    first_occ = ex_placed & (
-                        jnp.sum(
-                            (
-                                (t_nodes[None, :] == t_nodes[:, None])
-                                & ex_placed[None, :]
-                                & (iota_e[None, :] < iota_e[:, None])
-                            ),
-                            axis=1,
-                        )
-                        == 0
-                    )
-                    adj0 = alloc[0][tn_safe] - deltas_at(tn_safe, jnp.int32(0))
-                    fit0_adj = (
-                        _fit_row(adj0, reqn_j[None, :]) & fit0_j[tn_safe]
-                    )
-                    flips = first_occ & fit0_j[tn_safe] & ~fit0_adj
-                    n0_adj = n0_j - jnp.sum(flips.astype(jnp.int32))
-                    use_clean = (~ev_j) & (n0_adj >= 1)
-                    lvl_sel = jnp.where(use_clean, 0, lvl_j)
-
-                    msel = jnp.where(use_clean, m0_j, ml_j)
-                    msel = msel.at[t_nodes].set(_INF, mode="drop")
-                    # lint: allow(full-argmin) -- gang-unit member pick: units
-                    # bypass the per-key fit cache (CLAUDE.md), O(members) rare
-                    u_node = jnp.argmin(msel).astype(jnp.int32)
-                    u_score = msel[u_node]
-                    adjs = alloc[lvl_sel][tn_safe] - deltas_at(tn_safe, lvl_sel)
-                    fsel = jnp.where(use_clean, fit0_j, fitl_j)
-                    fit_t = (
-                        _fit_row(adjs, reqn_j[None, :])
-                        & fsel[tn_safe]  # static/ok/ban masks are node-stable
-                        & ex_placed
-                    )
-                    base_t = jnp.sum(adjs * p.inv_scale[None, :], axis=-1)
-                    if hetero:
-                        base_t = base_t + tb_j[tn_safe]
-                    sc_t = jnp.where(fit_t, base_t, _INF)
-                    t_best_score = jnp.min(sc_t)
-                    t_best_node = jnp.min(
-                        jnp.where(sc_t == t_best_score, t_nodes, N)
-                    ).astype(jnp.int32)
-                    t_wins = (t_best_score < u_score) | (
-                        (t_best_score == u_score) & (t_best_node < u_node)
-                    )
-                    node_j = jnp.where(t_wins, t_best_node, u_node)
-                    found = jnp.minimum(t_best_score, u_score) < _INF
-
-                    # evictee: pinned-node fit at its level, exactly
-                    pin_adj = alloc[lvl_j, pin_j] - deltas_at(
-                        pin_j[None], lvl_j
-                    )[0]
-                    ev_fit = (
-                        _fit_row(pin_adj[None, :], reqn_j[None, :])[0]
-                        & p.node_ok[pin_j]
-                    )
-                    node_j = jnp.where(ev_j, pin_j, node_j)
-                    found = jnp.where(ev_j, ev_fit, found)
-                    # a no-fit FAILS sequentially (state 2 + key retirement):
-                    # defer; an unplaced pick always ends the chain
-                    ok &= found
-
-                    t_nodes = t_nodes.at[k].set(jnp.where(ok, node_j, N))
-                    t_lo = t_lo.at[k].set(jnp.where(ev_j, 1, 0))
-                    t_level = t_level.at[k].set(lvl_j)
-                    t_req = t_req.at[k].set(reqn_j * ok.astype(jnp.float32))
-                    ex_placed = ex_placed.at[k].set(ok)
-                    ex_gang = ex_gang.at[k].set(g_j)
-                    ex_queue = ex_queue.at[k].set(qj)
-                    ex_pcv = ex_pcv.at[k].set(pc_j)
-                    ex_reqs = ex_reqs.at[k].set(
-                        req_j * ok.astype(jnp.float32)
-                    )
-                    ex_floats = ex_floats.at[k].set(
-                        flt_j * ok.astype(jnp.float32)
-                    )
-                    ex_evs = ex_evs.at[k].set(ev_j & ok)
-                    ex_runs = ex_runs.at[k].set(jnp.where(ev_j & ok, run_j, RJ))
-                    new_k = ok & ~ev_j
-                    r_count = r_count + new_k.astype(jnp.int32)
-                    r_res = r_res + jnp.where(new_k, req_j, 0.0)
-                    r_float = r_float + jnp.where(new_k, flt_j, 0.0)
-                    r_spot_res = r_spot_res + jnp.where(ok, req_j, 0.0)
-                    share_k = jnp.max(
-                        jnp.where(
-                            p.total_pool > 0,
-                            r_spot_res / jnp.maximum(p.total_pool, 1e-9),
-                            0.0,
-                        )
-                        * p.drf_mult
-                    )
-                    crossed_k = (
-                        p.market & ok & (r_spot < 0) & (share_k > p.spot_cutoff)
-                    )
-                    r_spot = jnp.where(
-                        crossed_k, wspot[qj, i_safe], r_spot
-                    )
-                    r_iter = r_iter + ok.astype(jnp.int32)
-
-                    # advance the picked queue's simulation state
-                    npos = nn[qj, jnp.minimum(i_j + 1, W)]
-                    np_safe = jnp.minimum(npos, W - 1)
-                    sim_row = sim_row.at[qj].add(
-                        jnp.where(ok, req_j, 0.0)
-                    )
-                    next_tot = wreq_tot[qj, np_safe]
-                    keyn = weighted_drf_cost(
-                        ((sim_row[qj] + p.q_penalty[qj]) + next_tot)[None, :],
-                        p.total_pool, p.drf_mult, p.q_weight[qj][None],
-                    )[0]
-                    keyn = jnp.where(p.market, -wprice[qj, np_safe], keyn)
-                    keyn = jnp.where(wallowed[qj, np_safe], keyn, _INF)
-                    keyn = jnp.where(
-                        npos < W,
-                        keyn,
-                        jnp.where(tail_known[qj], _INF, -_INF),
-                    )
-                    sim_keys = sim_keys.at[qj].set(
-                        jnp.where(ok, keyn, sim_keys[qj])
-                    )
-                    simpos = simpos.at[qj].set(jnp.where(ok, npos, simpos[qj]))
-                    alive = ok
-
-                # --- vectorized commit of the placed picks -----------------------
-                pf = ex_placed.astype(jnp.float32)
-                lv_e = jnp.arange(num_levels, dtype=jnp.int32)
-                lm_e = (
-                    (lv_e[:, None] >= t_lo[None, :])
-                    & (lv_e[:, None] <= t_level[None, :])
-                ).astype(jnp.float32)
-                # lint: allow(axis1-scatter) -- batched window-commit of placed
-                # picks into [P1,N,R] alloc, once per window refill
-                alloc = alloc.at[:, t_nodes, :].add(
-                    -lm_e[:, :, None] * t_req[None, :, :], mode="drop"
-                )
-                # duplicate queue indices accumulate; integral units stay exact
-                q_alloc = q_alloc.at[ex_queue].add(ex_reqs)
-                q_alloc_pc = q_alloc_pc.at[ex_queue, ex_pcv].add(ex_reqs)
-                new_e = ex_placed & ~ex_evs
-                sched_count = sched_count + jnp.sum(new_e.astype(jnp.int32))
-                sched_res = sched_res + jnp.sum(
-                    ex_reqs * new_e[:, None].astype(jnp.float32), axis=0
-                )
-                float_used = float_used + jnp.sum(
-                    ex_floats * new_e[:, None].astype(jnp.float32), axis=0
-                )
-                q_sched = q_sched.at[ex_queue].add(new_e.astype(jnp.int32))
-                spot_res = r_spot_res
-                spot_price = r_spot
-                # scatter ONLY placed picks: unplaced rows default to gang 0 /
-                # run RJ, and a gather-set there races the real writes
-                g_state = g_state.at[jnp.where(ex_placed, ex_gang, G)].set(
-                    1, mode="drop"
-                )
-                run_rescheduled = run_rescheduled.at[ex_runs].set(
-                    True, mode="drop"
-                )
-                ranks = jnp.cumsum(new_e.astype(jnp.int32)) - new_e.astype(
-                    jnp.int32
-                )
-                sidx = jnp.where(new_e, cursor + ranks, max_slots_cap)
-                ex_nodes_w = (
-                    jnp.full((E, slot_width), N, jnp.int32)
-                    .at[:, 0]
-                    .set(jnp.where(new_e, t_nodes, N))
-                )
-                ex_counts_w = (
-                    jnp.zeros((E, slot_width), jnp.int32)
-                    .at[:, 0]
-                    .set(new_e.astype(jnp.int32))
-                )
-                slot_gang = slot_gang.at[sidx].set(ex_gang, mode="drop")
-                slot_nodes = slot_nodes.at[sidx].set(ex_nodes_w, mode="drop")
-                slot_counts = slot_counts.at[sidx].set(ex_counts_w, mode="drop")
-                cursor = cursor + jnp.sum(new_e.astype(jnp.int32))
-                extra_iters = jnp.sum(ex_placed.astype(jnp.int32))
-
         # --- the carried skip window: patch what this trip changed ----------------
         with jax.named_scope("window"):
-            # State: g_state moved at the head pick (and at the extension's
-            # committed lanes), key_bad at the registered key (`register`
-            # holds check_keys) -- elementwise on [Q, W], no gather.
+            # State: g_state moved at the head pick, key_bad at the registered
+            # key (`register` holds check_keys) -- elementwise on [Q, W], no
+            # gather.
             hit = (wg == g) & attempt
-            if commit_k > 1:
-                hit |= jnp.any((wg[:, :, None] == ge) & ok_e, axis=-1)
-            if batch_k > 1:
-                hit |= jnp.any((wg[:, :, None] == ex_gang) & ex_placed, axis=-1)
             w_skip = c.w_skip | hit | (register & (wkey == key))
             # Cursor: a queue that moved gets its row gathered at the new head
             # (from the post-commit g_state / key_bad, so the patch above is
-            # moot for it).  A trip moves the queues whose heads the previous
-            # trip decided, at most `rows` of them; more than that (a key
-            # registration that retires several queues' heads at once) takes
-            # the branch that gathers every row, which returns the [Q, W]
-            # tables only -- a [G] array through a branch is copied per trip.
+            # moot for it).  A trip moves the queue whose head the previous
+            # trip decided; more than one (a key registration that retires
+            # several queues' heads at once) takes the branch that gathers
+            # every row, which returns the [Q, W] tables only -- a [G] array
+            # through a branch is copied per trip.
             moved = nskip > 0
-            if rows == 1:
-                # one reduce, where top_k sorts
-                # lint: allow(full-argmin) -- [Q]-axis first moved queue, not [N]
-                qm = jnp.argmax(moved).astype(jnp.int32)[None]
-            else:
-                _, qm = jax.lax.top_k(moved.astype(jnp.int32), rows)  # lowest index first
-                qm = qm.astype(jnp.int32)
+            # lint: allow(full-argmin) -- [Q]-axis first moved queue, not [N]
+            qm = jnp.argmax(moved).astype(jnp.int32)[None]
             moved_rows = _skip_window(
                 p, p.q_start[qm], q_head[qm], g_state, key_bad, check_keys
             )
@@ -1586,7 +874,7 @@ def _make_place_iteration(
                 t.at[qm].set(r, mode="drop")
                 for t, r in zip((wg, wkey, w_skip), moved_rows)
             )
-            refill = jnp.sum(moved.astype(jnp.int32)) > rows
+            refill = jnp.sum(moved.astype(jnp.int32)) > 1
             w_gang, w_key, w_skip = jax.lax.cond(
                 refill,
                 lambda: _skip_window(p, p.q_start, q_head, g_state, key_bad, check_keys),
@@ -1614,7 +902,7 @@ def _make_place_iteration(
             sched_res=sched_res,
             float_used=float_used,
             new_blocked=new_blocked,
-            iterations=c.iterations + active.astype(jnp.int32) + extra_iters,
+            iterations=c.iterations + active.astype(jnp.int32),
             kernel_iters=c.kernel_iters + active.astype(jnp.int32),
             window_refills=c.window_refills + refill.astype(jnp.int32),
             done=done,
@@ -1712,9 +1000,6 @@ def schedule_round(
     max_iterations: int = 0,
     prefer_large: bool = False,
     cache_slots: int = -1,
-    unroll: int = -1,
-    batch_k: int = -1,
-    commit_k: int = -1,
 ) -> RoundResult:
     """Run one full scheduling round on device.
 
@@ -1723,17 +1008,6 @@ def schedule_round(
     .slot_width).  max_iterations=0 derives the safe bound #gangs + #queues + 8.
     cache_slots sizes the per-scheduling-key fit cache (-1 = derive from the
     compat table; 0 = disable, compiling the original uncached body).
-    unroll applies the placement body this many times per while_loop
-    iteration (-1 = derive: several on accelerators, 1 on CPU) -- each inner
-    step IS one full sequential iteration (decisions bit-identical at any
-    unroll; tail steps past done self-disable via the body's active gate),
-    but grouping them lets XLA fuse/overlap the many small per-iteration ops
-    whose fixed latencies dominate the accelerator round.
-    commit_k (-1 = env ARMADA_COMMIT_K, default 1) arms the conflict-free
-    multi-commit extension: up to commit_k certified-independent placements
-    commit per while-loop iteration, shrinking the trip count itself (see
-    _make_place_iteration).  Decisions are bit-identical at any K; commit_k=1
-    compiles the single-commit body -- the A/B and escape hatch.
     """
     G = p.g_req.shape[0]
     Q = p.q_weight.shape[0]
@@ -1744,9 +1018,6 @@ def schedule_round(
         max_iterations=max_iterations,
         prefer_large=prefer_large,
         cache_slots=cache_slots,
-        unroll=unroll,
-        batch_k=batch_k,
-        commit_k=commit_k,
     )
     return _schedule_round_jit(
         p,
@@ -1766,9 +1037,6 @@ def schedule_round_stacked(
     max_iterations: int = 0,
     prefer_large: bool = False,
     cache_slots: int = -1,
-    unroll: int = -1,
-    batch_k: int = -1,
-    commit_k: int = -1,
 ) -> RoundResult:
     """Run P independent pools' rounds as ONE kernel launch (round 17).
 
@@ -1782,9 +1050,8 @@ def schedule_round_stacked(
     solo ``schedule_round`` on its slice -- pinned by
     tests/test_pool_parallel.py against the serial loop.  The win is
     dispatch-count economics: P small pools cost ONE launch whose trip
-    count is max(lane trips), not sum -- the multi-tenant analog of the
-    commit_k trip-count work (and one upload + one compact fetch serve
-    the whole stack).
+    count is max(lane trips), not sum (and one upload + one compact fetch
+    serve the whole stack).
 
     Statics resolve exactly like schedule_round (shared helper), from the
     per-lane shapes -- a stacked compile keys on the same resolved values
@@ -1801,9 +1068,6 @@ def schedule_round_stacked(
         max_iterations=max_iterations,
         prefer_large=prefer_large,
         cache_slots=cache_slots,
-        unroll=unroll,
-        batch_k=batch_k,
-        commit_k=commit_k,
     )
     return _schedule_round_stacked_jit(
         p,
@@ -1822,9 +1086,6 @@ def _resolve_round_statics(
     max_iterations: int,
     prefer_large: bool,
     cache_slots: int,
-    unroll: int,
-    batch_k: int,
-    commit_k: int,
 ) -> dict:
     """Resolve the platform/env-derived compile statics OUTSIDE the jit
     boundary -- shared by schedule_round and schedule_round_stacked so a
@@ -1837,9 +1098,12 @@ def _resolve_round_statics(
         # cache's flat-scatter bookkeeping instead.  Decisions are
         # bit-identical either way (the cache is exact memoization).
         # Polarity: cache only on XLA:CPU -- any accelerator platform string
-        # gets the vectorized body.  ARMADA_CACHE_SLOTS / ARMADA_BATCH_K override the
-        # platform defaults (how the CPU parity suites pin the TPU-shaped
-        # compile: cache 0 + batch 8).
+        # gets the vectorized body.  So the CPU suites run the cached body
+        # (the one the watchdog's CPU failover serves); ARMADA_CACHE_SLOTS
+        # overrides the platform default, and is how the cases that pin the
+        # chip's body reach it through the served path (the tests named
+        # "chip body": golden traces, full parity, verify, pipeline, pool
+        # parallel, mesh).
         env = _os.environ.get("ARMADA_CACHE_SLOTS")
         if env is not None:
             cache_slots = min(int(env), compat_rows)
@@ -1849,42 +1113,6 @@ def _resolve_round_statics(
                 if jax.default_backend() == "cpu"
                 else 0
             )
-    if unroll < 0:
-        # Measured (TPU v5e, 1M x 50k): unroll 8/16 changes NOTHING
-        # (~0.19s either way) -- the per-iteration cost is the sequential
-        # dependence chain of the body's ops, not while_loop overhead, so
-        # grouping steps cannot overlap them.  The knob stays for
-        # experiments; batching that actually shortens the chain is
-        # batch_k (certified multi-placement per iteration).
-        unroll = 1
-    if batch_k < 0:
-        # Default 1 EVERYWHERE -- measured on the real chip (v5e-lite,
-        # 1M x 50k): the certified pick chain is bit-exact (full parity
-        # gauntlet green at batch_k=8) but SLOWER (0.46s vs 0.19s at k=8,
-        # 0.36s at k=16): per-op dispatch latency ~1-2us dominates this
-        # chip, so replaying K sequential decisions inside one iteration
-        # costs what K iterations cost.  The machinery stays behind the
-        # knob (ARMADA_BATCH_K) for chips where [N]-vector work, not op
-        # count, is the per-iteration floor.  prefer_large's within-budget
-        # ordering re-ranks per placement, which the certification does
-        # not model; the cached CPU body would recompute what its cache
-        # exists to avoid -- both force 1.
-        env = _os.environ.get("ARMADA_BATCH_K")
-        batch_k = int(env) if env is not None else 1
-    if cache_slots > 0 or prefer_large:
-        batch_k = 1
-    if commit_k < 0:
-        commit_k = resolve_commit_k()
-    # prefer_large re-ranks every queue per placement (within-budget uses
-    # CURRENT cost), which the distinct-queue certification does not model;
-    # a single queue cannot batch.  The multi-commit extension and the
-    # batch_k replay are mutually exclusive shapes of the same iteration --
-    # commit_k (the supported one) wins.
-    commit_k = max(1, min(commit_k, Q))
-    if prefer_large:
-        commit_k = 1
-    if commit_k > 1:
-        batch_k = 1
     if max_iterations <= 0:
         # every iteration either decides a gang (<= G), advances a cursor
         # (<= G total across the round), or is the final no-op
@@ -1893,9 +1121,6 @@ def _resolve_round_statics(
         max_iterations=max_iterations,
         prefer_large=prefer_large,
         cache_slots=cache_slots,
-        unroll=unroll,
-        batch_k=batch_k,
-        commit_k=commit_k,
     )
 
 
@@ -1903,7 +1128,7 @@ def _resolve_round_statics(
     jax.jit,
     static_argnames=(
         "num_levels", "max_slots", "slot_width", "max_iterations", "prefer_large",
-        "cache_slots", "unroll", "batch_k", "commit_k",
+        "cache_slots",
     ),
 )
 def _schedule_round_stacked_jit(
@@ -1915,9 +1140,6 @@ def _schedule_round_stacked_jit(
     max_iterations: int,
     prefer_large: bool,
     cache_slots: int,
-    unroll: int,
-    batch_k: int,
-    commit_k: int,
 ) -> RoundResult:
     """vmap of the solo round over the leading pool axis: one XLA program,
     P lockstep lanes.  The inner call is the already-jitted solo entry --
@@ -1931,33 +1153,15 @@ def _schedule_round_stacked_jit(
             max_iterations=max_iterations,
             prefer_large=prefer_large,
             cache_slots=cache_slots,
-            unroll=unroll,
-            batch_k=batch_k,
-            commit_k=commit_k,
         )
     )(p)
-
-
-def resolve_commit_k() -> int:
-    """The env-resolved multi-commit width (ARMADA_COMMIT_K, default 1 --
-    the single-commit body), floored at 1 so reporters never echo a
-    nonsensical 0/negative arm.  Resolved OUTSIDE every jit boundary (the
-    schedule_round discipline: compiles key on the resolved value), and
-    exported so mesh/serve/bench report the ARMED K without re-parsing.
-    schedule_round additionally clamps the effective K to the problem's
-    queue-axis width (and market/prefer-large rounds force 1)."""
-    env = _os.environ.get("ARMADA_COMMIT_K")
-    try:
-        return max(1, int(env)) if env else 1
-    except ValueError:
-        return 1
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "num_levels", "max_slots", "slot_width", "max_iterations", "prefer_large",
-        "cache_slots", "unroll", "batch_k", "commit_k",
+        "cache_slots",
     ),
 )
 @jax.named_scope("armada.round")
@@ -1970,9 +1174,6 @@ def _schedule_round_jit(
     max_iterations: int,
     prefer_large: bool,
     cache_slots: int,
-    unroll: int,
-    batch_k: int,
-    commit_k: int,
 ) -> RoundResult:
     """The fully-resolved compile: schedule_round (the public wrapper)
     resolves platform/env-derived statics OUTSIDE the jit boundary, so the
@@ -2094,15 +1295,8 @@ def _schedule_round_jit(
         body = _make_place_iteration(
             p, num_levels, slot_width, check_keys=True,
             prefer_large=prefer_large, q_budget=q_budget, cache_slots=cache_slots,
-            max_iterations=max_iterations, batch_k=batch_k, commit_k=commit_k,
+            max_iterations=max_iterations,
         )
-        if unroll > 1:
-            inner = body
-
-            def body(c):  # noqa: F811 - the grouped body replaces the single step
-                for _ in range(unroll):
-                    c = inner(c)
-                return c
 
     with jax.named_scope("armada.round.loop"):
         carry = jax.lax.while_loop(

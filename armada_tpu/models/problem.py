@@ -238,10 +238,8 @@ class RoundOutcome:
     failed: list  # job ids attempted and unschedulable this round
     num_iterations: int
     termination: str
-    # Physical while-loop trips (RoundResult.kernel_iters): num_iterations /
-    # kernel_iters = average certified commits per iteration under the
-    # multi-commit kernel (ARMADA_COMMIT_K); equal when K=1.  0 = unknown
-    # (synthetic outcomes).
+    # Trips of the placement loop (RoundResult.kernel_iters); equal to
+    # num_iterations.  0 = unknown (synthetic outcomes).
     kernel_iters: int = 0
     # Trips on which the kernel gathered its whole skip window again
     # (RoundResult.window_refills); a small share of kernel_iters unless key

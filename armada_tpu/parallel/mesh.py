@@ -247,12 +247,10 @@ def shard_problem(
     jax.jit,
     static_argnames=(
         "mesh", "num_levels", "max_slots", "slot_width", "max_iterations",
-        "commit_k",
     ),
 )
 def _sharded_round(
     problem, *, mesh, num_levels, max_slots, slot_width, max_iterations,
-    commit_k,
 ):
     # Inputs arrive pre-sharded (shard_problem); jit propagates their shardings
     # through the while-loop and GSPMD inserts the collectives.  Outputs are
@@ -265,7 +263,6 @@ def _sharded_round(
         max_slots=max_slots,
         slot_width=slot_width,
         max_iterations=max_iterations,
-        commit_k=commit_k,
     )
 
 
@@ -277,7 +274,6 @@ def sharded_schedule_round(
     max_slots: int,
     slot_width: int,
     max_iterations: int = 0,
-    commit_k: int = -1,
 ):
     """Run one scheduling round SPMD over the mesh.
 
@@ -285,13 +281,6 @@ def sharded_schedule_round(
     numerically identical (the kernel is deterministic and sharding only
     distributes the reductions).
     """
-    from armada_tpu.models.fair_scheduler import resolve_commit_k
-
-    if commit_k < 0:
-        # Resolved OUTSIDE the jit boundary like every schedule_round
-        # static: _sharded_round's compile cache must key on the value an
-        # env override resolves TO, never silently reuse a stale trace.
-        commit_k = resolve_commit_k()
     problem = shard_problem(problem, mesh)
     with mesh:
         return _sharded_round(
@@ -301,5 +290,4 @@ def sharded_schedule_round(
             max_slots=max_slots,
             slot_width=slot_width,
             max_iterations=max_iterations,
-            commit_k=commit_k,
         )

@@ -138,8 +138,7 @@ class SchedulingReportsRepository:
                     "preempted": len(o.preempted),
                     "failed": len(o.failed),
                     "iterations": o.num_iterations,
-                    # physical while-loop trips under the multi-commit
-                    # kernel (ARMADA_COMMIT_K); == iterations at K=1,
+                    # trips of the placement loop; == iterations, and
                     # 0 on synthetic outcomes that never ran a kernel
                     "kernel_iters": getattr(o, "kernel_iters", 0),
                     "termination": o.termination,

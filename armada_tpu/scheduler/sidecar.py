@@ -413,8 +413,7 @@ def _stats_of(result: SchedulerResult, trace: Optional[dict] = None) -> str:
             "preempted": len(s.outcome.preempted),
             "termination": s.outcome.termination,
             "iterations": s.outcome.num_iterations,
-            # physical while-loop trips under the multi-commit kernel
-            # (ARMADA_COMMIT_K); == iterations at K=1
+            # trips of the placement loop; == iterations
             "kernel_iters": getattr(s.outcome, "kernel_iters", 0),
             # of those, the trips that gathered the whole skip window again
             "window_refills": getattr(s.outcome, "window_refills", 0),

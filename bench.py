@@ -32,9 +32,8 @@ ARMADA_BENCH_HETERO=0 skips the heterogeneous-fleet kernel A/B
 (hetero_* keys: 4 node types, ~30% type-sensitive keys, per-iteration
 cost vs the insensitive body -- the type-bias gather must stay off the
 sequential chain).
-ARMADA_COMMIT_K arms the multi-commit kernel for every arm; the JSON
-echoes it (commit_k) next to the trip counters (kernel_iters /
-round_iters / burst10k_iters -- docs/bench.md r15).
+The JSON carries the trip counters (kernel_iters / round_iters /
+burst10k_iters).
 
 The JSON carries host-load context (loadavg / cpu_count): the host-side
 slices (assemble, decode/apply) degrade roughly linearly with CPU
@@ -394,10 +393,7 @@ def _e2e_bench(
                 "assemble_s": round(t_asm - t_start, 4),
                 "upload_kernel_s": round(t_kernel - t_asm, 4),
                 "decode_apply_s": round(t_end - t_kernel, 4),
-                # Iteration-count legibility (ARMADA_COMMIT_K): physical
-                # while-loop trips vs logical sequential steps -- the
-                # multi-commit win (and its certification truncation rate,
-                # round_iters/kernel_iters) are exact counts on any
+                # Trips of the placement loop: an exact count on any
                 # backend.  Rides the compact decode buffer: free.
                 "kernel_iters": outcome.kernel_iters,
                 "round_iters": outcome.num_iterations,
@@ -1396,11 +1392,6 @@ def main():
         "pipeline": int(_pipeline_enabled()),
         **parts,
     }
-    # The armed multi-commit width (models/fair_scheduler.py): K=1 is the
-    # single-commit body; the iteration keys above only move when K > 1.
-    from armada_tpu.models.fair_scheduler import resolve_commit_k
-
-    line["commit_k"] = resolve_commit_k()
     if burst != 1_000:
         line["burst"] = burst
     if burst10k_s is not None:

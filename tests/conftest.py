@@ -37,6 +37,18 @@ def _tsan_violations_fail_tests():
     assert not found, "tsan violations:\n" + "\n".join(found)
 
 
+@pytest.fixture(params=["chip", "cpu"])
+def round_body(request, monkeypatch):
+    """Which body of the round the served path compiles: an accelerator's (no
+    fit cache, ARMADA_CACHE_SLOTS=0) or XLA:CPU's per-key fit cache, which
+    schedule_round derives from the platform and a CPU failover serves."""
+    if request.param == "chip":
+        monkeypatch.setenv("ARMADA_CACHE_SLOTS", "0")
+    else:
+        monkeypatch.delenv("ARMADA_CACHE_SLOTS", raising=False)
+    return request.param
+
+
 @pytest.fixture(autouse=True)
 def _bound_xla_mappings(request):
     """Drop compiled executables at each module boundary.

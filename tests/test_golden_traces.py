@@ -97,12 +97,11 @@ def _jobset_of(sim, jid):
     return tmpl.job_set
 
 
-def test_golden_traces_with_commit_k_armed(monkeypatch):
-    """One full golden pass with ARMADA_COMMIT_K=8 armed (round 15).  The
-    golden config runs prefer-large ordering, which schedule_round forces
-    back to the single-commit body -- so this pins two things: arming the
-    knob can never corrupt a prefer-large round (the force works), and the
-    reference's own published traces survive a plane-wide K=8 arm."""
-    monkeypatch.setenv("ARMADA_COMMIT_K", "8")
+def test_golden_traces_under_the_chip_body(monkeypatch):
+    """One full golden pass on the body an accelerator compiles (no fit
+    cache; ARMADA_CACHE_SLOTS=0 here): the reference's own published traces
+    hold for the program the chip runs, prefer-large ordering included, and
+    not only for XLA:CPU's cached body that the cases above run."""
+    monkeypatch.setenv("ARMADA_CACHE_SLOTS", "0")
     for path in GOLDEN:
         test_golden_trace(path)

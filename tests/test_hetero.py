@@ -4,7 +4,7 @@ The tentpole surfaces under one roof: the annotation parser and its
 validation gate, key identity absorbing the type axis, SubmitChecker's
 unknown-type rejection, the kernel's whitelist + throughput-bias placement
 on hand-built worlds, bit-identity of single-type fleets with pre-hetero
-decisions, cache/commit_k bit-equality on a type-sensitive synthetic
+decisions, fit-cache bit-equality on a type-sensitive synthetic
 problem (the docs/lint.md ledger row), the explain pass's type-mismatch
 attribution + per-type fragmentation, and a heterogeneous soak smoke.
 
@@ -285,11 +285,10 @@ def test_single_type_fleet_bit_identical_to_untyped():
     assert a.failed == b.failed
 
 
-def test_hetero_cache_and_commit_k_bit_equal():
+def test_hetero_cache_bit_equal():
     """The docs/lint.md ledger leg: on a type-sensitive synthetic problem
-    the per-key fit cache (which refuses trow != 0 candidates) and the
-    multi-commit kernel (whose extension lanes truncate sensitive picks)
-    must stay bit-identical to the single-commit uncached body."""
+    the per-key fit cache (which refuses trow != 0 candidates) must stay
+    bit-identical to the uncached body, the one the chip runs."""
     import jax.numpy as jnp
 
     from armada_tpu.models.fair_scheduler import schedule_round as sr
@@ -307,17 +306,14 @@ def test_hetero_cache_and_commit_k_bit_equal():
         num_levels=meta["num_levels"], max_slots=meta["max_slots"],
         slot_width=meta["slot_width"],
     )
-    base = sr(dev, **kw, cache_slots=0, commit_k=1)
-    for cs, ck in ((8, 1), (0, 4), (8, 8)):
-        got = sr(dev, **kw, cache_slots=cs, commit_k=ck)
-        for name in base._fields:
-            if name in ("kernel_iters", "window_refills"):
-                continue  # multi-commit legitimately shrinks trips
-            np.testing.assert_array_equal(
-                np.asarray(getattr(base, name)),
-                np.asarray(getattr(got, name)),
-                err_msg=f"cache_slots={cs} K={ck}: diverged on {name}",
-            )
+    base = sr(dev, **kw, cache_slots=0)
+    got = sr(dev, **kw, cache_slots=8)
+    for name in base._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(base, name)),
+            np.asarray(getattr(got, name)),
+            err_msg=f"cache_slots=8: diverged on {name}",
+        )
 
 
 # --- explain: type-mismatch + per-type fragmentation -------------------------

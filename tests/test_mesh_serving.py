@@ -272,13 +272,12 @@ def test_mesh_steady_cycle_bit_equal_over_churn(seed):
     assert total > 10  # the churn actually scheduled work
 
 
-def test_mesh_churn_bit_equal_with_commit_k_armed(monkeypatch):
-    """The mesh equality suite with the multi-commit kernel armed
-    (round 15, ARMADA_COMMIT_K=8): sharded vs single-device cycles stay
-    bit-equal cycle-by-cycle when both arms compile the K=8 body -- the
-    [E,N] certification tables ride the node-axis sharding like the fit
-    masks do."""
-    monkeypatch.setenv("ARMADA_COMMIT_K", "8")
+def test_mesh_churn_bit_equal_under_the_chip_body(monkeypatch):
+    """The mesh equality suite on the body an accelerator compiles (no fit
+    cache; ARMADA_CACHE_SLOTS=0 here): sharded vs single-device cycles stay
+    bit-equal cycle-by-cycle -- the full [N] fit masks and argmin of every
+    trip ride the node-axis sharding, where the cached body reads rows."""
+    monkeypatch.setenv("ARMADA_CACHE_SLOTS", "0")
     total = run_churn_ab(0)
     assert total > 10
 

@@ -216,13 +216,14 @@ def test_jobs_axis_sharded_round_at_scale_matches():
             )
 
 
-def test_sharded_multi_commit_matches(monkeypatch):
-    """The GSPMD path with the multi-commit kernel armed (round 15): the
-    sharded round at ARMADA_COMMIT_K=8 must equal both the unsharded K=8
-    round and the K=1 body -- the [E,N] certification tables ride the same
-    node-axis sharding as the fit masks, and sharded_schedule_round
-    resolves the env OUTSIDE its jit boundary so compiles key on K."""
-    monkeypatch.setenv("ARMADA_COMMIT_K", "8")
+def test_sharded_chip_body_matches(monkeypatch):
+    """The GSPMD path on the body an accelerator compiles (no fit cache;
+    ARMADA_CACHE_SLOTS=0 here): the sharded round must equal the unsharded
+    one, its [N] fit masks and argmin split over the node axis."""
+    monkeypatch.setenv("ARMADA_CACHE_SLOTS", "0")
+    # sharded_schedule_round resolves the body while it traces: drop any
+    # trace these shapes left under the cached body
+    jax.clear_caches()
     cfg = make_config()
     nodes = [node(cfg, f"n{i}", cpu="4", memory="8Gi") for i in range(16)]
     queues = [Queue(q, 1.0) for q in ("A", "B", "C", "D")]
@@ -234,5 +235,4 @@ def test_sharded_multi_commit_matches(monkeypatch):
     s, p = _both_rounds(cfg, nodes, queues, jobs)
     _assert_same(s, p)
     assert len(p.scheduled) > 0
-    # and the armed kernel really batched (the sharded path included)
-    assert s.kernel_iters and s.kernel_iters < s.num_iterations
+    assert s.kernel_iters == s.num_iterations == p.kernel_iters

@@ -931,35 +931,32 @@ def _mkjob(jid, q, cpu, sub):
     )
 
 
-# --- multi-commit kernel (ARMADA_COMMIT_K, round 15) -------------------------
+# --- the chip's body and XLA:CPU's, each against the oracle ------------------
+# (conftest's `round_body`: schedule_round compiles the uncached body on an
+# accelerator and the per-key fit cache on XLA:CPU)
 
 
-@pytest.mark.parametrize("commit_k", [1, 4, 8])
 @pytest.mark.parametrize("seed", [6, 14, 27])
-def test_multi_commit_conflict_heavy_parity(seed, commit_k, monkeypatch):
-    """The armed multi-commit kernel against the independent oracle on
-    conflict-heavy worlds: few nodes (every pick contends for the same
-    best-fit targets, exercising the same-node stacking certification),
-    gangs interleaved with singletons (gang heads truncate the batch),
-    at K in {1, 4, 8}.  _compare asserts scheduled-set, preempted-set and
-    per-queue-count equality; each K matching the oracle pins cross-K
-    equality transitively."""
-    monkeypatch.setenv("ARMADA_COMMIT_K", str(commit_k))
+def test_conflict_heavy_parity(seed, round_body):
+    """Both bodies against the independent oracle on conflict-heavy worlds:
+    few nodes (every pick contends for the same best-fit targets, so the
+    cached fit rows of a node are re-derived after almost every commit),
+    gangs interleaved with singletons.  _compare asserts scheduled-set,
+    preempted-set and per-queue-count equality; each body matching the
+    oracle pins their equality transitively."""
     nodes, queues, jobs, running = world(
         seed, num_nodes=30, num_jobs=250, num_running=0, gangs=4
     )
     _compare(CFG, nodes, queues, jobs, running, seed=seed)
 
 
-@pytest.mark.parametrize("commit_k", [4, 8])
 @pytest.mark.parametrize("seed", [5, 17])
-def test_multi_commit_eviction_preempted_set_parity(seed, commit_k, monkeypatch):
-    """Eviction rounds with the multi-commit kernel armed: evictee slots
-    bypass certification (they truncate the batch), and the preempted /
-    rescheduled sets must still match the oracle exactly."""
+def test_eviction_preempted_set_parity(seed, round_body):
+    """Eviction rounds under both bodies: evictee slots take the pinned path
+    (never the fit cache), and the preempted / rescheduled sets must match
+    the oracle exactly."""
     import dataclasses
 
-    monkeypatch.setenv("ARMADA_COMMIT_K", str(commit_k))
     cfg = dataclasses.replace(CFG, protected_fraction_of_fair_share=0.0)
     nodes, queues, jobs, running = world(
         seed, num_nodes=120, num_jobs=150, num_running=60, gangs=0

@@ -530,20 +530,23 @@ def test_device_loss_mid_cycle_invalidates_prefetch(monkeypatch):
         watchdog._reset_hooks[:] = saved_hooks
 
 
-def test_sidecar_pipelined_equals_sequential_with_commit_k(monkeypatch):
-    """The pipeline equality suite with the multi-commit kernel armed
-    (round 15): pipelined vs sequential cycle order must stay bit-equal
-    when every round runs the K=8 body, AND the armed runs must match the
-    K=1 decisions -- the shadow prefetch and the batched commits compose."""
+def test_sidecar_pipelined_equals_sequential_under_the_chip_body(monkeypatch):
+    """The pipeline equality suite on the body an accelerator compiles (no
+    fit cache; ARMADA_CACHE_SLOTS=0 here): pipelined vs sequential cycle
+    order must stay bit-equal, AND its decisions must match those of
+    XLA:CPU's cached body -- what a CPU failover would have decided."""
     runs = {}
-    for ck in ("8", "1"):
-        monkeypatch.setenv("ARMADA_COMMIT_K", ck)
-        runs[ck] = (
+    for body in ("chip", "cpu"):
+        if body == "chip":
+            monkeypatch.setenv("ARMADA_CACHE_SLOTS", "0")
+        else:
+            monkeypatch.delenv("ARMADA_CACHE_SLOTS")
+        runs[body] = (
             _sidecar_scenario(monkeypatch, True, True, 0),
             _sidecar_scenario(monkeypatch, False, True, 0),
         )
-    for ck, (a, b) in runs.items():
-        assert a[0] == b[0], f"K={ck}: per-round decisions diverged"
-        assert a[1] == b[1], f"K={ck}: final mirror state diverged"
-    assert runs["8"][0][0] == runs["1"][0][0], "K=8 decisions != K=1"
-    assert any(sched for sched, _ in runs["8"][0][0]), "scenario must schedule"
+    for body, (a, b) in runs.items():
+        assert a[0] == b[0], f"{body}: per-round decisions diverged"
+        assert a[1] == b[1], f"{body}: final mirror state diverged"
+    assert runs["chip"][0][0] == runs["cpu"][0][0], "the two bodies decided differently"
+    assert any(sched for sched, _ in runs["chip"][0][0]), "scenario must schedule"

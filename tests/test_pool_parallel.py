@@ -10,7 +10,8 @@ here:
    /gang/preemption stream driven through P in {2, 4, 8} pool-restricted
    tenants yields identical per-cycle decisions, apply order (the event
    order), and final JobDb state with pool-parallel armed vs the serial
-   loop -- both assemble modes, with verify armed, commit_k in {1, 8}.
+   loop -- both assemble modes, with verify armed, under the chip's
+   body (no fit cache) and XLA:CPU's.
 2. *Certification fallback*: a cycle that cannot certify pool
    independence (a multi-pool job queued, binding rate-limiter tokens)
    runs the serial order -- and stays bit-equal (the ledger shows the
@@ -269,9 +270,9 @@ def test_pool_parallel_bit_equal_with_verify_and_stacking(monkeypatch):
     assert verify_state().rounds > 0 and verify_state().failures == 0
 
 
-@pytest.mark.parametrize("commit_k", [1, 8])
-def test_pool_parallel_bit_equal_with_commit_k(monkeypatch, commit_k):
-    monkeypatch.setenv("ARMADA_COMMIT_K", str(commit_k))
+def test_pool_parallel_bit_equal_under_both_bodies(monkeypatch, round_body):
+    """The stacked launch is a vmap of whichever body the platform compiles:
+    the chip's (ARMADA_CACHE_SLOTS=0 here) and XLA:CPU's fit cache."""
     a = run_scenario(False, npools=4, seed=1, monkeypatch=monkeypatch)
     b = run_scenario(True, npools=4, seed=1, monkeypatch=monkeypatch)
     assert a == b

@@ -2,8 +2,8 @@
 
 The placement loop no longer gathers its per-queue skip window on every trip:
 it carries the three [Q, W] tables and patches them by what a trip changed.
-Checked here, on worlds built to hit the window's edges, for K in {1, 8} x
-solo / stacked x check_keys:
+Checked here, on worlds built to hit the window's edges, for solo / stacked x
+check_keys, on the chip's body (cache_slots = 0):
 
 * the invariant itself: at the top of EVERY trip the carried tables equal a
   fresh gather (``_skip_window``) from the cursors, gang states and bad keys
@@ -12,12 +12,15 @@ solo / stacked x check_keys:
 * every RoundResult field: what the sequential oracle (tests/
   test_parity_full.py) decides -- scheduled jobs and their count, preempted
   and rescheduled runs, per-queue allocation, termination -- against the
-  oracle, and all fields bit for bit against the K=1 solo round
-  (``kernel_iters`` and ``window_refills`` count trips and differ with K);
+  oracle, and the stacked round's fields bit for bit against the solo round's;
 * ``window_refills``, pinned per world;
-* the same at Q = 512 (PR 28): 300 queues whose heads all hold one key, so
-  that its registration retires 299 other heads at once and the whole
-  [512, W] window is gathered again.
+* the same at Q = 512 (PR 28) and at Q = 1,024 (the bucket of the benchmark's
+  925-queue configuration): hundreds of queues whose heads all hold one key, so
+  that its registration retires every other head at once and the whole [Q, W]
+  window is gathered again;
+* a fleet with no room for most of what is queued: the round cannot fill its
+  cap, fails every (queue, key) head in turn, gathers the window again on
+  almost every trip and ends ``exhausted`` (the walk PR 27 met on the chip).
 """
 
 import dataclasses
@@ -39,8 +42,8 @@ WHOLE = 16  # cores of a node: fits its shape, never its free capacity
 GANGS = 64  # every world fills its gang axis exactly: the last window clips at G - 1
 
 
-def _res(cpu):
-    return F.from_mapping({"cpu": cpu, "memory": 1})
+def _res(cpu, memory=1):
+    return F.from_mapping({"cpu": cpu, "memory": memory})
 
 
 class _World:
@@ -64,12 +67,12 @@ class _World:
                        resources=_res(1))
         return RunningJob(job=spec, node_id=node, away=False)
 
-    def add(self, queue, cpu, n=1, pc="high"):
+    def add(self, queue, cpu, n=1, pc="high", memory=1):
         for _ in range(n):
             i = len(self.jobs)
             self.jobs.append(
                 JobSpec(id=f"j{i:03d}", queue=f"q{queue}", priority_class=pc, submit_time=float(i),
-                        resources=_res(cpu))
+                        resources=_res(cpu, memory))
             )
         return self
 
@@ -93,8 +96,8 @@ def _deep_skip():
 
 def _shared_key():
     """Every queue's head is the same whole-node job: ONE failed fit registers a key that
-    the heads of nine other queues hold -- more cursors move than any body rebuilds by
-    rows (K = 8 rebuilds eight)."""
+    the heads of nine other queues hold -- more cursors move than the body rebuilds by
+    rows (one)."""
     w = _World()
     for q in range(NQ):
         w.add(q, WHOLE, 1)
@@ -131,47 +134,71 @@ def _exhausted():
     return w.fill(queue=7)
 
 
-WIDE_NQ, WIDE_GANGS, WIDE_NODES = 300, 768, 40  # Q = 512 and G = 768 under a shape bucket of 256
+# Under a shape bucket of 256: (real queues, gang slots, nodes) -> the queue axis.  512 is the
+# axis past its first bucket; 1,024 is what the benchmark's 925 queues pad to.
+WIDE = {512: (300, 768, 40), 1024: (800, 1792, 72)}
 
 
-def _wide_shared_key():
-    """`_shared_key` on the queue axis past its first 256-bucket: 300 queues, every head the
-    same whole-node job, so ONE failed fit retires 299 other queues' heads and the carried
-    [512, W] window is gathered whole; behind each head one 1-core job, and room for every
-    1-core job (40 nodes), so that the set placed does not depend on the order of trips
-    (without keys the 300 heads fail one by one, between other queues' placements)."""
-    w = _World(
-        dataclasses.replace(CFG, shape_bucket=256), nq=WIDE_NQ, gangs=WIDE_GANGS, nodes=WIDE_NODES
-    )
-    for q in range(WIDE_NQ):
+def _wide_shared_key(q_axis):
+    """`_shared_key` on a queue axis of hundreds: every head the same whole-node job, so ONE
+    failed fit retires every other queue's head and the carried [Q, W] window is gathered
+    whole; behind each head one 1-core job, and room for every 1-core job, so that the set
+    placed does not depend on the order of trips (without keys the heads fail one by one,
+    between other queues' placements)."""
+    nq, gangs, nodes = WIDE[q_axis]
+    w = _World(dataclasses.replace(CFG, shape_bucket=256), nq=nq, gangs=gangs, nodes=nodes)
+    for q in range(nq):
         w.add(q, WHOLE, 1)
-    for q in range(WIDE_NQ):
+    for q in range(nq):
         w.add(q, 1, 1)
-    return w.fill(queue=WIDE_NQ - 1)
+    return w.fill(queue=nq - 1)
+
+
+FULL_KEYS = 6  # whole-node requests a pair of queues holds, each its own key
+
+
+def _full_fleet():
+    """A fleet with room for four of the sixty-four jobs queued.  Every queue holds six
+    whole-node jobs of six keys, and a pair of queues holds the same six: each failed fit
+    registers a key that retires the partner's head too, so two cursors move on the next
+    trip and the window is gathered whole -- on every trip until the last pair is through.
+    The round leases the four 1-core jobs behind them and ends `exhausted`."""
+    w = _World()
+    for q in range(NQ):
+        for j in range(FULL_KEYS):
+            w.add(q, WHOLE, 1, memory=2 + (q // 2) * FULL_KEYS + j)
+    return w.fill()
 
 
 WORLDS = {
     "deep_skip": _deep_skip, "shared_key": _shared_key, "tails": _tails,
     "evictees": _evictees, "exhausted": _exhausted,
 }
-WIDE_WORLDS = {"wide_shared_key": _wide_shared_key}  # other shapes: never stacked with WORLDS
-# window_refills[world][(K, check_keys)].  A trip rebuilds K rows.  With K = 1 two cursors
-# move at once only after a key registration: one that retires another queue's head too,
-# or one whose queue is still skipping (its window skipped whole) while the next decided
-# queue moves.  Without keys nothing is registered, and never more than K cursors move.
+# other shapes: never stacked with WORLDS
+OWN_SHAPES = {
+    "wide_shared_key_512": functools.partial(_wide_shared_key, 512),
+    "wide_shared_key_1024": functools.partial(_wide_shared_key, 1024),
+    "full_fleet": _full_fleet,
+}
+# window_refills[world][check_keys].  A trip rebuilds one row.  Two cursors move at once
+# only after a key registration: one that retires another queue's head too, or one whose
+# queue is still skipping (its window skipped whole) while the next decided queue moves.
+# Without keys nothing is registered, and never more than one cursor moves.
 REFILLS = {
-    "deep_skip": {(1, True): 1, (1, False): 0, (8, True): 0, (8, False): 0},
-    "shared_key": {(1, True): 1, (1, False): 0, (8, True): 1, (8, False): 0},
-    "tails": {(1, True): 0, (1, False): 0, (8, True): 0, (8, False): 0},
-    "evictees": {(1, True): 1, (1, False): 0, (8, True): 0, (8, False): 0},
-    "exhausted": {(1, True): 0, (1, False): 0, (8, True): 0, (8, False): 0},
-    "wide_shared_key": {(1, True): 1, (1, False): 0, (8, True): 1, (8, False): 0},
+    "deep_skip": {True: 1, False: 0},
+    "shared_key": {True: 1, False: 0},
+    "tails": {True: 0, False: 0},
+    "evictees": {True: 1, False: 0},
+    "exhausted": {True: 0, False: 0},
+    "wide_shared_key_512": {True: 1, False: 0},
+    "wide_shared_key_1024": {True: 1, False: 0},
+    "full_fleet": {True: (NQ // 2) * FULL_KEYS},  # one for every key there is
 }
 
 
 @functools.lru_cache(maxsize=None)
 def _built(name):
-    w = {**WORLDS, **WIDE_WORLDS}[name]()
+    w = {**WORLDS, **OWN_SHAPES}[name]()
     problem, ctx = build_problem(
         w.config, pool="default", nodes=w.nodes, queues=w.queues, queued_jobs=w.jobs, running=w.running
     )
@@ -183,18 +210,18 @@ def _built(name):
     return dev, ctx, (scheduled, preempted, rescheduled, q_alloc)
 
 
-def _statics(dev, ctx, commit_k):
+def _statics(dev, ctx):
     return dict(
         num_levels=len(ctx.ladder) + 2, max_slots=ctx.max_slots, slot_width=ctx.slot_width,
         **fs._resolve_round_statics(
             compat_rows=dev.compat.shape[0], G=dev.g_req.shape[0], Q=dev.q_weight.shape[0], max_iterations=0,
-            prefer_large=False, cache_slots=0, unroll=1, batch_k=1, commit_k=commit_k,
+            prefer_large=False, cache_slots=0,
         ),
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _round(commit_k, stacked, check_keys):
+def _round(stacked, check_keys):
     """The round as served, its body built with `check_keys` and, solo, watched: before
     every trip the carried window is compared with a fresh gather."""
     bare = fs._schedule_round_jit.__wrapped__  # under jit: the named function
@@ -226,7 +253,7 @@ def _round(commit_k, stacked, check_keys):
         finally:
             fs._make_place_iteration = make
 
-    jitted = jax.jit(run, static_argnames=tuple(_statics(*_built("tails")[:2], 1)))
+    jitted = jax.jit(run, static_argnames=tuple(_statics(*_built("tails")[:2])))
     return jitted, trips
 
 
@@ -240,80 +267,81 @@ def _assert_oracle(name, result, ctx, expected):
     assert outcome.termination == "exhausted", name
 
 
+def _watched_round(name, check_keys):
+    """The solo round on `name`, the invariant checked at the top of every trip (the dummy
+    ones included), the oracle's decisions and the pinned refills."""
+    dev, ctx, expected = _built(name)
+    run, trips = _round(False, check_keys)
+    del trips[:]
+    got = jax.block_until_ready(run(dev, **_statics(dev, ctx)))
+    jax.effects_barrier()
+    assert len(trips) == int(got.kernel_iters) == int(got.iterations) > 0, name
+    assert np.all(trips), (name, np.argwhere(~np.stack(trips)))
+    _assert_oracle(name, got, ctx, expected)
+    assert int(got.window_refills) == REFILLS[name][check_keys], name
+    return got
+
+
 @pytest.mark.parametrize("check_keys", [True, False], ids=["keys", "nokeys"])
 @pytest.mark.parametrize("stacked", [False, True], ids=["solo", "stacked"])
-@pytest.mark.parametrize("commit_k", [1, 8], ids=["k1", "k8"])
-def test_carried_window_is_the_gathered_window(commit_k, stacked, check_keys):
+def test_carried_window_is_the_gathered_window(stacked, check_keys):
+    if not stacked:
+        for name in WORLDS:
+            _watched_round(name, check_keys)
+        return
     built = {name: _built(name) for name in WORLDS}
-    results = {}
-    if stacked:
-        run, _ = _round(commit_k, True, check_keys)
-        lanes = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *(built[n][0] for n in WORLDS))
-        dev, ctx, _ = built["tails"]
-        out = run(lanes, **_statics(dev, ctx, commit_k))
-        for i, name in enumerate(WORLDS):
-            results[name] = jax.tree_util.tree_map(lambda a: a[i], out)
-    else:
-        run, trips = _round(commit_k, False, check_keys)
-        for name, (dev, ctx, _) in built.items():
-            del trips[:]
-            results[name] = jax.block_until_ready(run(dev, **_statics(dev, ctx, commit_k)))
-            jax.effects_barrier()
-            # the invariant, at the top of every trip (the dummy one included)
-            assert len(trips) == int(results[name].kernel_iters) > 0, name
-            assert np.all(trips), (name, np.argwhere(~np.stack(trips)))
-    ref_run, _ = _round(1, False, check_keys)
-    for name, (dev, ctx, expected) in built.items():
-        got = results[name]
+    run, _ = _round(True, check_keys)
+    lanes = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *(built[n][0] for n in WORLDS))
+    dev, ctx, _ = built["tails"]
+    out = run(lanes, **_statics(dev, ctx))
+    solo_run, _ = _round(False, check_keys)
+    for i, (name, (dev, ctx, expected)) in enumerate(built.items()):
+        got = jax.tree_util.tree_map(lambda a: a[i], out)
         _assert_oracle(name, got, ctx, expected)
-        ref = ref_run(dev, **_statics(dev, ctx, 1))
+        solo = solo_run(dev, **_statics(dev, ctx))
         for field in got._fields:
             if field in ("kernel_iters", "window_refills"):
-                continue
+                continue  # lanes run in lockstep: a lane that is done still counts trips
             np.testing.assert_array_equal(
-                np.asarray(getattr(got, field)), np.asarray(getattr(ref, field)),
-                err_msg=f"{name}: {field} differs from the K=1 solo round",
+                np.asarray(getattr(got, field)), np.asarray(getattr(solo, field)),
+                err_msg=f"{name}: {field} differs from the solo round",
             )
-        assert int(got.window_refills) == REFILLS[name][(commit_k, check_keys)], name
+        assert int(got.window_refills) == REFILLS[name][check_keys], name
         assert int(got.window_refills) <= int(got.kernel_iters)
 
 
 @pytest.mark.parametrize("check_keys", [True, False], ids=["keys", "nokeys"])
-@pytest.mark.parametrize("commit_k", [1, 8], ids=["k1", "k8"])
-def test_carried_window_is_the_gathered_window_at_512_queues(commit_k, check_keys):
-    """The invariant, the oracle's decisions and the K = 1 round's fields on a queue axis
-    of 512 (300 real queues), through refills that gather all 512 x W entries again."""
-    name = "wide_shared_key"
-    dev, ctx, expected = _built(name)
-    assert (ctx.num_real_queues, dev.q_weight.shape[0]) == (WIDE_NQ, 512)
-    assert dev.g_req.shape[0] == WIDE_GANGS
-    run, trips = _round(commit_k, False, check_keys)
-    del trips[:]
-    got = jax.block_until_ready(run(dev, **_statics(dev, ctx, commit_k)))
-    jax.effects_barrier()
-    assert len(trips) == int(got.kernel_iters) > 0
-    assert np.all(trips), np.argwhere(~np.stack(trips))
-    _assert_oracle(name, got, ctx, expected)
-    assert int(got.scheduled_count) == WIDE_GANGS - WIDE_NQ  # every 1-core job, no whole-node job
-    ref_run, _ = _round(1, False, check_keys)
-    ref = ref_run(dev, **_statics(dev, ctx, 1))
-    for field in got._fields:
-        if field not in ("kernel_iters", "window_refills"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(got, field)), np.asarray(getattr(ref, field)), err_msg=field
-            )
-    assert int(got.window_refills) == REFILLS[name][(commit_k, check_keys)]
+@pytest.mark.parametrize("q_axis", sorted(WIDE), ids=lambda q: f"q{q}")
+def test_carried_window_is_the_gathered_window_on_a_wide_queue_axis(q_axis, check_keys):
+    """The invariant and the oracle's decisions on a queue axis of 512 (300 real queues) and
+    of 1,024 (800), through refills that gather all Q x W entries again."""
+    name = f"wide_shared_key_{q_axis}"
+    nq, gangs, _ = WIDE[q_axis]
+    dev, ctx, _ = _built(name)
+    assert (ctx.num_real_queues, dev.q_weight.shape[0]) == (nq, q_axis)
+    assert dev.g_req.shape[0] == gangs
+    got = _watched_round(name, check_keys)
+    assert int(got.scheduled_count) == gangs - nq  # every 1-core job, no whole-node job
     # with keys, a registration moves hundreds of cursors at once: the window is refilled
     assert (int(got.window_refills) > 0) == check_keys
 
 
+def test_a_round_that_cannot_fill_its_cap_refills_on_every_failed_head():
+    got = _watched_round("full_fleet", True)
+    placed, trips, refills = int(got.scheduled_count), int(got.kernel_iters), int(got.window_refills)
+    assert placed == GANGS - NQ * FULL_KEYS == 4  # far under any cap: the round ran out of heads
+    # a failed fit for each key, the trips that place, two dummy trips at the end
+    assert trips == refills + placed + 2
+    assert refills / trips > 0.8
+
+
 def test_the_worlds_reach_the_edges():
-    """The worlds do what their docstrings say, read off the K = 1 round with keys."""
-    run, _ = _round(1, False, True)
+    """The worlds do what their docstrings say, read off the solo round with keys."""
+    run, _ = _round(False, True)
     seen = {}
     for name in WORLDS:
         dev, ctx, _ = _built(name)
-        seen[name] = (dev, run(dev, **_statics(dev, ctx, 1)))
+        seen[name] = (dev, run(dev, **_statics(dev, ctx)))
     dev, r = seen["deep_skip"]
     whole = np.asarray(dev.g_req)[:, 0] == req_units(_res(WHOLE))[0]
     assert np.sum(np.asarray(r.g_state)[whole] == 2) == 20

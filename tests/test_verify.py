@@ -4,8 +4,8 @@ The certification layer's contract, pinned four ways:
 
 1. *No false positives*: clean rounds verify green, multi-seed, in BOTH
    assemble modes (legacy dense build_problem and the incremental slab
-   path), with running jobs/evictions in play, at commit_k K in {1, 8},
-   pipelined and sequential -- and an armed plane's DECISIONS are
+   path), with running jobs/evictions in play, under the chip's body
+   and XLA:CPU's, pipelined and sequential -- and an armed plane's DECISIONS are
    bit-identical to a disarmed one's (the pass only reads).
 2. *Oracle cross-check of every invariant*: tampering with exactly one of
    the kernel's redundant encodings (header scalar, slot record, gang
@@ -238,12 +238,10 @@ def test_incremental_mode_verifies_green(seed):
     assert snap["rounds_verified"] >= 3
 
 
-@pytest.mark.parametrize("commit_k", [1, 8])
-def test_verification_armed_parity_at_commit_k(commit_k, monkeypatch):
+def test_verification_armed_parity_under_both_bodies(round_body, monkeypatch):
     """The armed plane's decisions are bit-identical to the disarmed one's
-    at K in {1, 8}, pipelined AND sequential -- the equality legs the
-    acceptance criteria name."""
-    monkeypatch.setenv("ARMADA_COMMIT_K", str(commit_k))
+    under the chip's body (no fit cache) and XLA:CPU's, pipelined AND
+    sequential -- and round verification passes the chip's body on the CPU."""
     monkeypatch.delenv("ARMADA_VERIFY", raising=False)
     base = run_incremental_cycles(CFG, seed=11, pipeline="1")
     monkeypatch.setenv("ARMADA_VERIFY", "1")

@@ -565,17 +565,6 @@ def main() -> int:
         "tsan violations, and reports RTO (restart_recovery_s)",
     )
     ap.add_argument(
-        "--commit-k",
-        type=int,
-        default=None,
-        dest="commit_k",
-        help="arm the conflict-free multi-commit kernel (ARMADA_COMMIT_K) "
-        "for EVERY leg of the drill -- the faulted run, the clean replay, "
-        "and the soak/crash legs -- so chip-loss convergence is exercised "
-        "under the configuration serve would arm, not a silent K=1 "
-        "(default: inherit the environment)",
-    )
-    ap.add_argument(
         "--pools",
         type=int,
         default=0,
@@ -643,11 +632,6 @@ def main() -> int:
     )
     args = ap.parse_args()
 
-    if args.commit_k is not None:
-        # Set BEFORE any leg runs: schedule_round resolves the env per call,
-        # so both replay legs and the soak/crash sub-drills (whose env
-        # save/restore keeps ARMADA_COMMIT_K intact) compile the armed K.
-        os.environ["ARMADA_COMMIT_K"] = str(args.commit_k)
     if args.ingest_shards is not None:
         os.environ["ARMADA_INGEST_SHARDS"] = str(args.ingest_shards)
     if args.store_shards is not None:
@@ -866,11 +850,6 @@ def main() -> int:
         "chaos_run_s": round(chaos_s, 2),
         "tsan_violations": len(tsan_found),
     }
-    from armada_tpu.models.fair_scheduler import resolve_commit_k
-
-    # the multi-commit width every leg compiled with (bit-equality above
-    # therefore covers the armed kernel, not just K=1)
-    line["commit_k"] = resolve_commit_k()
     from armada_tpu.ingest import resolve_num_shards
 
     # the ingest-shard width every leg ran with (--ingest-shards / env)

@@ -4,9 +4,9 @@
 Builds the 1M x 50k x 64-queue world on an IncrementalBuilder (the served
 path's state, so verify/explain get a real decode context) and runs each
 device program of the cycle ONCE cold and once warm, in this order: the
-full slab upload, the round at commit_k=1, compaction, verify, explain, the
+full slab upload, the round, compaction, verify, explain, the
 `scatter_content` prefetch and the slab delta scatter of the next cycle,
-the round at commit_k=8, and -- toy shapes -- the vmapped stacked round.
+and -- toy shapes -- the vmapped stacked round.
 
 For each it prints the XLA backend-compile seconds spent inside the first
 call (jax.monitoring), the first and second call's wall time, and the
@@ -128,16 +128,13 @@ def main(argv=None) -> int:
         repeat=False,
     )
 
-    def round_at(dev, kw, k):
-        def run():
-            result = schedule_round(dev, **kw, commit_k=k)
-            return result, int(result.scheduled_count), int(result.kernel_iters)
+    def run_round():
+        result = schedule_round(dev, **kw)
+        return result, int(result.scheduled_count), int(result.kernel_iters)
 
-        return run
-
-    result, n_sched, trips = measure("round_commit_k1", round_at(dev, kw, 1))
+    result, n_sched, trips = measure("round", run_round)
     assert n_sched > 0, "round scheduled nothing"
-    print(f"chip_programs: K=1 scheduled {n_sched} in {trips} trips", file=sys.stderr)
+    print(f"chip_programs: scheduled {n_sched} in {trips} trips", file=sys.stderr)
 
     def compact():
         fin = begin_decode(result, ctx)
@@ -181,22 +178,14 @@ def main(argv=None) -> int:
             for jid, nid in outcome.scheduled.items()
         ]
     )
-    bundle2, ctx2 = builder.assemble_delta()
+    bundle2, _ = builder.assemble_delta()
 
     def scatter():
         d = devcache.apply(bundle2)
         np.asarray(d.g_valid[:1])
         return d
 
-    dev = measure("slab_delta_scatter", scatter, repeat=False)
-    kw = dict(
-        num_levels=len(ctx2.ladder) + 2,
-        max_slots=ctx2.max_slots,
-        slot_width=ctx2.slot_width,
-    )
-    _, n8, trips8 = measure("round_commit_k8", round_at(dev, kw, 8))
-    assert n8 > 0, "K=8 round scheduled nothing"
-    print(f"chip_programs: K=8 scheduled {n8} in {trips8} trips", file=sys.stderr)
+    measure("slab_delta_scatter", scatter, repeat=False)
 
     # Toy shapes: the vmapped stacked round (pool-parallel serving).
     toy, meta = synthetic_problem(

@@ -67,16 +67,6 @@ def main() -> int:
         "window (default 0.5); RTO lands in restart_recovery_s",
     )
     ap.add_argument(
-        "--commit-k",
-        type=int,
-        default=None,
-        dest="commit_k",
-        help="arm the conflict-free multi-commit kernel (ARMADA_COMMIT_K) "
-        "for the whole soak window, including the fault/crash legs (the "
-        "drill's env save/restore keeps it armed); default: inherit the "
-        "environment",
-    )
-    ap.add_argument(
         "--ingest-shards",
         type=int,
         default=None,
@@ -101,8 +91,6 @@ def main() -> int:
         help="JSON-line output (the default; kept for bench.py symmetry)",
     )
     args = ap.parse_args()
-    if args.commit_k is not None:
-        os.environ["ARMADA_COMMIT_K"] = str(args.commit_k)
     if args.ingest_shards is not None:
         os.environ["ARMADA_INGEST_SHARDS"] = str(args.ingest_shards)
     if args.store_shards is not None:
