@@ -1181,7 +1181,7 @@ def _unpinned_out_shardings(src: Source):
 _POOL_STATE_FACTORIES = {"builder_for": "builder", "devcache_for": "devcache"}
 _POOL_STATE_MUTATORS = {
     "submit", "submit_many", "remove", "remove_many", "lease", "lease_many",
-    "unlease", "unlease_if_present", "set_nodes", "set_queues",
+    "unlease", "unlease_many", "set_nodes", "set_queues",
     "assemble_delta", "apply", "scatter_content", "prefetch_content",
     "invalidate_prefetch", "note_running_gang", "forget_running_gang",
 }

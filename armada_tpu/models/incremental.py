@@ -1250,30 +1250,40 @@ class IncrementalBuilder:
             self._unknown_queue.pop(job_id, None)
             self.running_gang_specs.pop(job_id, None)
             enc.append(job_id.encode())
-        qis, pcs, reqs = [], [], []
-        freed: list = []
-        gw = self._g_ids.live.shape[0]
         with _trace().span("table_remove", n=len(enc)):
             infos = self.jobs.remove_many(enc)
+        gw = self._g_ids.live.shape[0]
+        freed = [
+            s
+            for s in self._release_rows(infos, self._sg, self._demand_sg)
+            if s < gw
+        ]
+        if freed:
+            self._g_ids.write(np.asarray(freed, np.int64), b"", span="g_ids_copy")
+
+    def _release_rows(self, infos: Sequence, slab, demand: np.ndarray) -> list:
+        """Free the slab slots of a table's removed rows (remove_many's
+        infos; None = the id was absent) in the batch's order, so the free
+        list is what one-by-one removal leaves, and retire their demand
+        shares in ONE vectorized update.  Returns the freed slots."""
+        qis, pcs, reqs, slots = [], [], [], []
         for info in infos:
             if info is None:
                 continue
             slot = int(info["slot"])
-            if self._sg.valid[slot]:
+            if slab.valid[slot]:
                 qis.append(int(info["qi"]))
                 pcs.append(int(info["pc"]))
                 reqs.append(info["req"])
-            self._sg.release(slot)
-            if slot < gw:
-                freed.append(slot)
-        if freed:
-            self._g_ids.write(np.asarray(freed, np.int64), b"", span="g_ids_copy")
+            slab.release(slot)
+            slots.append(slot)
         if qis:
             np.subtract.at(
-                self._demand_sg,
+                demand,
                 (np.asarray(qis, np.int64), np.asarray(pcs, np.int64)),
                 np.stack(reqs).astype(np.float64),
             )
+        return slots
 
     def reprioritise(self, spec: JobSpec) -> None:
         """Priority changed: re-slot (the order key embeds the priority)."""
@@ -1373,17 +1383,23 @@ class IncrementalBuilder:
         self._pending_runs.pop(job_id, None)
         self._release_run(self.runs.remove(job_id.encode()))
 
-    def unlease_if_present(self, job_id: str, jid_b: Optional[bytes] = None) -> None:
-        """Feed hot-path unlease: O(1) dict membership checks first, so the
-        common case -- a fresh submit that was never leased in this pool --
-        skips the encode + run-table probe the JobDb feed otherwise pays
-        per builder per upsert (scheduler/incremental_algo.apply_job)."""
-        if (
-            (jid_b if jid_b is not None else job_id.encode()) in self.runs
-            or job_id in self._pending_runs
-            or job_id in self.running_gang_specs
-        ):
-            self.unlease(job_id)
+    def unlease_many(self, job_ids: Sequence[str]) -> None:
+        with _trace().span("unlease_many", pool=self.pool, n=len(job_ids)):
+            self._unlease_many(job_ids)
+
+    def _unlease_many(self, job_ids: Sequence[str]) -> None:
+        """Batched unlease() for a commit's ended runs (a sync's ~1k
+        completions), the run-table twin of _remove_many: one table pass +
+        ONE vectorized demand update.  Slots go back in the batch's order,
+        so the free list -- and every later slot assignment -- is what
+        unlease() job by job leaves.  An id with no run here (a fresh
+        submit, another pool's run) costs three dict pops."""
+        enc = []
+        for job_id in job_ids:
+            self.running_gang_specs.pop(job_id, None)
+            self._pending_runs.pop(job_id, None)
+            enc.append(job_id.encode())
+        self._release_rows(self.runs.remove_many(enc), self._rr, self._demand_run)
 
     def _flush_pending_runs(self) -> None:
         ready = [

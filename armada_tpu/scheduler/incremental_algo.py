@@ -48,6 +48,28 @@ def new_device_cache() -> DeviceDeltaCache:
     return DeviceDeltaCache()
 
 
+class _Batch:
+    """One commit's builder work, gathered job by job in commit order and
+    handed to each builder in one call a kind (IncrementalProblemFeed._flush).
+
+    gone     ids leaving every builder: deletes and terminal upserts
+    leased   ids leaving every backlog because they run now
+    ended    (id, pool where its run lives on, or None): every builder but
+             that pool's drops the id's run, if it holds one
+    submits  id -> spec at its current priority; bans: id -> banned nodes
+    leases   pool -> the RunningJobs joining that pool's run table"""
+
+    __slots__ = ("gone", "leased", "ended", "submits", "bans", "leases")
+
+    def __init__(self):
+        self.gone: list = []
+        self.leased: list = []
+        self.ended: list = []
+        self.submits: dict = {}
+        self.bans: dict = {}
+        self.leases: dict = {}
+
+
 class IncrementalProblemFeed:
     """Per-pool IncrementalBuilders + device caches, fed from JobDb commits.
 
@@ -148,10 +170,10 @@ class IncrementalProblemFeed:
             self.builders[p.name] = IncrementalBuilder(self.config, p.name)
             self.devcaches[p.name] = new_device_cache()
         if self._jobdb is not None:
-            pending = {}
+            batch = _Batch()
             for job in self._jobdb.read_txn().all_jobs():
-                self.apply_job(job, pending)
-            self._flush(pending)
+                self.apply_job(job, batch)
+            self._flush(batch)
 
     def builder_for(self, pool: str, txn=None) -> Optional[IncrementalBuilder]:
         b = self.builders.get(pool)
@@ -162,10 +184,10 @@ class IncrementalProblemFeed:
             if txn is not None:
                 # Late pool discovery (a node snapshot introduced a pool not
                 # in config): one-time backfill scan.
-                pending = {}
+                batch = _Batch()
                 for job in txn.all_jobs():
-                    self.apply_job(job, pending)
-                self._flush(pending)
+                    self.apply_job(job, batch)
+                self._flush(batch)
         return b
 
     def devcache_for(self, pool: str) -> DeviceDeltaCache:
@@ -205,78 +227,69 @@ class IncrementalProblemFeed:
         self._apply_delta(upserts, deletes, record=True)
 
     def _apply_delta(self, upserts: dict, deletes, record: bool) -> None:
-        # Per-job submit()/lease() is one np.insert PER COLUMN PER JOB --
-        # O(table) each, so a K-job commit against a 1M-row table would cost
-        # O(K x table x pools).  Accumulate the batch and flush once per
-        # builder (one np.insert per column total), the same shape bench.py's
-        # backlog load uses.
+        # A per-job table call is a binary search through numpy's dispatch
+        # wrappers (remove / unlease) or one np.insert PER COLUMN (submit /
+        # lease, O(table) each): a K-job commit would pay K of them a
+        # builder.  So the commit is gathered into ONE batch -- the ids
+        # that leave, the runs that ended, the submits, the leases -- and
+        # _flush hands each builder one call a kind.  `terminal` counts the
+        # ids (deletes and terminal upserts) that left through that pass.
         with _trace().span(
             "feed_apply",
             upserts=len(upserts),
             deletes=len(deletes),
             overlay=record,
-        ):
+        ) as span:
+            batch = _Batch()
             for job_id in deletes:
                 if job_id in self._overlaid_deletes:
                     continue
                 if record:
                     self._overlaid_deletes.add(job_id)
-                self._remove_everywhere(job_id)
-            pending: dict = {}
+                self._gone(job_id, batch)
             overlaid = self._overlaid
             for job in upserts.values():
                 if overlaid.get(job.id) is job:
                     continue
                 if record:
                     overlaid[job.id] = job
-                self.apply_job(job, pending)
-            self._flush(pending)
+                self.apply_job(job, batch)
+            span.annotate(terminal=len(batch.gone))
+            self._flush(batch)
 
-    def _pending_for(
-        self, pending: dict, pool: str
-    ) -> tuple[dict, dict, dict, dict]:
-        entry = pending.get(pool)
-        if entry is None:
-            # submits/bans/leases/removals all keyed by job id: a re-applied
-            # job within one batch must not become two live rows
-            # (submit_many/lease_many only de-dupe against the TABLE, not
-            # within their own batch).
-            entry = pending[pool] = ({}, {}, {}, {})
-        return entry
-
-    @staticmethod
-    def _purge_pending(pending: dict, job_id: str, leases_too: bool) -> None:
-        for submits, ban_map, leases, _removals in pending.values():
-            submits.pop(job_id, None)
-            ban_map.pop(job_id, None)
-            if leases_too:
-                leases.pop(job_id, None)
-
-    def _flush(self, pending: dict) -> None:
-        # Per-op spans (submit_many/remove_many/lease_many) live inside the
-        # builder methods themselves, so the trace attributes this cost
-        # wherever the feed runs -- serve, sidecar, or bench.
-        for pool, (submits, bans, leases, removals) in pending.items():
-            b = self.builders.get(pool)
-            if b is None:
-                continue
-            if removals:
-                # Batched: a cycle's ~1k scheduled jobs leave the backlog
-                # with one table pass + one demand update (remove_many),
-                # not 1k binary searches through numpy dispatch wrappers.
-                b.remove_many(list(removals))
-            if submits:
-                b.submit_many(list(submits.values()), bans or None)
+    def _flush(self, batch: _Batch) -> None:
+        # Per-op spans (remove_many/unlease_many/submit_many/lease_many)
+        # live inside the builder methods themselves, so the trace
+        # attributes this cost wherever the feed runs -- serve, sidecar, or
+        # bench.  Order a builder: ids out of the backlog and ended runs out
+        # of the run table first (their slab slots return to the free lists
+        # in the batch's order), then the submits and leases that reuse
+        # them.
+        leaving = batch.gone + batch.leased
+        for pool, b in self.builders.items():
+            if leaving:
+                # one table pass + one demand update for a commit's
+                # terminal jobs and a round's ~1k scheduled jobs alike
+                b.remove_many(leaving)
+            ended = [j for j, stays in batch.ended if stays != pool]
+            if ended:
+                b.unlease_many(ended)
+            if batch.submits:
+                b.submit_many(
+                    list(batch.submits.values()), batch.bans or None
+                )
+            leases = batch.leases.get(pool)
             if leases:
-                b.lease_many(list(leases.values()))
+                b.lease_many(leases)
 
-    def _remove_everywhere(self, job_id: str) -> None:
+    def _gone(self, job_id: str, batch: _Batch) -> None:
+        """A deleted or terminal job: out of the feed's own sets now, out of
+        every builder's backlog and run table at the flush."""
         self.pool_restricted.discard(job_id)
         self.unrestricted_queued.discard(job_id)
         self.multi_pool_queued.discard(job_id)
-        for b in self.builders.values():
-            b.remove(job_id)
-            b.unlease(job_id)
+        batch.gone.append(job_id)
+        batch.ended.append((job_id, None))
         self._forget_gang(job_id)
 
     def _forget_gang(self, job_id: str) -> None:
@@ -287,19 +300,19 @@ class IncrementalProblemFeed:
             if b is not None:
                 b.forget_running_gang(queue, gang_id, job_id)
 
-    def apply_job(self, job: Job, pending: Optional[dict] = None) -> None:
-        """Translate one job's state into builder deltas.  Removes/unleases
-        apply immediately (tombstones, cheap); submits/leases go into
-        `pending` (flushed by the caller as one batch per builder) or flush
-        inline when called one-shot."""
-        flush_here = pending is None
-        if pending is None:
-            pending = {}
+    def apply_job(self, job: Job, batch: Optional[_Batch] = None) -> None:
+        """Translate one job's state into builder deltas.  Nothing touches a
+        builder's tables here: the job is classified into `batch` (what
+        leaves, whose run ended, submits, leases), which the caller flushes
+        once per builder, or which flushes inline -- a batch of one, the
+        same code -- when called one-shot.  A batch holds an id once: a
+        commit's upserts are keyed by id, as the JobDb's jobs are."""
+        flush_here = batch is None
+        if batch is None:
+            batch = _Batch()
         if job.in_terminal_state():
-            self._remove_everywhere(job.id)
-            self._purge_pending(pending, job.id, leases_too=True)
-            return
-        if job.queued:
+            self._gone(job.id, batch)
+        elif job.queued:
             if not job.validated:
                 return
             pools = job.pools or job.spec.pools
@@ -321,60 +334,53 @@ class IncrementalProblemFeed:
                 self.pool_restricted.discard(job.id)
                 self.multi_pool_queued.discard(job.id)
                 self.unrestricted_queued.add(job.id)
-            self._purge_pending(pending, job.id, leases_too=True)
-            jid_b = job.id.encode()
-            for name, b in self.builders.items():
-                # Guarded: a fresh submit was never leased anywhere, so the
-                # per-builder probe degrades to O(1) dict checks (the feed
-                # hot loop -- ~100ms/cycle of the round-6 profile).
-                b.unlease_if_present(job.id, jid_b)
-                submits, ban_map, _, _ = self._pending_for(pending, name)
-                submits[spec.id] = spec
-                if bans:
-                    ban_map[spec.id] = tuple(bans)
-            if flush_here:
-                self._flush(pending)
-            return
-        # leased / running
+            # a requeued job's run ended; a fresh submit never had one
+            batch.ended.append((job.id, None))
+            batch.submits[spec.id] = spec
+            if bans:
+                batch.bans[spec.id] = tuple(bans)
+        else:
+            self._leased(job, batch)
+        if flush_here:
+            self._flush(batch)
+
+    def _leased(self, job: Job, batch: _Batch) -> None:
+        """A leased or running job: out of every backlog, and into the run
+        table of its run's pool while that run lives."""
         self.pool_restricted.discard(job.id)
         self.unrestricted_queued.discard(job.id)
         self.multi_pool_queued.discard(job.id)
+        batch.leased.append(job.id)
         run = job.latest_run
-        for name in self.builders:
-            self._pending_for(pending, name)[3][job.id] = True
-        self._purge_pending(pending, job.id, leases_too=True)
-        jid_b = job.id.encode()
         if run is None or run.in_terminal_state():
-            for b in self.builders.values():
-                b.unlease_if_present(job.id, jid_b)
+            batch.ended.append((job.id, None))
             self._forget_gang(job.id)
             return
         pool = run.pool or "default"
-        for name, b in self.builders.items():
-            if name != pool:
-                b.unlease_if_present(job.id, jid_b)
+        # the run lives on in its own pool's table (lease_many replaces the
+        # row); any other builder's row for this job is a run that ended
+        batch.ended.append((job.id, pool))
         # Existing builders only: creating one here would skip builder_for's
         # one-time JobDb backfill and permanently hide the queued backlog
         # from a late-discovered pool (the algo creates builders WITH a txn).
         b = self.builders.get(pool)
         if b is None:
             return
-        r = RunningJob(
-            job=(
-                job.spec
-                if job.priority == job.spec.priority
-                else dataclasses.replace(job.spec, priority=job.priority)
-            ),
-            node_id=run.node_id,
-            priority=run.scheduled_at_priority or 0,
-            away=run.pool_scheduled_away,
+        batch.leases.setdefault(pool, []).append(
+            RunningJob(
+                job=(
+                    job.spec
+                    if job.priority == job.spec.priority
+                    else dataclasses.replace(job.spec, priority=job.priority)
+                ),
+                node_id=run.node_id,
+                priority=run.scheduled_at_priority or 0,
+                away=run.pool_scheduled_away,
+            )
         )
-        self._pending_for(pending, pool)[2][job.id] = r
         if job.spec.gang_id:
             b.note_running_gang(job.queue, job.spec.gang_id, job.id)
             self._gang_of[job.id] = (pool, job.queue, job.spec.gang_id)
-        if flush_here:
-            self._flush(pending)
 
     # ------------------------------------------------------------ queries ---
 

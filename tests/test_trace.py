@@ -604,6 +604,9 @@ _EXPECTED_STEADY_SPANS = {
     "g_ids_copy": 1, "lease_many": 1, "away_prepare": 1, "slo_feed": 1, "xfer_down": 1,
     # the queue axis (PR 28): one span a boundary, whatever the queue count
     "queue_tokens": 1, "queue_caps": 1, "stats_encode": 1,
+    # the sync's commit (PR 32): the run table asked once for every id that
+    # may have left a run behind (here the fresh submits, which hold none)
+    "unlease_many": 1,
 }
 
 
