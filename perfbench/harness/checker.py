@@ -30,7 +30,16 @@ Invariants (README.md lists them for users):
      `priorityClasses`) is preempted, and no job is both leased and preempted
      in one round;
   8. the initial running jobs are known by id, node, shape and class: a
-     preemption of `r00000017` frees that run's room on that run's node.
+     preemption of `r00000017` frees that run's room on that run's node;
+  9. a round that gives up has nothing left to place: where a round preempted
+     nothing, leased fewer jobs than the per-round cap and reports
+     `termination` "exhausted", then after its leases no job that some queue
+     under its per-queue cap held among its first `maxQueueLookback` queued
+     jobs at the round's start (the queue's order: priority class, submit
+     time, id) fits, in every resource, into what any node has left.  Rounds
+     that preempt are left alone: the reference's second pass may free room
+     that nothing retries.  A configuration whose rounds can give up states
+     its `maxQueueLookback`: a round that gives up without one is reported.
 """
 
 from __future__ import annotations
@@ -42,12 +51,23 @@ MAX_REPORTED = 20
 
 
 class Checker:
-    def __init__(self, world, cap: int, queue_cap: int, priority_classes: dict):
+    def __init__(
+        self, world, cap: int, queue_cap: int, priority_classes: dict, lookback: int | None = None
+    ):
         """`priority_classes` is the configuration's `priorityClasses` block
-        (class name -> {"preemptible": bool, ...})."""
+        (class name -> {"preemptible": bool, "priority": int, ...}); `lookback`
+        its `maxQueueLookback`, which a configuration whose rounds can give up
+        has to state (invariant 9 reads it, and takes no default of the
+        program's for it)."""
         self.world = world
         self.cap = cap
         self.queue_cap = queue_cap
+        self.lookback = None if lookback is None else int(lookback)
+        self.class_priority = {
+            flag: int(priority_classes[world.class_name(flag)].get("priority", 0))
+            for flag in (True, False)
+        }
+        self._order = None  # see `_heads`: made once for the world's tables as they stand
         self.used = np.zeros_like(world.node_total)
         np.add.at(self.used, world.run_node, world.run_shape_req[world.run_shape])
         self.violations: list = []
@@ -130,6 +150,13 @@ class Checker:
             self.used[self.node_of.pop(i)] -= w.shape_req[w.job_shape[i]]
 
         leases = record["leases"]
+        heads = None
+        if self.gave_up(record):
+            if self.lookback is None:
+                self._bad(n, "the round gave up (exhausted) and the configuration states no "
+                          "maxQueueLookback: \"nothing left fits\" cannot be held")
+            else:
+                heads = self._heads()  # before the leases leave the backlog
         # the round evicts, then places: its preemptions free room first
         touched = []
         leased_now = {job_id for job_id, _, _ in leases}
@@ -182,6 +209,9 @@ class Checker:
                     f"of {w.node_total[node].tolist()} (thousandths of cpu, memory)"
                 )
 
+        if heads is not None:
+            self._nothing_left_fits(n, heads, per_queue)
+
         # 5: the scheduler's own counts, as the round saw them on entry
         counts = (record.get("num_queued"), record.get("num_running"))
         if None not in counts and self.prev_counts is not None:
@@ -199,3 +229,57 @@ class Checker:
                 counts,
                 {"leased": len(leases), "preempted": len(record["preempted"])},
             )
+
+    # ---- 9: a round that gives up ----
+
+    def gave_up(self, record: dict) -> bool:
+        """Whether invariant 9 holds this round to "nothing left fits"."""
+        return (
+            record.get("termination") == "exhausted"
+            and not record["preempted"]
+            and len(record["leases"]) < self.cap
+        )
+
+    def _heads(self) -> np.ndarray:
+        """Job numbers of every queue's first `lookback` queued jobs, in the
+        queue's own order (priority class, highest first; submit time; id)."""
+        w = self.world
+        if self._order is None or self._order[0] != w.num_jobs:
+            flags = np.array([s[2] for s in w.shapes], bool)[w.job_shape]
+            rank = np.where(flags, -self.class_priority[True], -self.class_priority[False])
+            order = np.lexsort((np.arange(w.num_jobs), w.job_submit, rank, w.job_queue))
+            queue = w.job_queue[order]
+            starts = np.flatnonzero(np.r_[True, queue[1:] != queue[:-1]])  # each queue's first place
+            self._order = (w.num_jobs, order, starts, np.diff(np.r_[starts, len(order)]))
+        _, order, starts, lengths = self._order
+        queued = (self.status[order] == QUEUED) & (order < self.submitted)
+        before = np.cumsum(queued) - queued  # queued jobs ahead of each, over all queues
+        ahead_of_queue = np.repeat(before[starts], lengths)
+        return order[queued & (before - ahead_of_queue < self.lookback)]
+
+    def _nothing_left_fits(self, n: int, heads: np.ndarray, leased_per_queue: dict) -> None:
+        """`heads` less what the round leased, queue by queue: no shape left in
+        a queue still under its cap fits any node's free room."""
+        w = self.world
+        left = heads[self.status[heads] == QUEUED]
+        capped = [
+            w.queue_index[q] for q, k in leased_per_queue.items() if k >= self.queue_cap
+        ]
+        left = left[~np.isin(w.job_queue[left], capped)]
+        shapes = np.unique(w.job_shape[left])
+        free = w.node_total - self.used
+        fits = (w.shape_req[shapes][:, None, :] <= free[None, :, :]).all(axis=2)
+        if not fits.any():
+            return
+        s, node = (int(x) for x in np.argwhere(fits)[0])
+        shape = int(shapes[s])
+        cpu, mem, preemptible = w.shapes[shape]
+        holder = w.queue_names[int(w.job_queue[left[w.job_shape[left] == shape][0]])]
+        self._bad(
+            n,
+            f"the round gave up (exhausted, {sum(leased_per_queue.values())} leases under the "
+            f"cap {self.cap}, nothing preempted) while a job still fits: {holder} holds a "
+            f"{w.class_name(preemptible)} job of {cpu} cpu thousandths and {mem} memory within "
+            f"its lookback, node {w.node_ids[node]} has {free[node].tolist()} free; "
+            f"{int(fits.any(axis=0).sum())} nodes have room for one of {len(shapes)} shapes",
+        )

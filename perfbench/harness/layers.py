@@ -4,7 +4,11 @@
     {"kind": "cycle_field", "field": "wire_s", "reduce": "median"}
         a field of the per-cycle record (counters, host clocks); a dotted name
         goes into a group of the record: `pool.<key>` is any scalar of the
-        round's own stats JSON, `scatter_rows.sg_rows` an arg of a span
+        round's own stats JSON, `scatter_rows.sg_rows` an arg of a span;
+        with "equals": <value> each sample becomes 1 where the field has that
+        value and 0 where it has another, before the reduction; the reduction
+        "count" is how many samples there are (a share of the window's rounds
+        is a `ratio` of a "sum" of such 0 / 1 samples over a "count")
     {"kind": "span_sum", "spans": ["assemble"], "reduce": "median"}
         per cycle, the seconds inside the named program spans (a span nested
         in another named span counts once), then the reduction over cycles;
@@ -46,6 +50,7 @@ _REDUCE = {
     "sum": sum,
     "max": max,
     "min": min,
+    "count": len,
     "p75": lambda xs: percentile(xs, 75.0),
 }
 
@@ -76,6 +81,8 @@ def read(spec: dict, ctx: dict):
         cycles = [c for c in cycles if c.get("traced")]
     if kind == "cycle_field":
         xs = [x for x in (field_of(c, spec["field"]) for c in cycles) if x is not None]
+        if "equals" in spec:
+            xs = [1 if x == spec["equals"] else 0 for x in xs]
         return _REDUCE[spec.get("reduce", "median")](xs) if xs else None
     if kind == "span_sum":
         names = set(spec["spans"])

@@ -109,6 +109,9 @@ class Run:
         self.traffic = cell.traffic
         self.submits = int(self.traffic["submits_per_cycle"])
         self.lifetime = int(self.traffic["lifetime_cycles"])
+        # a fleet that finishes K jobs a cycle, whatever an earlier round leased
+        rate = self.traffic.get("completions_per_cycle")
+        self.service_rate = None if rate is None else int(rate)
         self.step_ns = int(float(self.traffic["logical_cycle_s"]) * 1e9)
         self.compiles = probes.CompileLog()
         self.gclog = probes.GcLog()
@@ -145,6 +148,7 @@ class Run:
             cap=int(block["maximumSchedulingBurst"]),
             queue_cap=int(block["maximumPerQueueSchedulingBurst"]),
             priority_classes=block["priorityClasses"],
+            lookback=block.get("maxQueueLookback"),
         )
 
     def load_mirror(self) -> None:
@@ -192,17 +196,41 @@ class Run:
 
     def prepare(self, k: int):
         """Between cycles: cycle k's request, with the terminal states of the
-        jobs leased `lifetime` cycles earlier and still running appended (a job
-        the scheduler preempted since has left the books: `forget`)."""
+        jobs that finished appended: every job leased `lifetime` cycles earlier
+        and still running or, where the mix gives `completions_per_cycle`, the
+        K oldest live leases of the books (`finishing`).  A job the scheduler
+        preempted since has left the books: `forget`."""
         if k not in self.requests:
             self.prebuild(1)  # the estimate of cycles per window fell short
         req, submitted = self.requests.pop(k)
-        done = self.leased.pop(k - self.lifetime, {})
-        ran_from = NOW0_NS + (k - self.lifetime + 1) * self.step_ns
-        for i, lease in done.items():
+        completed = []
+        for at, i, lease in self.finishing(k):
             del self.leased_at[i]
+            ran_from = NOW0_NS + (at + 1) * self.step_ns
             req.jobs.append(self.world.terminal_state(i, lease, ran_from))
-        return req, submitted, list(done)
+            completed.append(i)
+        return req, submitted, completed
+
+    def finishing(self, k: int) -> list:
+        """(cycle leased, job number, lease) of the jobs that finish before
+        cycle k, taken out of `leased`.  With `completions_per_cycle` K the
+        fleet has a service rate: from cycle `lifetime` on, the K oldest live
+        leases (by the cycle that leased them, then by their place in that
+        round's response), or all of them if fewer are live; what one thin
+        round leased does not come back as one thin round of completions."""
+        if self.service_rate is None:
+            at = k - self.lifetime
+            return [(at, i, lease) for i, lease in self.leased.pop(at, {}).items()]
+        out, want = [], self.service_rate if k >= self.lifetime else 0
+        for at in sorted(self.leased):
+            if len(out) >= want:
+                break
+            live = self.leased[at]
+            for i in list(live)[: want - len(out)]:
+                out.append((at, i, live.pop(i)))
+            if not live:
+                del self.leased[at]
+        return out
 
     def forget(self, job_id: str) -> None:
         """A job the round reports preempted: the scheduler ended its run and
@@ -397,19 +425,34 @@ def histogram(values, bins: int = 12) -> list:
     return [[round(lo + i * width, 4), c] for i, c in enumerate(counts)]
 
 
-def drift(window: list) -> tuple:
+def drift(window: list, traffic: dict | None = None) -> tuple:
     """How far `num_queued` and `num_running` moved between the window's first
-    and last cycle, each as {"value", "limit"} with the limit 0 (the window
-    stands still), and the problem if one moved."""
+    and last cycle, each as {"value", "limit"}, and the problem if one moved by
+    more than its limit.  The running count is held to where it stood; the
+    queued count to where the mix's own flows put it: with a service rate
+    (`completions_per_cycle`) under the arrivals the backlog grows by
+    `submits_per_cycle` - `completions_per_cycle` every cycle BY DESIGN, and
+    what is compared is how far it is from that.  The limit is 0 (exactly)
+    unless the mix states `stationary_slack_per_cycle` S, jobs a cycle: then it
+    is floor(S x the window's cycles after its first), for both counts, so a
+    window of faster cycles is held as tightly as one of slower ones."""
+    traffic = traffic or {}
     first, last = ((c.get("num_queued"), c.get("num_running")) for c in (window[0], window[-1]))
     moved = f"not stationary: queued, running {first} -> {last}"
     if None in first or None in last:
         return {}, moved
+    steps = len(window) - 1
+    grows = 0
+    if "completions_per_cycle" in traffic:
+        grows = int(traffic["submits_per_cycle"]) - int(traffic["completions_per_cycle"])
+    limit = math.floor(float(traffic.get("stationary_slack_per_cycle", 0)) * steps)
     out = {
-        name: {"value": abs(b - a), "limit": 0}
-        for name, a, b in zip(("queued_drift", "running_drift"), first, last)
+        "queued_drift": {"value": abs(last[0] - first[0] - grows * steps), "limit": limit},
+        "running_drift": {"value": abs(last[1] - first[1]), "limit": limit},
     }
-    return out, (moved if first != last else None)
+    if all(c["value"] <= limit for c in out.values()):
+        return out, None
+    return out, moved + f" over {steps} cycles, the backlog due to grow {grows} a cycle, limit {limit}"
 
 
 def verdict(run: Run, window: list, on_tpu: bool) -> tuple:
@@ -439,7 +482,7 @@ def verdict(run: Run, window: list, on_tpu: bool) -> tuple:
         )
     if not run.diag["warm_clean"]:
         problems.append("warm-up never reached three cycles in a row without a compile")
-    drifts, moved = drift(window)
+    drifts, moved = drift(window, run.traffic)
     if moved:
         problems.append(moved)
     checks.update(

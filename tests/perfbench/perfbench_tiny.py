@@ -26,7 +26,11 @@ def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3, fu
     `full` (a dict) makes it the tiny FULL-FLEET cell instead: the fleet
     filled by capacity with the first queues over their share, so that rounds
     preempt.  Its keys: `world` (updates of the world block, over
-    `running_fill` 1.0 and `running_queue_demand` "1/k")."""
+    `running_fill` 1.0 and `running_queue_demand` "1/k"), `traffic`
+    (updates of the mix: `completions_per_cycle`,
+    `stationary_slack_per_cycle`) and `scheduling` (updates of the scheduling
+    block, over `maxQueueLookback` 100000: a full fleet's rounds can give up,
+    and invariant 9 reads the lookback from the configuration alone)."""
     root = str(root)
     data = os.path.join(root, "perfbench")
     shutil.copytree(os.path.join(ROOT, "perfbench", "layers"), os.path.join(data, "layers"))
@@ -47,11 +51,13 @@ def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3, fu
             running_memory=8,
         )
         config["world"].update(full.get("world", {}))
+        config["scheduling"].update({"maxQueueLookback": 100_000}, **full.get("scheduling", {}))
     traffic = load("perfbench", "traffic", "steady-1k.json")
     traffic.update(
         name="steady-40", submits_per_cycle=burst, cap=burst,
         lifetime_cycles=lifetime, traced_cycles=2,
     )
+    traffic.update((full or {}).get("traffic", {}))
     extra = {
         "name": "downloads_per_cycle", "layer": "decode and apply", "unit": "count",
         "better": "lower", "moves": "cycle_p50_s", "source": "program_counter",
@@ -59,14 +65,14 @@ def make_tiny(root, nodes=120, queued=1500, running=60, burst=40, lifetime=3, fu
     }
     # a counter of the round's own stats JSON, read as a file and no code
     pooled = dict(
-        extra, name="preempted_per_cycle",
+        extra, name="preempted_max_per_cycle",
         read={"kind": "cycle_field", "field": "pool.preempted", "reduce": "max"},
     )
     for path, doc in (
         (os.path.join(data, "configs", "tiny.json"), config),
         (os.path.join(data, "traffic", "steady-40.json"), traffic),
         (os.path.join(data, "layers", "downloads_per_cycle.json"), extra),
-        (os.path.join(data, "layers", "preempted_per_cycle.json"), pooled),
+        (os.path.join(data, "layers", "preempted_max_per_cycle.json"), pooled),
     ):
         with open(path, "w", encoding="utf-8") as f:
             json.dump(doc, f)

@@ -1,5 +1,6 @@
 """The plain checker accepts an honest replay and rejects doctored answers."""
 
+import numpy as np
 import pytest
 
 from perfbench_tiny import load
@@ -266,3 +267,149 @@ def test_the_configuration_decides_which_class_is_preemptible():
     for n, r in enumerate(recs):
         c.cycle(n, r)
     assert sum("not preemptible" in v for v in c.violations) == 2
+
+
+# ---- invariant 9: a round that gives up has nothing left to place ----
+
+FULL_SIZES = {k: v for k, v in SIZES.items() if k != "running_jobs"}
+FULL_SIZES.update(running_fill=1.0, running_cpu_milli=[8000], running_memory=32, preemptible_share=1.0)
+
+
+def giving_up(world):
+    """Two rounds on a fleet that its initial runs fill exactly.  Round 0
+    preempts one preemptible initial run (8 cores, 32 of memory freed on its
+    node) and leases two 4-core jobs there: the node is full again.  Before
+    round 1 the client reports one of the two finished; round 1 leases one
+    4-core job into that room, preempts nothing, stays under its cap of 10 and
+    says `exhausted`: every node is full, so nothing is left that fits."""
+    victim = next(r for r, s in enumerate(world.run_shape) if world.run_shapes[s][2])
+    node = world.node_ids[world.run_node[victim]]
+    four = [i for i in range(400) if world.shapes[world.job_shape[i]][:2] == (4000, 9)][:3]
+    lease = lambda i: (world.job_id(i), node, world.queue_names[world.job_queue[i]])  # noqa: E731
+    n_runs = len(world.run_shape)
+    return [
+        dict(submitted=[], completed=[], leases=[lease(four[0]), lease(four[1])],
+             preempted=[f"r{victim:08d}"], termination="global_burst", num_queued=400, num_running=n_runs),
+        dict(submitted=[], completed=[four[0]], leases=[lease(four[2])], preempted=[],
+             termination="exhausted", num_queued=398, num_running=n_runs),
+    ], node
+
+
+def _lease_removed(w, recs):
+    recs[1]["leases"] = []  # the round gave up with four cores free on a node
+
+
+def _gave_up_and_preempted(w, recs):
+    _lease_removed(w, recs)
+    other = next(
+        r for r, s in enumerate(w.run_shape)
+        if w.run_shapes[s][2] and f"r{r:08d}" != recs[0]["preempted"][0]
+    )
+    recs[1]["preempted"] = [f"r{other:08d}"]  # the second pass may free room nothing retries
+
+
+def _stopped_at_its_cap(w, recs):
+    _lease_removed(w, recs)
+    recs[1]["termination"] = "global_burst"
+
+
+def _says_nothing(w, recs):
+    _lease_removed(w, recs)
+    del recs[1]["termination"]  # a program that reports no termination is not held to it
+
+
+@pytest.mark.parametrize(
+    "doctor,kwargs,reported",
+    [
+        (None, {}, False),  # the honest record
+        (_lease_removed, {}, True),
+        (_gave_up_and_preempted, {}, False),
+        (_stopped_at_its_cap, {}, False),
+        (_says_nothing, {}, False),
+        # a round that leased its cap did not give up, whatever it says (round 0 is over a cap of 0 too)
+        (_lease_removed, {"cap": 0}, False),
+        # no queue holds anything within a lookback of 0
+        (_lease_removed, {"lookback": 0}, False),
+    ],
+)
+def test_a_round_that_gives_up_is_held_to_nothing_left_fits(doctor, kwargs, reported):
+    w = World(FULL_SIZES, 3)
+    recs, node = giving_up(w)
+    if doctor:
+        doctor(w, recs)
+    c = Checker(w, priority_classes=CLASSES, **{"cap": 10, "queue_cap": 10, "lookback": 100_000, **kwargs})
+    for n, r in enumerate(recs):
+        c.cycle(n, r)
+    said = [v for v in c.violations if "while a job still fits" in v]
+    assert bool(said) == reported, c.violations
+    if reported:
+        # it names the round, a shape, the queue that holds it and the node with room
+        assert said[0].startswith("cycle 1:") and node in said[0] and "[4000, 23000] free" in said[0]
+        assert "500 cpu thousandths" in said[0] and c.bad_cycles == {1}
+    assert [v for v in c.violations if v not in said and "per-round cap" not in v] == []
+
+
+def test_a_round_that_gives_up_needs_the_configurations_lookback():
+    """Invariant 9 takes no default of the program's: where the configuration
+    states no `maxQueueLookback`, a round that gives up is reported for that,
+    honest or not, and a round that does not give up is not."""
+    w = World(FULL_SIZES, 3)
+    recs, _ = giving_up(w)
+    c = check(w, recs)
+    assert c.violations == ["cycle 1: the round gave up (exhausted) and the configuration states no "
+                            'maxQueueLookback: "nothing left fits" cannot be held']
+    assert c.bad_cycles == {1}
+    assert check(w, [recs[0], dict(recs[1], termination="global_burst")]).violations == []
+
+
+def test_only_what_a_queue_holds_within_its_lookback_counts():
+    """Room for a small job is a violation only if some queue holds a small
+    job among its first `lookback` queued jobs, in the queue's own order, at
+    the round's start; and a queue that leased its per-queue cap is out."""
+    w = World(FULL_SIZES, 3)
+    recs, node = giving_up(w)
+    # round 1 fills the four free cores but for 500 thousandths: only the smallest shape still fits
+    i_35 = [i for i in range(400) if w.shapes[w.job_shape[i]][0] in (2000, 1000, 500)]
+    by_cpu = {cpu: [i for i in i_35 if w.shapes[w.job_shape[i]][:2] == (cpu, mem)] for cpu, mem in ((2000, 5), (1000, 3), (500, 1))}
+    take = [by_cpu[2000][0], by_cpu[1000][0], by_cpu[500][0]]  # 3.5 cores, 9 of memory
+    recs[1]["leases"] = [(w.job_id(i), node, w.queue_names[w.job_queue[i]]) for i in take]
+
+    def violations(**kwargs):
+        c = Checker(w, priority_classes=CLASSES, **{"cap": 10, "queue_cap": 10, "lookback": 100_000, **kwargs})
+        for n, r in enumerate(recs):
+            c.cycle(n, r)
+        return c.violations
+
+    assert any("500 cpu thousandths" in v for v in violations())
+    # the queue's order is by submit time: how deep the first half-core job of any queue sits
+    depth = []
+    for q in range(4):
+        mine = np.flatnonzero(w.job_queue[:400] == q)
+        mine = mine[np.argsort(w.job_submit[mine], kind="stable")]
+        mine = [i for i in mine if w.job_id(i) not in {job_id for job_id, _, _ in recs[0]["leases"]}]
+        small = [n for n, i in enumerate(mine) if w.shapes[w.job_shape[i]][0] == 500 and i not in take]
+        depth.append(small[0])
+    assert min(depth) >= 1  # else the case below shows nothing
+    assert violations(lookback=min(depth)) == []  # no queue sees a half-core job that early
+    assert any("500 cpu thousandths" in v for v in violations(lookback=min(depth) + 1))
+
+
+def test_a_queue_at_its_per_queue_cap_holds_nothing_against_the_round():
+    """Round 1 leaves half a core free and leased one job from EACH queue:
+    under a per-queue cap of 1 no queue may lease more, so nothing is left to
+    place; under a cap of 2 the half-core jobs they all hold still fit."""
+    w = World(FULL_SIZES, 3)
+    recs, node = giving_up(w)
+    want = {0: (1000, 3), 1: (1000, 3), 2: (1000, 3), 3: (500, 1)}
+    take = [
+        next(i for i in range(400) if w.job_queue[i] == q and w.shapes[w.job_shape[i]][:2] == shape)
+        for q, shape in want.items()
+    ]
+    recs[1]["leases"] = [(w.job_id(i), node, w.queue_names[w.job_queue[i]]) for i in take]
+    for queue_cap, reported in ((1, False), (2, True)):
+        c = Checker(w, cap=10, queue_cap=10, priority_classes=CLASSES, lookback=100_000)
+        c.cycle(0, recs[0])
+        c.queue_cap = queue_cap  # round 0's two leases may share a queue: not this test's
+        c.cycle(1, recs[1])
+        assert [v for v in c.violations if "while a job still fits" not in v] == []
+        assert bool(c.violations) == reported, c.violations

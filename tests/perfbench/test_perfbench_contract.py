@@ -101,6 +101,53 @@ def test_cell_files_and_metrics(cell):
         assert doc["moves"] == m["moves"] and doc["source"] == m["source"]
 
 
+@pytest.mark.parametrize("mix", sorted(f[:-5] for f in os.listdir(os.path.join(ROOT, "perfbench", "traffic"))))
+def test_traffic_mix(mix):
+    """The keys the one generator reads, in every mix file there is, declared
+    or kept for later; and the optional two of a full fleet (PR 33): a service
+    rate under the arrivals, and how many jobs a cycle a count may be off."""
+    doc = load("perfbench", "traffic", mix + ".json")
+    assert doc["name"] == mix and doc["loop"] == "closed"
+    for key in ("submits_per_cycle", "lifetime_cycles", "cap", "traced_cycles"):
+        assert isinstance(doc[key], int) and doc[key] > 0, key
+    assert doc["logical_cycle_s"] > 0 and doc.get("min_warm_cycles", 0) >= 0
+    if "completions_per_cycle" in doc:
+        assert isinstance(doc["completions_per_cycle"], int)
+        assert 0 < doc["completions_per_cycle"] <= doc["submits_per_cycle"]
+    slack = doc.get("stationary_slack_per_cycle", 0)
+    assert 0 <= slack < doc["submits_per_cycle"]
+    # a mix that lets a count drift gives the two readings its slack lies between
+    assert not slack or doc["stationary_slack_per_cycle_why"]
+
+
+def test_no_shipped_mix_or_configuration_states_a_full_fleet_key():
+    """`steady-1k` is what it was: completions echo leases, the window stands
+    still exactly; no shipped configuration fills its fleet or has rounds that
+    give up (none states `maxQueueLookback`).  The rules of a full fleet are
+    named by the tests' tiny full fleet until a cell with a sourced running
+    set brings them (PERF.md section 7, row 1)."""
+    for mix in ("steady-1k", "bulk-5k"):
+        doc = load("perfbench", "traffic", mix + ".json")
+        assert "completions_per_cycle" not in doc and "stationary_slack_per_cycle" not in doc
+    names = sorted(f[:-5] for f in os.listdir(os.path.join(ROOT, "perfbench", "configs")))
+    assert names == ["cluster-100k-5k", "envelope-1m-50k", "tenants-925q-1m-50k"]
+    for name in names:
+        doc = load("perfbench", "configs", name + ".json")
+        assert "running_fill" not in doc["world"] and "running_jobs" in doc["world"]
+        assert "maxQueueLookback" not in doc["scheduling"]
+
+
+def test_the_three_round_counters_are_declared_for_every_cell():
+    """`exhausted_round_share`, `preempted_per_cycle`, `scheduled_per_cycle`:
+    a file and an entry each, layer "round kernel", no `workloads` list (every
+    cell's rounds report a termination and both counts)."""
+    entries = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in ("exhausted_round_share", "preempted_per_cycle", "scheduled_per_cycle"):
+        assert "workloads" not in entries[name] and entries[name]["layer"] == "round kernel"
+        assert entries[name]["moves"] == "cycle_p50_s" and entries[name]["source"] == "program_counter"
+        assert load("perfbench", "layers", name + ".json")["name"] == name
+
+
 def test_paths_hold_only_allowed_file_names():
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for p in BENCH["paths"]:

@@ -146,6 +146,56 @@ def test_cycle_field_reads_into_a_group_of_the_record(field, reduce, want):
     assert layers.read(spec, dict(CTX, cycles=POOL_CYCLES)) == want
 
 
+TERMINATIONS = [
+    {"termination": "exhausted", "pool": {"termination": "exhausted", "scheduled": 480}},
+    {"termination": "global_burst", "pool": {"termination": "global_burst", "scheduled": 1000}},
+    {"termination": "exhausted", "pool": {"termination": "exhausted", "scheduled": 520}},
+    {"termination": "exhausted", "pool": {"termination": "exhausted", "scheduled": 0}},
+    {"error": "UNAVAILABLE"},  # no response: no sample, neither a 0 nor a 1
+]
+
+
+@pytest.mark.parametrize(
+    "spec,want",
+    [
+        # the samples become 0 / 1 before the reduction
+        ({"field": "termination", "equals": "exhausted", "reduce": "mean"}, 0.75),
+        ({"field": "termination", "equals": "exhausted", "reduce": "sum"}, 3),
+        ({"field": "termination", "equals": "global_burst", "reduce": "mean"}, 0.25),
+        ({"field": "pool.termination", "equals": "max_iterations", "reduce": "max"}, 0),
+        ({"field": "pool.scheduled", "equals": 0, "reduce": "sum"}, 1),  # any value, not only a string
+        ({"field": "pool.no_such_key", "equals": "exhausted", "reduce": "mean"}, None),
+        # how many samples there are: a record without the field is none
+        ({"field": "termination", "reduce": "count"}, 4),
+        ({"field": "termination", "equals": "max_iterations", "reduce": "count"}, 4),
+        # without `equals`: the reader as it was
+        ({"field": "pool.scheduled", "reduce": "median"}, 500),
+    ],
+)
+def test_cycle_field_equals_counts_a_value(spec, want):
+    got = layers.read(dict(spec, kind="cycle_field"), dict(CTX, cycles=TERMINATIONS))
+    assert got == pytest.approx(want) if want is not None else got is None
+
+
+@pytest.mark.parametrize(
+    "name,cycles,want",
+    [
+        ("exhausted_round_share", TERMINATIONS, 75.0),
+        ("exhausted_round_share", POOL_CYCLES, None),  # a record without the field: left out, never 0
+        ("scheduled_per_cycle", TERMINATIONS, 500),
+        ("scheduled_per_cycle", POOL_CYCLES, 39),
+        ("preempted_per_cycle", POOL_CYCLES, 1.0),
+    ],
+)
+def test_the_round_kernel_counters_of_pr33_are_files(name, cycles, want):
+    from perfbench_tiny import load
+
+    doc = load("perfbench", "layers", name + ".json")
+    assert doc["layer"] == "round kernel" and doc["moves"] == "cycle_p50_s"
+    got = layers.read(doc["read"], dict(CTX, cycles=cycles))
+    assert got == pytest.approx(want) if want is not None else got is None
+
+
 def test_no_shipped_layer_file_reads_a_field_the_record_lacks(tmp_path):
     """Every counter a shipped layer file reads as a `cycle_field` is in a
     cycle's record as the runner builds it (the tiny cell's first round)."""

@@ -96,7 +96,7 @@ def test_window_is_stationary(tiny_run):
     # the round's own counts ride beside the lists
     assert all(c["scheduled"] == 40 and c["preempted"] == 0 for c in window)
     assert all(c["window_refills"] is not None and c["termination"] for c in window)
-    assert result_metric(record, "preempted_per_cycle") == 0  # a `pool.<key>` reader, added as a file
+    assert result_metric(record, "preempted_max_per_cycle") == 0  # a `pool.<key>` reader, added as a file
     assert record["books"] == {"live_leases": 3 * 40, "initial_runs_live": 60, "preempted_in_window": 0}
 
 
@@ -315,7 +315,10 @@ def test_full_fleet_cell_is_sound_and_preempts_in_the_window(full_run):
     window = [c for c in record["per_cycle"] if c["phase"] == "window"]
     assert record["books"]["preempted_in_window"] == sum(c["preempted"] for c in window) >= 1
     assert 0 < checks["running_drift"]["value"] <= record["books"]["preempted_in_window"]
-    assert result_metric(record, "preempted_per_cycle") == max(c["preempted"] for c in window)
+    assert result_metric(record, "preempted_max_per_cycle") == max(c["preempted"] for c in window)
+    assert result_metric(record, "preempted_per_cycle") == pytest.approx(
+        sum(c["preempted"] for c in window) / len(window)
+    )
     h = record["histograms"]
     assert h["node_fill_pct"][100] == 60 and h["running_jobs"] == 1035
     assert h["run_queue"][0] > 5 * h["run_queue"][-1]  # the first queues start over their share
@@ -372,10 +375,170 @@ def test_the_known_overfill_is_told_from_any_other_violation(tmp_path):
     assert not _is_the_known_overfill(w, both, [])
 
 
+# ---- the tiny full fleet under overload: `batch` arrivals, a service rate, a window that may drift ----
+
+OVERLOAD = dict(nodes=60, queued=1100, burst=40, lifetime=8)
+# 40 submits a cycle against 20 completions: the backlog is due to grow by 20 a
+# cycle, and either count may be 8 jobs a window cycle off.  The two readings
+# behind the 8 (CPU counts, this cell, seeds 3300000019-071, twelve of them):
+# sound windows of 16-18 cycles drift 0.06-3.62 running jobs a cycle; the
+# run's first 8 cycles, while the fleet still fills and finishes nothing
+# (`control.early_window`), 11.4-14.0 a cycle, and the backlog 20 a cycle
+# short of what the mix says it grows by.
+OVERLOAD_MIX = {"completions_per_cycle": 20, "min_warm_cycles": 20, "stationary_slack_per_cycle": 8}
+# Slabs of 4,096 rows put the next capacity step (a full upload and a recompile
+# of the round) a hundred cycles past the first completions.
+OVERLOAD_SCHEDULING = {"shapeBucket": 4096}
+
+
+def _overload(tmp_path):
+    full = _full("batch", traffic=OVERLOAD_MIX, scheduling=OVERLOAD_SCHEDULING)
+    return make_tiny(tmp_path, full=full, **OVERLOAD)
+
+
+@pytest.fixture(scope="module")
+def overload_run(tmp_path_factory):
+    """What `saturated.overload-1k` is at full size: the fleet full, every
+    arrival `batch`, 40 submits a cycle against 20 completions."""
+    root = tmp_path_factory.mktemp("overload")
+    bench = _overload(root)
+    out = os.path.join(root, "out")
+    p = _run_cli("--workload", "tiny.steady-40", "--seed", "3300000019", "--seconds", "1", "--trace", "1",
+                 "--allow-cpu", "--benchmark", bench, "--out", out)
+    assert p.returncode == 0, p.stderr[-2000:]
+    record = json.load(open(os.path.join(out, "tiny.steady-40.seed3300000019.trace1.0.json")))
+    return json.loads(p.stdout.strip().splitlines()[-1]), record, p.stderr
+
+
+def test_overloaded_full_fleet_is_correct_and_every_window_round_gives_up(overload_run):
+    """The cell PR 27 could not make `correct`: with a service rate the thin
+    rounds are not echoed, with `stationary_slack_per_cycle` each count may be
+    a stated number of jobs a cycle off, and every round that gives up passes invariant 9 (the
+    parent's program: nothing left fits)."""
+    result, record, stderr = overload_run
+    assert result["correct"] is True and result["failed"] == 0, record["problems"]
+    window = [c for c in record["per_cycle"] if c["phase"] == "window"]
+    assert len(window) >= 3 and record["setup"]["warm_cycles"] >= 21
+    assert all(c["termination"] == "exhausted" and c["leases"] < 40 for c in window)
+    assert all(c["completions"] == 20 and c["scheduled"] == c["leases"] for c in window)
+    assert result_metric(record, "exhausted_round_share") == 100.0
+    assert 0 < result_metric(record, "scheduled_per_cycle") < 40
+    assert result_metric(record, "preempted_per_cycle") == pytest.approx(
+        sum(c["preempted"] for c in window) / len(window)
+    )
+    # the walk: a round that gives up makes more trips than it leases jobs
+    assert all(c["kernel_iters"] > c["leases"] + 8 for c in window)
+    # each drift beside the limit it was held to: the mix's slack, times the window's cycles after its first
+    checks, steps = result["checks"], len(window) - 1
+    assert checks["queued_drift"]["limit"] == checks["running_drift"]["limit"] == 8 * steps
+    grew = window[-1]["num_queued"] - window[0]["num_queued"]
+    assert grew > 0 and checks["queued_drift"]["value"] == abs(grew - 20 * steps) <= 8 * steps
+    assert checks["running_drift"]["value"] == abs(window[-1]["num_running"] - window[0]["num_running"])
+    # nothing preempted, so what the backlog is short of its due is what the running set gained
+    if not record["books"]["preempted_in_window"]:
+        assert checks["queued_drift"]["value"] == checks["running_drift"]["value"]
+    assert f"perfbench check queued_drift: {checks['queued_drift']['value']} (limit {checks['queued_drift']['limit']})" in stderr
+    # the warm-up's first rounds preempt to make room; the books hold what the fleet has not finished
+    assert sum(c["preempted"] for c in record["per_cycle"][:8]) > 40
+    assert record["books"]["live_leases"] > 40
+
+
+def test_the_same_window_is_not_correct_without_the_key(overload_run):
+    """The accepted rule is pinned: the same recorded window under a mix that
+    states no `stationary_slack_per_cycle` is held to 0 and fails, for drift
+    alone: with no service rate either (a `steady-1k` mix: the backlog is due
+    to stand still), and with the service rate alone (due to grow by 20)."""
+    from perfbench.harness.runner import drift
+
+    _, record, _ = overload_run
+    window = [c for c in record["per_cycle"] if c["phase"] == "window"]
+    steps = len(window) - 1
+    grew = window[-1]["num_queued"] - window[0]["num_queued"]
+    checks, problem = drift(window)
+    assert problem.startswith("not stationary")
+    assert checks["queued_drift"] == {"value": grew, "limit": 0} and checks["running_drift"]["limit"] == 0
+    rate = {"submits_per_cycle": 40, "completions_per_cycle": 20}
+    checks, problem = drift(window, rate)
+    assert problem.startswith("not stationary")
+    assert checks["queued_drift"] == {"value": abs(grew - 20 * steps), "limit": 0}
+    assert drift(window, dict(rate, stationary_slack_per_cycle=8))[1] is None
+    assert record["problems"] == []
+
+
+def _overload_args(tmp_path, seed):
+    # untraced tiny cycles take ~0.03 s here and the backlog grows by 20 a cycle: a short window
+    return ["--workload", "tiny.steady-40", "--seed", str(seed), "--seconds", "0.3", "--trace", "0", "--allow-cpu",
+            "--benchmark", _overload(tmp_path),
+            "--out", str(tmp_path / "out")]
+
+
+def test_the_control_of_invariant_9_fails_a_run_that_is_correct(tmp_path, capsys):
+    """`control.py` (what the chip runs at a cell's own size): the run is
+    `correct`; the same record with one lease taken out of the last round
+    that gave up is reported, by invariant 9 and by nothing else; and the
+    run's first cycles, while the fleet still fills and finishes nothing, are
+    a window that does not stand still: both counts over their limit."""
+    import control
+
+    assert control.main(_overload_args(tmp_path, 3300000023)) == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-2])["correct"] is True
+    said = json.loads(lines[-1].removeprefix("perfbench control "))
+    assert said["run_correct"] is True and said["honest_violations"] == 0 and said["doctored_violations"] == 1
+    assert said["reported_by_invariant_9"] is True and said["rounds_that_gave_up"] >= 10
+    assert said["first"].startswith(f"cycle {said['round_doctored']}: the round gave up")
+    early = said["early_window"]
+    assert early["reported"] is True and 3 <= early["cycles"] <= 9
+    steps = early["cycles"] - 1
+    # the fleet finishes nothing yet, so the backlog is 20 a cycle short of its due; the running set gains 11-14 a cycle
+    assert early["checks"]["queued_drift"] == {"value": 20 * steps, "limit": 8 * steps}
+    assert early["checks"]["running_drift"]["value"] > 10 * steps and early["checks"]["running_drift"]["limit"] == 8 * steps
+
+
+def test_a_lease_dropped_where_it_is_produced_makes_the_run_incorrect(tmp_path, monkeypatch):
+    """The rest of a run with the timed path broken underneath, on the full
+    fleet: once the window is near, the client's `ScheduleRound` call hands
+    back every round that stopped short of its cap with its first lease taken
+    out.  The round then gave up while that job still fitted (invariant 9),
+    and the scheduler's own counts say it leased one more than it told
+    (invariant 5): `correct` comes out false."""
+    import argparse
+    import time
+
+    from perfbench.harness import runner
+
+    class Dropping(runner.Wire):
+        def __init__(self, port):
+            super().__init__(port)
+            honest, rounds = self.round, []
+
+            def round_(req):
+                resp = honest(req)
+                rounds.append(len(resp.scheduled))
+                if len(rounds) > 18 and 0 < len(resp.scheduled) < 40:
+                    del resp.scheduled[0]
+                return resp
+
+            self.round = round_
+
+    monkeypatch.setattr(runner, "Wire", Dropping)
+    argv = _overload_args(tmp_path, 3300000029)
+    args = argparse.Namespace(
+        benchmark=argv[argv.index("--benchmark") + 1], workload="tiny.steady-40", seed=3300000029, seconds=0.3,
+        trace=0, out=str(tmp_path / "out"), allow_cpu=True, keep_trace=False,
+    )
+    code, result = runner.run_cell(args, time.time())
+    assert code == 0 and result["correct"] is False and result["failed"] >= 1
+    record = json.load(open(tmp_path / "out" / "tiny.steady-40.seed3300000029.trace0.0.json"))
+    assert any("while a job still fits" in p for p in record["problems"]), record["problems"]
+    assert any("scheduler counts" in p for p in record["problems"]), record["problems"]
+
+
 # ---- the client's books, without a plane ----
 
 
-def test_a_preempted_job_leaves_the_books(tmp_path):
+def _books(tmp_path, seed=5, **mix):
+    """A Run with a world and the wire's message types, and no plane."""
     from armada_tpu.rpc import rpc_pb2 as pb
 
     from perfbench.harness.cell import Cell
@@ -383,13 +546,26 @@ def test_a_preempted_job_leaves_the_books(tmp_path):
     from perfbench.harness.world import World
 
     cell = Cell(make_tiny(tmp_path, lifetime=2), "tiny.steady-40")
-    run = Run(cell, 5, 1.0, False)
-    run.world = World(cell.config["world"], 5)
+    cell.traffic.update(mix)
+    run = Run(cell, seed, 1.0, False)
+    run.world = World(cell.config["world"], seed)
     run.wire = type("Wire", (), {"pb": pb})()
     run.sid = "books"
-    lease = lambda i: pb.RoundLease(job_id=run.world.job_id(i), run_id=f"run-{i}", node_id="n000001")  # noqa: E731
-    run.leased[0] = {i: lease(i) for i in (3, 4, 5)}
-    run.leased_at.update({3: 0, 4: 0, 5: 0})
+
+    def lease(k, numbers):
+        run.leased[k] = {
+            i: pb.RoundLease(job_id=run.world.job_id(i), run_id=f"run-{i}", node_id=f"n{i % 7:06d}",
+                             executor="ex0", pool="default", scheduled_at_priority=100)
+            for i in numbers
+        }
+        run.leased_at.update(dict.fromkeys(numbers, k))
+
+    return run, lease
+
+
+def test_a_preempted_job_leaves_the_books(tmp_path):
+    run, lease = _books(tmp_path)
+    lease(0, [3, 4, 5])
     for job_id in (run.world.job_id(4), "r00000007", "r99999999", "j999999999", run.world.job_id(4)):
         run.forget(job_id)  # unknown ids and a second time are the checker's to report
     assert list(run.leased[0]) == [3, 5] and run.leased_at == {3: 0, 5: 0}
@@ -402,28 +578,130 @@ def test_a_preempted_job_leaves_the_books(tmp_path):
     assert run.leased == {} and run.leased_at == {}
 
 
+def test_a_service_rate_finishes_the_oldest_live_leases(tmp_path):
+    """`completions_per_cycle` K: from cycle `lifetime` on, the K oldest live
+    leases finish (by the cycle that leased them, then by their place in that
+    round's response), whatever one round leased; a preempted job is not among
+    them; fewer than K live: all of them."""
+    run, lease = _books(tmp_path, completions_per_cycle=4)
+    lease(0, [9, 3, 7])  # the response's order, not the numbers'
+    lease(1, [5])  # a thin round
+    lease(2, [12, 11, 10, 8, 6])
+    run.forget(run.world.job_id(3))  # preempted since
+    run.k = 1
+    req, _, completed = run.prepare(1)  # before cycle `lifetime` nothing finishes
+    assert completed == [] and not [m for m in req.jobs if m.terminal]
+    run.k = 2
+    req, submitted, completed = run.prepare(2)
+    assert completed == [9, 7, 5, 12] and len(submitted) == 40
+    terminal = [m for m in req.jobs if m.terminal]
+    assert [m.job_id for m in terminal] == [run.world.job_id(i) for i in completed]
+    # each ran from the cycle that leased it
+    step = run.step_ns
+    assert [m.run.running_ns for m in terminal] == [10**12 + (at + 1) * step for at in (0, 0, 1, 2)]
+    assert set(run.leased) == {2} and list(run.leased[2]) == [11, 10, 8, 6]
+    assert run.leased_at == {11: 2, 10: 2, 8: 2, 6: 2}
+    run.k = 3
+    lease(3, [20])
+    assert run.prepare(3)[2] == [11, 10, 8, 6]
+    run.k = 4
+    assert run.prepare(4)[2] == [20] and run.leased == {} and run.leased_at == {}  # fewer than K are live
+    run.k = 5
+    assert run.prepare(5)[2] == []
+
+
+def test_without_the_key_the_request_is_byte_for_byte_what_it_was(tmp_path):
+    """No `completions_per_cycle`: `prepare` builds the request the accepted
+    harness built: the cycle's submits, then the terminal state of every job
+    leased `lifetime` cycles earlier, in the response's order, each running
+    from that cycle (the rule of the parent's `prepare`, written out here)."""
+    run, lease = _books(tmp_path, seed=3000000019)
+    assert "completions_per_cycle" not in run.cell.traffic and run.service_rate is None
+    lease(3, [9, 3, 7])
+    lease(4, [5, 40, 2])
+    lease(5, [12])
+    run.forget(run.world.job_id(40))
+    run.k = 5
+    run.prebuild(2)
+    want = []
+    for k in (5, 6):
+        req, numbers = run.requests[k]
+        expect = type(req)()
+        expect.CopyFrom(req)
+        ran_from = 10**12 + (k - run.lifetime + 1) * run.step_ns
+        done = {5: [9, 3, 7], 6: [5, 2]}[k]
+        for i in done:
+            expect.jobs.append(run.world.terminal_state(i, run.leased[k - 2][i], ran_from))
+        want.append((expect.SerializeToString(deterministic=True), list(numbers), done))
+    for k, (wire, numbers, done) in zip((5, 6), want):
+        run.k = k
+        req, submitted, completed = run.prepare(k)
+        assert (req.SerializeToString(deterministic=True), submitted, completed) == (wire, numbers, done)
+    assert set(run.leased) == {5} and run.leased_at == {12: 5}
+
+
 # ---- stationarity ----
 
 
+RATE = {"submits_per_cycle": 1000, "completions_per_cycle": 500}  # the backlog is due to grow by 500 a cycle
+SLACK = dict(RATE, stationary_slack_per_cycle=2.5)  # over the 2 cycles after the first: limit 5, both counts
+
+
 @pytest.mark.parametrize(
-    "last,ok", [((1000, 200), True), ((1000, 201), False), ((999, 200), False), ((1020, 204), False)]
+    "last,traffic,ok",
+    [
+        # no `stationary_slack_per_cycle`: the accepted rule, exactly
+        ((1000, 200), None, True), ((1000, 201), None, False), ((999, 200), None, False),
+        ((1020, 204), None, False),
+        # a service rate alone: the backlog is held to what the flows say, exactly
+        ((2000, 200), RATE, True), ((2001, 200), RATE, False), ((1000, 200), RATE, False),
+        # a slack a cycle, times the cycles after the first, rounded down; either way; limit + 1
+        ((2005, 205), SLACK, True), ((1995, 195), SLACK, True),
+        ((2006, 200), SLACK, False), ((1994, 200), SLACK, False),
+        ((2000, 206), SLACK, False), ((2000, 194), SLACK, False),
+        ((1000, 200), SLACK, False),  # a backlog that stands still where it is due to grow
+        ((1005, 205), {"stationary_slack_per_cycle": 2.5}, True),  # without a service rate it is due to stand
+        ((1006, 200), {"stationary_slack_per_cycle": 2.5}, False),
+    ],
 )
-def test_the_window_stands_still(last, ok):
+def test_the_window_stands_still(last, traffic, ok):
+    import math
+
     from perfbench.harness.runner import drift
 
     window = [{"num_queued": 1000, "num_running": 200}, {"num_queued": 5, "num_running": 5},
               {"num_queued": last[0], "num_running": last[1]}]
-    checks, problem = drift(window)
+    checks, problem = drift(window, traffic)
     assert (problem is None) == ok, problem
-    assert checks["queued_drift"] == {"value": abs(last[0] - 1000), "limit": 0}
-    assert checks["running_drift"] == {"value": abs(last[1] - 200), "limit": 0}
+    traffic = traffic or {}
+    due = 2 * 500 if "completions_per_cycle" in traffic else 0
+    limit = math.floor(2 * traffic.get("stationary_slack_per_cycle", 0))
+    assert checks["queued_drift"] == {"value": abs(last[0] - 1000 - due), "limit": limit}
+    assert checks["running_drift"] == {"value": abs(last[1] - 200), "limit": limit}
+    assert traffic or drift(window) == (checks, problem)  # the argument may be left out
+
+
+def test_a_window_of_faster_cycles_is_held_as_tightly():
+    """The limit follows the window's cycles, not its seconds nor the counts:
+    twice the cycles, twice the room, at any size of backlog."""
+    from perfbench.harness.runner import drift
+
+    def window(cycles, queued):
+        return [{"num_queued": queued + 500 * k, "num_running": 300 + 3 * k} for k in range(cycles)]
+
+    for queued in (1_000, 1_000_000):
+        for cycles, ok in ((11, True), (41, True)):
+            checks, problem = drift(window(cycles, queued), dict(RATE, stationary_slack_per_cycle=3))
+            assert (problem is None) == ok and checks["running_drift"] == {"value": 3 * (cycles - 1), "limit": 3 * (cycles - 1)}
+        assert drift(window(11, queued), dict(RATE, stationary_slack_per_cycle=2.9))[1] is not None
 
 
 def test_stationary_needs_the_counts():
     from perfbench.harness.runner import drift
 
-    checks, problem = drift([{"num_queued": 1, "num_running": 1}, {"error": "UNAVAILABLE"}])
-    assert checks == {} and "not stationary" in problem
+    for traffic in (None, SLACK):
+        checks, problem = drift([{"num_queued": 1, "num_running": 1}, {"error": "UNAVAILABLE"}], traffic)
+        assert checks == {} and "not stationary" in problem
 
 
 # ---- the rest of a run, with the timed path broken underneath ----

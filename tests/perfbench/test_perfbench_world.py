@@ -134,6 +134,31 @@ def test_bad_fill_keys_are_refused(sizes):
         World(sizes, 1)
 
 
+@pytest.mark.parametrize("seed", [7, 3_300_000_019])
+def test_the_envelope_filled_by_eight_core_runs(seed):
+    """The full fleet PR 33 measured and did not declare (PERF.md section 7,
+    row 1), at a hundredth: the envelope's world with runs of 8 cores and 32
+    of memory filling every node to the last core and unit of memory, 26 runs
+    a four nodes (325,000 at 50,000 nodes), 2 % of them preemptible, 13 of 64
+    queues holding two thirds; every queued job `batch`."""
+    sizes = dict(load("perfbench", "configs", "envelope-1m-50k.json")["world"], nodes=500, queued_jobs=10_000)
+    del sizes["running_jobs"]
+    sizes.update(running_fill=1.0, running_queue_demand="1/k", running_cpu_milli=[8000], running_memory=32,
+                 running_preemptible_share=0.02, preemptible_share=1.0)
+    w = World(sizes, seed)
+    h = w.histograms()
+    assert h["running_jobs"] == 500 // 4 * (2 + 4 + 8 + 12) == 3250
+    assert h["node_fill_pct"][100] == 500 and h["runs_per_node"][2::2][:6] == [125, 125, 0, 125, 0, 125]
+    used = np.zeros_like(w.node_total)
+    np.add.at(used, w.run_node, w.run_shape_req[w.run_shape])
+    assert (used == w.node_total).all()  # memory too: 32 a run, 4 a core
+    assert sum(c for c, s in zip(h["run_shape"], w.run_shapes) if s[2]) == 65  # 2 %
+    assert 0.66 < sum(h["run_queue"][:13]) / 3250 < 0.68
+    assert all(w.shapes[s][2] for s in np.unique(w.job_shape)) and len(np.unique(w.job_shape)) == 12
+    later = w.extend(1000, 1.0)  # a cycle's arrivals
+    assert {w.class_name(w.shapes[s][2]) for s in w.job_shape[later.start:later.stop]} == {"batch"}
+
+
 def test_initial_runs_are_known_by_id():
     w = World(SIZES, 5)
     assert w.run_number("r00000017") == 17
