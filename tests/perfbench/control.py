@@ -20,8 +20,9 @@ gave up, so no later round's counts are there to disagree), and both drifts
 of the early window beside their limits (one has to be over).  Exit code 0
 only if the run is `correct` and every control comes out not correct.
 `tests/perfbench/test_perfbench_run.py` runs it at tiny size, on the tests'
-full fleet; no declared cell has a round that gives up yet, and the
-benchmark's own runs never run it.
+full fleet; on the chip it runs at the size of whatever cell `--workload`
+names, once one whose rounds give up is declared (perfbench/README.md, "A
+full fleet"); the benchmark's own runs never run it.
 """
 
 from __future__ import annotations

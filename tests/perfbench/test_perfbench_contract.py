@@ -120,21 +120,122 @@ def test_traffic_mix(mix):
     assert not slack or doc["stationary_slack_per_cycle_why"]
 
 
-def test_no_shipped_mix_or_configuration_states_a_full_fleet_key():
-    """`steady-1k` is what it was: completions echo leases, the window stands
-    still exactly; no shipped configuration fills its fleet or has rounds that
-    give up (none states `maxQueueLookback`).  The rules of a full fleet are
-    named by the tests' tiny full fleet until a cell with a sourced running
-    set brings them (PERF.md section 7, row 1)."""
-    for mix in ("steady-1k", "bulk-5k"):
-        doc = load("perfbench", "traffic", mix + ".json")
+ACCEPTED_CONFIGS = ("cluster-100k-5k", "envelope-1m-50k", "tenants-925q-1m-50k")
+ACCEPTED_MIXES = ("steady-1k", "bulk-5k")
+
+
+@pytest.mark.parametrize("name", ACCEPTED_CONFIGS + ACCEPTED_MIXES)
+def test_the_accepted_files_are_what_they_were(name):
+    """By NAME, not by listing a directory: a sixth file beside them fails
+    nothing.  `steady-1k` and `bulk-5k`: completions echo leases and the window
+    stands still exactly; the three configurations of PR 24 and PR 28 spread
+    `running_jobs` over the fleet, fill nothing and state no lookback (none of
+    their rounds gives up)."""
+    if name in ACCEPTED_MIXES:
+        doc = load("perfbench", "traffic", name + ".json")
         assert "completions_per_cycle" not in doc and "stationary_slack_per_cycle" not in doc
-    names = sorted(f[:-5] for f in os.listdir(os.path.join(ROOT, "perfbench", "configs")))
-    assert names == ["cluster-100k-5k", "envelope-1m-50k", "tenants-925q-1m-50k"]
-    for name in names:
+    else:
         doc = load("perfbench", "configs", name + ".json")
         assert "running_fill" not in doc["world"] and "running_jobs" in doc["world"]
         assert "maxQueueLookback" not in doc["scheduling"]
+
+
+def full_fleet_problems(configs, mixes, bench):
+    """What is missing where a full fleet is declared, as a list of sentences
+    (perfbench/README.md, "A full fleet").  `configs` and `mixes` are the files
+    of `perfbench/configs/` and `perfbench/traffic/` by name; `bench` is
+    BENCHMARK.json.  Structure only, cell by cell: a configuration whose
+    `world` has `running_fill` states `maxQueueLookback` (invariant 9 reads it
+    from the configuration alone) and derives its running set (no
+    `running_jobs`), and a cell of it runs a mix with a service rate UNDER its
+    arrivals and a slack with the two readings it lies between.  Which classes
+    arrive, and which fleets a mix with a service rate may run on, are not
+    rules of form: `correct` decides them, on the chip."""
+    out = []
+    file_of = {c["name"]: c["file"].rsplit("/", 1)[-1][:-5] for c in bench["configs"]}
+    for cell in bench["workloads"]:
+        config, mix = configs[file_of[cell["config"]]], mixes[cell["traffic"]]
+        if "running_fill" not in config["world"]:
+            continue
+        lookback = config["scheduling"].get("maxQueueLookback")
+        if not (isinstance(lookback, int) and lookback > 0):
+            out.append(f"{cell['name']}: a full fleet's rounds can give up, and invariant 9 needs `maxQueueLookback` stated")
+        if "running_jobs" in config["world"]:
+            out.append(f"{cell['name']}: `running_fill` derives the running set: no `running_jobs` beside it")
+        if not 0 < mix.get("completions_per_cycle", 0) < mix["submits_per_cycle"]:
+            out.append(f"{cell['name']}: a full fleet needs a service rate under the arrivals (`completions_per_cycle`)")
+        if not (mix.get("stationary_slack_per_cycle", 0) > 0 and mix.get("stationary_slack_per_cycle_why")):
+            out.append(f"{cell['name']}: a full fleet needs `stationary_slack_per_cycle` and its `_why`")
+    return out
+
+
+def _shipped(kind):
+    d = os.path.join(ROOT, "perfbench", kind)
+    return {f[:-5]: load("perfbench", kind, f) for f in sorted(os.listdir(d)) if f.endswith(".json")}
+
+
+def test_a_full_fleet_is_declared_whole():
+    """Over every workload of BENCHMARK.json, whoever added it: the contract
+    of a full fleet that perfbench/README.md states.  No cell declares one yet
+    (PERF.md section 7, row 1), so the scratch cases below are what shows the
+    rule at work."""
+    assert full_fleet_problems(_shipped("configs"), _shipped("traffic"), BENCH) == []
+
+
+# a full fleet and its mix as a later PR would add them, in the fewest keys
+# (the tests' tiny full fleet, perfbench_tiny.py, runs the same keys)
+FULL = {"running_fill": 1.0, "running_jobs": None, "scheduling": {"maxQueueLookback": 100000}}
+OVERLOAD = {"completions_per_cycle": 500, "stationary_slack_per_cycle": 50,
+            "stationary_slack_per_cycle_why": "between a sound window's reading and the filling phase's"}
+
+
+def _updated(doc, keys):
+    for key, value in keys.items():
+        if value is None:
+            doc.pop(key, None)
+        else:
+            doc[key] = value
+    return doc
+
+
+def _scratch(mix=(), scheduling=(), **world):
+    """The shipped files with a scratch configuration beside them (a copy of
+    `cluster-100k-5k`, its `world` and `scheduling` updated; None removes a
+    key) and a scratch cell that runs it under `steady-1k` updated by `mix`."""
+    import copy
+
+    configs, mixes, bench = _shipped("configs"), _shipped("traffic"), copy.deepcopy(BENCH)
+    configs["scratch"] = copy.deepcopy(configs["cluster-100k-5k"])
+    _updated(configs["scratch"]["world"], world)
+    _updated(configs["scratch"]["scheduling"], dict(scheduling))
+    mixes["scratch-mix"] = _updated(copy.deepcopy(mixes["steady-1k"]), dict(mix))
+    bench["configs"].append({"name": "scratch", "file": "perfbench/configs/scratch.json"})
+    bench["workloads"].append({"name": "scratch.cell", "config": "scratch", "traffic": "scratch-mix"})
+    return configs, mixes, bench
+
+
+@pytest.mark.parametrize(
+    "scratch,says",
+    [
+        (dict(), None),  # one more configuration and cell that break no rule fail nothing
+        (dict(FULL, mix=OVERLOAD), None),  # nor does a full fleet, declared whole
+        (dict(FULL, mix=OVERLOAD, preemptible_share=0.7), None),  # whatever arrives: `correct` decides that
+        (dict(mix=OVERLOAD), None),  # nor a mix with a service rate on a fleet that is not full
+        (dict(FULL, mix=OVERLOAD, scheduling={}), "maxQueueLookback"),
+        (dict(FULL, mix=OVERLOAD, running_jobs=2500), "no `running_jobs`"),
+        (dict(FULL), "a service rate under the arrivals"),  # run by `steady-1k` as it is
+        (dict(FULL, mix=dict(OVERLOAD, completions_per_cycle=1000)), "a service rate under the arrivals"),
+        (dict(FULL, mix=dict(OVERLOAD, stationary_slack_per_cycle_why=None)), "and its `_why`"),
+    ],
+    ids=["a-fourth-configuration", "a-full-fleet", "prod-arrivals", "a-service-rate-on-an-empty-fleet", "no-lookback",
+         "running-jobs-too", "run-by-steady-1k", "service-rate-not-under-arrivals", "slack-without-its-why"],
+)
+def test_the_full_fleet_rule_on_a_scratch_configuration(scratch, says):
+    problems = [p for p in full_fleet_problems(*_scratch(**scratch)) if p.startswith("scratch.cell: ")]
+    if says is None:
+        assert problems == []
+    else:
+        assert any(says in p for p in problems), problems
 
 
 def test_the_three_round_counters_are_declared_for_every_cell():

@@ -8,10 +8,10 @@ import os
 import re
 import subprocess
 import sys
-import tempfile
 
 import pytest
 
+from oracle_rounds import kinds as _kinds  # a preempted set up to which of two interchangeable jobs went
 from perfbench_tiny import ROOT, RUN, make_tiny
 
 
@@ -119,58 +119,17 @@ def test_unknown_workload_is_refused(tmp_path):
 
 def _first_round_and_oracle(cell, seed, more_cycles=0):
     """One round of the harness over the wire, and the same world through the
-    repo's independent sequential oracle (tests/test_parity_full.py); then
+    repo's independent sequential oracle (tests/test_parity_full.py, by way of
+    `oracle_rounds.py`, which does the same at a cell's own size); then
     `more_cycles` further cycles.  Returns (run, records, the oracle's
     scheduled {job id: node id}, the oracle's preempted ids)."""
-    import test_parity_full as parity  # tests/ is on sys.path (rootdir conftest)
+    import oracle_rounds
 
-    from armada_tpu.core.config import scheduling_config_from_dict
-    from armada_tpu.core.types import JobSpec, NodeSpec, Queue, RunningJob
-
-    from perfbench.harness.runner import Run
-
-    run = Run(cell, seed, 1.0, False)
-    with tempfile.TemporaryDirectory() as data_dir:
-        run.start(data_dir)
-        try:
-            run.load_mirror()
-            records = [run.cycle() for _ in range(1 + more_cycles)]
-        finally:
-            run.stop()
+    run, records = oracle_rounds.serve(cell, seed, 1 + more_cycles)
     w = run.world
-    cfg = scheduling_config_from_dict(cell.scheduling())
-    f = cfg.resource_list_factory()
-    rl = lambda cpu, mem: f.from_mapping({"cpu": f"{cpu}m", "memory": str(mem)})  # noqa: E731
-    nodes = [
-        NodeSpec(id=w.node_ids[i], pool="default",
-                 total_resources=rl(int(c) * 1000, int(c) * int(w.sizes["memory_per_core"])))
-        for i, c in enumerate(w.node_cores)
-    ]
-    queues = [Queue(q, 1.0) for q in w.queue_names]
-    first = int(w.sizes["queued_jobs"]) + int(cell.traffic["submits_per_cycle"])
-    jobs = [
-        JobSpec(
-            id=w.job_id(i), queue=w.queue_names[w.job_queue[i]],
-            priority_class=w.class_name(w.shapes[w.job_shape[i]][2]),
-            submit_time=float(w.job_submit[i]),
-            resources=rl(w.shapes[w.job_shape[i]][0], w.shapes[w.job_shape[i]][1]),
-        )
-        for i in range(first)  # the backlog and cycle 0's submits
-    ]
-    running = [
-        RunningJob(
-            job=JobSpec(
-                id=f"r{i:08d}", queue=w.queue_names[w.run_queue[i]],
-                priority_class=w.class_name(w.run_shapes[w.run_shape[i]][2]),
-                submit_time=-1.0,
-                resources=rl(w.run_shapes[w.run_shape[i]][0], w.run_shapes[w.run_shape[i]][1]),
-            ),
-            node_id=w.node_ids[w.run_node[i]],
-        )
-        for i in range(len(w.run_shape))
-    ]
-    o_sched, o_preempted, _ = parity._Oracle(cfg, nodes, queues, jobs, running).run()
-    return run, records, dict(o_sched), set(o_preempted)
+    first = int(w.sizes["queued_jobs"]) + int(cell.traffic["submits_per_cycle"])  # the backlog and cycle 0's submits
+    o_sched, o_preempted = oracle_rounds.oracle_sets(cell, w, range(first), {}, range(len(w.run_shape)))
+    return run, records, o_sched, o_preempted
 
 
 def test_first_round_agrees_with_the_sequential_oracle(tmp_path):
@@ -202,21 +161,6 @@ OVERFULL = (
 
 def _full(arrivals, **more):
     return dict(world={"preemptible_share": ARRIVALS[arrivals]}, **more)
-
-
-def _kinds(world, job_ids):
-    """The multiset of (queue, cpu, memory, preemptible) of `job_ids`, backlog
-    jobs and initial runs alike: what a preempted set is, up to which of two
-    interchangeable jobs on tied nodes was taken."""
-    out = []
-    for job_id in job_ids:
-        if job_id.startswith("r"):
-            i = world.run_number(job_id)
-            out.append((int(world.run_queue[i]), *world.run_shapes[world.run_shape[i]]))
-        else:
-            i = world.job_number(job_id)
-            out.append((int(world.job_queue[i]), *world.shapes[world.job_shape[i]]))
-    return sorted(out)
 
 
 def _is_the_known_overfill(world, cycles, violations):
@@ -391,15 +335,17 @@ OVERLOAD_MIX = {"completions_per_cycle": 20, "min_warm_cycles": 20, "stationary_
 OVERLOAD_SCHEDULING = {"shapeBucket": 4096}
 
 
-def _overload(tmp_path):
+def _overload(tmp_path, running_preemptible_share=None):
     full = _full("batch", traffic=OVERLOAD_MIX, scheduling=OVERLOAD_SCHEDULING)
+    if running_preemptible_share is not None:
+        full["world"]["running_preemptible_share"] = running_preemptible_share
     return make_tiny(tmp_path, full=full, **OVERLOAD)
 
 
 @pytest.fixture(scope="module")
 def overload_run(tmp_path_factory):
-    """What `saturated.overload-1k` is at full size: the fleet full, every
-    arrival `batch`, 40 submits a cycle against 20 completions."""
+    """A full fleet under overload, tiny: the fleet full, every arrival
+    `batch`, 40 submits a cycle against 20 completions."""
     root = tmp_path_factory.mktemp("overload")
     bench = _overload(root)
     out = os.path.join(root, "out")
@@ -493,6 +439,44 @@ def test_the_control_of_invariant_9_fails_a_run_that_is_correct(tmp_path, capsys
     # the fleet finishes nothing yet, so the backlog is 20 a cycle short of its due; the running set gains 11-14 a cycle
     assert early["checks"]["queued_drift"] == {"value": 20 * steps, "limit": 8 * steps}
     assert early["checks"]["running_drift"]["value"] > 10 * steps and early["checks"]["running_drift"]["limit"] == 8 * steps
+
+
+def test_the_first_rounds_against_the_oracle_served_and_again_from_the_dump(tmp_path, capsys):
+    """`oracle_rounds.py` (what the chip runs at a cell's own size, outside any
+    window): the first rounds of the tiny full fleet, 0.3 of its runs
+    preemptible, pass the checker; round 0 leases the oracle's jobs and
+    preempts the oracle's kinds; the first round that gave up is compared next
+    (and only reported: there the two sides may part, PERF.md section 7); and
+    the dump alone, with the world again from the seed, gives the same lines."""
+    import oracle_rounds
+
+    bench, out = _overload(tmp_path, 0.3), str(tmp_path / "out")
+    argv = ["--workload", "tiny.steady-40", "--seed", "3500000001", "--benchmark", bench, "--out", out]
+    assert oracle_rounds.main(argv + ["--rounds", "9", "--allow-cpu"]) == 0
+
+    def said():
+        lines = [x for x in capsys.readouterr().out.splitlines() if x.startswith("perfbench oracle ")]
+        rounds = [json.loads(x.removeprefix("perfbench oracle round ")) for x in lines[:-1]]
+        for r in rounds:
+            r.pop("oracle_s")
+        return rounds, json.loads(lines[-1].removeprefix("perfbench oracle "))
+
+    rounds, last = said()
+    assert last["violations"] == 0 and last["round_0_agrees"] is True and last["rounds_served"] == 9
+    assert last["gave_up"] and last["rounds_compared"][:2] == [0, last["gave_up"][0]]
+    first = rounds[0]
+    assert first["leases"] == first["oracle_leases"] == 40 and first["same_jobs"] and first["served_not_oracle"] == 0
+    assert first["preempted"] == first["oracle_preempted"] > 0 and first["same_preempted_kinds"]
+    gave_up = rounds[1]
+    assert gave_up["gave_up"] and gave_up["termination"] == "exhausted" and gave_up["leases"] < 40
+    dump = os.path.join(out, "oracle_rounds.tiny.steady-40.seed3500000001.json")
+    assert oracle_rounds.main(argv + ["--from-dump", dump]) == 0  # no device asked for
+    assert said() == (rounds, last)
+    # the third way, the program's own from-scratch round from each round's
+    # state: on the backend that served them, the served rounds to a job
+    for r in rounds:
+        assert r["program_backend"] == "cpu" and r["served_not_program"] == r["program_not_served"] == 0, r
+        assert r["program_leases"] == r["leases"] == sum(c[0] for c in r["leases_by_shape_served_oracle_program"].values())
 
 
 def test_a_lease_dropped_where_it_is_produced_makes_the_run_incorrect(tmp_path, monkeypatch):
