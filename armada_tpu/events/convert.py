@@ -145,3 +145,49 @@ def job_spec_from_proto(
         if len(msg.node_type_scores)
         else (),
     )
+
+
+class SpecTemplates:
+    """What `job_spec_from_proto` makes of a `spec` sub-message, interned by
+    the message's bytes.
+
+    A batch of JobStates carries a handful of distinct specs (a job set is
+    thousands of jobs from one pod template, and a mirroring caller re-sends
+    a job's spec with every state change), so the batch conversion
+    (scheduler/sidecar.py `_jobs_from_states`) derives each ONCE and gives
+    every job of it a JobSpec that SHARES the template's `resources`,
+    `node_selector`, `tolerations`, `pools`, `annotations`, `labels`,
+    `services`, `ingress` and `node_type_scores` objects.  Shared means
+    immutable: the template's atoms are made read-only, and nothing under
+    armada_tpu/ writes to a spec's mappings in place.
+
+    Equal bytes are the same message, so a hit is exact; two encodings of one
+    content (map order) are two entries, never a wrong hit.  The table is
+    bounded by a plain reset: a caller whose every spec is distinct pays one
+    serialisation and one store a message, and holds at most `bound` entries.
+    One table per ResourceListFactory (a session owns one of each).
+    """
+
+    BOUND = 1024
+
+    def __init__(self, factory: ResourceListFactory, bound: int = BOUND):
+        self.factory = factory
+        self.bound = bound
+        self._fields: dict[bytes, dict] = {}
+        # `lookup(bytes)` -> the template's field dict, or None: bound once
+        # for the hot loop, which calls it a message (the reset in intern()
+        # clears the dict in place, so the binding stays good)
+        self.lookup = self._fields.get
+
+    def __len__(self) -> int:
+        return len(self._fields)
+
+    def intern(self, key: bytes, msg: pb.JobSpec) -> dict:
+        """The miss: today's one-message conversion, run once and kept.
+        Returns the field dict of a JobSpec whose per-job fields are blank."""
+        if len(self._fields) >= self.bound:
+            self._fields.clear()
+        blank = job_spec_from_proto("", "", "", msg, self.factory)
+        blank.resources.atoms.flags.writeable = False
+        fields = self._fields[key] = vars(blank)
+        return fields

@@ -25,6 +25,7 @@ corrected by the next sync.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import json
 import time
 import uuid
@@ -33,8 +34,8 @@ from typing import Optional, Sequence
 from armada_tpu.core.config import SchedulingConfig
 from armada_tpu.core.logging import get_logger
 from armada_tpu.core.pipeline import pipeline_enabled, prefetch_worthwhile
-from armada_tpu.core.types import Queue
-from armada_tpu.events.convert import job_spec_from_proto
+from armada_tpu.core.types import JobSpec, Queue
+from armada_tpu.events.convert import SpecTemplates, job_spec_from_proto
 from armada_tpu.jobdb.job import Job, JobRun
 from armada_tpu.jobdb.jobdb import JobDb
 from armada_tpu.ops.trace import recorder as _trace
@@ -142,6 +143,119 @@ def _job_from_state(msg, factory) -> Job:
     )
 
 
+def _field_defaults(cls) -> dict:
+    return {
+        f.name: f.default
+        for f in dataclasses.fields(cls)
+        if f.default is not dataclasses.MISSING
+    }
+
+
+_JOB_DEFAULTS = _field_defaults(Job)
+_RUN_DEFAULTS = _field_defaults(JobRun)
+_new = object.__new__
+
+
+def _jobs_from_states(msgs, templates: SpecTemplates, terminal_synced: dict):
+    """A SyncState's JobStates -> jobdb Jobs, in ONE pass: each Job equal,
+    field for field, to `_job_from_state(msg, factory)` (the one-message
+    reference; tests/test_sidecar.py holds the pass to it), with the session's
+    terminal bookkeeping done on the way.  Returns (jobs, spec_hits).
+
+    What a `spec` sub-message converts to comes from `templates` (derived
+    once per distinct spec, its containers shared between the jobs of it),
+    and each frozen dataclass is filled from a prototype field dict instead
+    of through its generated __init__ (an object.__setattr__ a field, twenty
+    fields a JobSpec, and Job's __post_init__): the instances are ordinary
+    ones, ==, dataclasses.replace and the with_* methods work on them.
+    """
+    lookup = templates.lookup
+    out = []
+    misses = 0
+    for m in msgs:
+        spec_msg = m.spec
+        key = spec_msg.SerializeToString()
+        fields = lookup(key)
+        if fields is None:
+            fields = templates.intern(key, spec_msg)
+            misses += 1
+        job_id = m.job_id
+        submit_time = m.submit_time
+        terminal = m.terminal
+        r = m.run
+
+        spec = _new(JobSpec)
+        d = spec.__dict__
+        d.update(fields)
+        d["id"] = job_id
+        d["queue"] = m.queue
+        d["jobset"] = m.jobset
+        d["submit_time"] = submit_time
+
+        # len()-guarded, like the spec's collections: iterating an empty
+        # repeated field costs as much as a JobRun.  Bans are rare (a retry):
+        # they take the plain constructor, as the reference does.
+        runs = []
+        if len(m.banned_nodes):
+            for node_id in m.banned_nodes:
+                runs.append(
+                    JobRun(
+                        id=f"ban/{job_id}/{node_id}",
+                        job_id=job_id,
+                        node_id=node_id,
+                        node_name=node_id,
+                        failed=True,
+                        run_attempted=True,
+                    )
+                )
+        if r.run_id or r.node_id:
+            run = _new(JobRun)
+            d = run.__dict__
+            d.update(_RUN_DEFAULTS)
+            d["id"] = r.run_id or uuid.uuid4().hex
+            d["job_id"] = job_id
+            d["executor"] = r.executor
+            d["node_id"] = r.node_id
+            d["node_name"] = r.node_name or r.node_id
+            d["pool"] = r.pool or "default"
+            d["scheduled_at_priority"] = (
+                int(r.scheduled_at_priority)
+                if r.has_scheduled_at_priority
+                else None
+            )
+            d["pool_scheduled_away"] = r.away
+            d["running"] = r.running
+            d["running_ns"] = int(r.running_ns)
+            d["run_attempted"] = r.running or bool(r.running_ns)
+            d["failed"] = terminal and not r.preempted
+            d["preempted"] = terminal and r.preempted
+            runs.append(run)
+
+        if terminal:
+            # 0 = never ran: the penalty can't apply
+            # (ShortJobPenalty.applies needs running_ns > 0), so the sweep
+            # drops it at the next round -- and never mixes the sidecar wall
+            # clock with the caller's logical now_ns.
+            terminal_synced[job_id] = int(r.running_ns)
+        else:
+            terminal_synced.pop(job_id, None)
+
+        job = _new(Job)
+        d = job.__dict__
+        d.update(_JOB_DEFAULTS)
+        d["spec"] = spec
+        # what Job.__post_init__ derives from a priority that is given
+        d["priority"] = d["requested_priority"] = int(m.priority)
+        d["submitted_ns"] = int(submit_time * 1e9)
+        d["queued"] = m.queued and not terminal
+        d["validated"] = m.validated
+        d["pools"] = tuple(m.pools) if len(m.pools) else ()
+        d["failed"] = terminal
+        d["runs"] = tuple(runs)
+        out.append(job)
+    return out, len(out) - misses
+
+
 class ScheduleSession:
     """One caller's mirrored world + algo; serialized rounds."""
 
@@ -161,6 +275,7 @@ class ScheduleSession:
         # in-process scheduler's _retained_terminal sweep equivalent.
         self._terminal_synced: dict[str, int] = {}
         self.factory = config.resource_list_factory()
+        self._spec_templates = SpecTemplates(self.factory)
         self.jobdb = JobDb(config)
         self.queues: list[Queue] = []
         self.executors: list[ExecutorSnapshot] = []
@@ -231,30 +346,24 @@ class ScheduleSession:
         trace = _trace()
         with self._locked():
             if jobs or deletes:
-                for m in jobs:
-                    if m.terminal:
-                        # 0 = never ran: the penalty can't apply
-                        # (ShortJobPenalty.applies needs running_ns > 0), so
-                        # the sweep drops it at the next round -- and never
-                        # mixes the sidecar wall clock with the caller's
-                        # logical now_ns.
-                        self._terminal_synced[m.job_id] = int(
-                            m.run.running_ns
-                        )
-                    else:
-                        self._terminal_synced.pop(m.job_id, None)
-                for jid in deletes:
-                    self._terminal_synced.pop(jid, None)
                 txn = self.jobdb.write_txn()
                 if deletes:
                     txn.delete(list(deletes))
                 if jobs:
-                    with trace.span("job_from_state", n=len(jobs)):
-                        converted = [
-                            _job_from_state(m, self.factory) for m in jobs
-                        ]
+                    # the conversion AND the terminal bookkeeping of the
+                    # request's jobs: one pass over the messages
+                    with trace.span("job_from_state", n=len(jobs)) as span:
+                        converted, spec_hits = _jobs_from_states(
+                            jobs, self._spec_templates, self._terminal_synced
+                        )
+                        span.annotate(
+                            templates=len(self._spec_templates),
+                            spec_hits=spec_hits,
+                        )
                     with trace.span("mirror_upsert", n=len(converted)):
                         txn.upsert(converted)
+                for jid in deletes:
+                    self._terminal_synced.pop(jid, None)
                 # the commit publishes to the mirror's indexes (mirror_index)
                 # and fires the feed's subscription (feed_apply with its
                 # submit_many / remove_many / lease_many nest in here)

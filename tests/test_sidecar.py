@@ -10,15 +10,19 @@ on the same world.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
+import pickle
 
 import grpc
 import pytest
 
 from armada_tpu.core.config import PriorityClass, SchedulingConfig
 from armada_tpu.core.types import NodeSpec, Queue
+from armada_tpu.events import events_pb2 as epb
 from armada_tpu.jobdb.job import Job, JobRun, JobSpec
 from armada_tpu.jobdb.jobdb import JobDb
+from armada_tpu.rpc import rpc_pb2 as pb
 from armada_tpu.rpc.client import ScheduleClient, job_state_of
 from armada_tpu.rpc.server import make_server
 from armada_tpu.scheduler.algo import FairSchedulingAlgo
@@ -574,3 +578,260 @@ def test_sidecar_short_job_penalty_rides_terminal_runs(sidecar_env):
         assert session.algo.short_job_penalty.applies(
             mirrored, NOW_NS
         ) is expect_penalty, f"preempted={preempted}"
+
+
+# --- the sync's batch conversion against its one-message reference ----------
+# A SyncState's JobStates are converted in ONE pass over interned spec
+# templates (sidecar._jobs_from_states, events/convert.SpecTemplates); the
+# plain reference stays `_job_from_state(msg, factory)`, and every Job the
+# pass returns must be indistinguishable from the reference's.
+
+
+def _state(job_id="j1", spec=None, **kw):
+    if spec is None:
+        spec = epb.JobSpec(
+            priority_class="pc-low",
+            resources=epb.Resources(milli={"cpu": 2000, "memory": 8000}),
+        )
+    kw.setdefault("queued", True)
+    return pb.JobState(
+        job_id=job_id, queue="qa", jobset="set-1", spec=spec, validated=True,
+        submit_time=12.5, **kw,
+    )
+
+
+def _run_state(**kw):
+    base = dict(
+        run_id="run-1", node_id="n03", node_name="node-3", executor="ex1",
+        pool="default", scheduled_at_priority=1000, has_scheduled_at_priority=True,
+    )
+    return pb.JobRunState(**{**base, **kw})
+
+
+def _rich_spec():
+    return epb.JobSpec(
+        priority_class="pc-high",
+        resources=epb.Resources(milli={"cpu": 500, "memory": 1000}),
+        node_selector={"zone": "a", "disk": "ssd"},
+        tolerations=[
+            epb.Toleration(key="gpu", operator="Exists", effect="NoSchedule"),
+            epb.Toleration(key="team", value="x"),
+        ],
+        gang_id="g1",
+        gang_cardinality=3,
+        gang_node_uniformity_label="rack",
+        pools=["default", "away"],
+        price_band="B",
+        namespace="ns1",
+        annotations={"a": "1"},
+        labels={"app": "web"},
+        services=[epb.ServiceSpec(type="Headless", ports=[80, 443], name="svc")],
+        ingress=[
+            epb.IngressSpec(
+                ports=[8080], annotations={"k": "v"}, tls_enabled=True,
+                cert_name="c", use_cluster_ip=True,
+            )
+        ],
+        node_type_scores=[
+            epb.NodeTypeScore(node_type="v5e", throughput=2.0),
+            epb.NodeTypeScore(node_type="a100", throughput=1.5),
+        ],
+    )
+
+
+def _resources_spec(milli):
+    return epb.JobSpec(priority_class="pc-low", resources=epb.Resources(milli=milli))
+
+
+_MESSAGE_FORMS = {
+    "queued_submit": lambda: _state(),
+    "leased_and_running": lambda: _state(
+        queued=False, run=_run_state(running=True, running_ns=NOW_NS - 5, away=True)
+    ),
+    "leased_not_running_no_priority": lambda: _state(
+        queued=False,
+        run=_run_state(has_scheduled_at_priority=False, node_name="", pool=""),
+    ),
+    "terminal_with_run": lambda: _state(
+        queued=False, terminal=True, run=_run_state(running_ns=NOW_NS - 10**9)
+    ),
+    "terminal_never_ran": lambda: _state(queued=True, terminal=True),
+    "terminal_and_preempted": lambda: _state(
+        queued=False, terminal=True,
+        run=_run_state(running_ns=NOW_NS - 10**9, preempted=True),
+    ),
+    "banned_nodes": lambda: _state(banned_nodes=["n01", "n07"]),
+    "banned_nodes_and_run": lambda: _state(
+        queued=False, banned_nodes=["n01"], run=_run_state(running=True)
+    ),
+    "pools": lambda: _state(pools=["default", "spot"]),
+    "rich_spec": lambda: _state(spec=_rich_spec()),
+    "no_resources": lambda: _state(spec=epb.JobSpec(priority_class="pc-low")),
+    "unknown_resource": lambda: _state(
+        spec=_resources_spec({"cpu": 1000, "unobtainium": 7})
+    ),
+    "nonzero_priority": lambda: _state(
+        priority=7,
+        spec=epb.JobSpec(
+            priority_class="pc-low", priority=3,
+            resources=epb.Resources(milli={"cpu": 1000}),
+        ),
+    ),
+}
+
+
+def _assert_fields_equal(got, want):
+    """Every dataclass field equal AND of the same type (a bool is not an
+    int here), recursing into the frozen dataclasses and their tuples."""
+    assert type(got) is type(want)
+    for f in dataclasses.fields(want):
+        g, w = getattr(got, f.name), getattr(want, f.name)
+        assert type(g) is type(w), (type(want).__name__, f.name, g, w)
+        if dataclasses.is_dataclass(w) and not isinstance(w, type):
+            if hasattr(w, "atoms"):
+                assert g.factory == w.factory
+                assert g.atoms.dtype == w.atoms.dtype
+                assert g.atoms.tolist() == w.atoms.tolist()
+            else:
+                _assert_fields_equal(g, w)
+        elif isinstance(w, tuple) and w and dataclasses.is_dataclass(w[0]):
+            assert len(g) == len(w)
+            for a, b in zip(g, w):
+                _assert_fields_equal(a, b)
+        else:
+            assert g == w, (type(want).__name__, f.name, g, w)
+    # the generated __eq__ agrees, and so does a dict of the instance
+    assert got == want
+    assert set(vars(got)) >= {f.name for f in dataclasses.fields(want)}
+
+
+def _reference_terminal_sync(msgs, state: dict) -> dict:
+    """The bookkeeping loop as `_apply_sync_locked` ran it before the pass."""
+    state = dict(state)
+    for m in msgs:
+        if m.terminal:
+            state[m.job_id] = int(m.run.running_ns)
+        else:
+            state.pop(m.job_id, None)
+    return state
+
+
+@pytest.mark.parametrize("form", sorted(_MESSAGE_FORMS))
+def test_batch_conversion_equals_the_one_message_reference(form):
+    from armada_tpu.events.convert import SpecTemplates
+    from armada_tpu.scheduler.sidecar import _job_from_state, _jobs_from_states
+
+    F = config_for(False).resource_list_factory()
+    msg = _MESSAGE_FORMS[form]()
+    twin = type(msg)()
+    twin.CopyFrom(msg)
+    twin.job_id, twin.queue, twin.submit_time = "j2", "qb", 99.0
+    other = _state(job_id="j3", spec=_resources_spec({"cpu": 123}))
+    assert twin.spec.SerializeToString() == msg.spec.SerializeToString()
+    assert other.spec.SerializeToString() != msg.spec.SerializeToString()
+    batch = [msg, twin, other]
+
+    seen_before = {"j1": 5, "j2": 6, "j3": 7, "elsewhere": 8}
+    terminal_synced = dict(seen_before)
+    templates = SpecTemplates(F)
+    jobs, spec_hits = _jobs_from_states(batch, templates, terminal_synced)
+
+    assert len(jobs) == 3
+    for got, m in zip(jobs, batch):
+        _assert_fields_equal(got, _job_from_state(m, F))
+    assert terminal_synced == _reference_terminal_sync(batch, seen_before)
+    # two templates, one hit (the twin)
+    assert (len(templates), spec_hits) == (2, 1)
+    # a second pass over the same messages is served from the table alone
+    again, spec_hits = _jobs_from_states(batch, templates, {})
+    assert (len(templates), spec_hits) == (2, 3)
+    assert again == jobs
+
+    # equal spec bytes share what the spec converts to; different bytes never
+    a, b, c = (j.spec for j in jobs)
+    for name in (
+        "resources", "node_selector", "tolerations", "annotations", "labels",
+        "services", "ingress", "node_type_scores",
+    ):
+        assert getattr(a, name) is getattr(b, name), name
+    assert c.resources is not a.resources
+    assert c.resources.atoms is not a.resources.atoms
+    assert c.node_selector is not a.node_selector
+    assert (a.id, a.queue, a.submit_time) == ("j1", "qa", 12.5)
+    assert (b.id, b.queue, b.submit_time) == ("j2", "qb", 99.0)
+
+    # ordinary instances: replace and the with_* methods work on them
+    job = jobs[0]
+    assert dataclasses.replace(job.spec, id="x").id == "x"
+    assert dataclasses.replace(job.spec, id="x").resources is job.spec.resources
+    bumped = job.with_priority(11)
+    assert (bumped.priority, bumped.requested_priority) == (11, 11)
+    assert bumped.spec is job.spec and job.priority == int(msg.priority)
+    assert job.with_queued(False).queued_version == job.queued_version + 1
+    leased = job.with_new_run(JobRun(id="r-new", job_id=job.id, node_id="n00"))
+    assert leased.runs[:-1] == job.runs and leased.latest_run.id == "r-new"
+    for run in job.runs:
+        assert run.with_running(running_ns=3).running_ns == 3
+        assert run.with_running().running_ns == run.running_ns
+        assert run.with_failed().failed and not run.with_failed().running
+    assert pickle.loads(pickle.dumps(job)) == job
+    assert copy.deepcopy(job) == job
+
+
+def test_spec_template_table_is_bounded_by_a_reset():
+    from armada_tpu.events.convert import SpecTemplates
+    from armada_tpu.scheduler.sidecar import _job_from_state, _jobs_from_states
+
+    F = config_for(False).resource_list_factory()
+    templates = SpecTemplates(F, bound=4)
+    batch = [
+        _state(job_id=f"j{i}", spec=_resources_spec({"cpu": 100 + i}))
+        for i in range(11)
+    ]
+    jobs, spec_hits = _jobs_from_states(batch, templates, {})
+    assert spec_hits == 0 and 0 < len(templates) <= 4
+    assert jobs == [_job_from_state(m, F) for m in batch]
+    assert [j.spec.resources.get("cpu") for j in jobs] == [100 + i for i in range(11)]
+    # the default bound holds too, and a repeat within it hits
+    assert SpecTemplates.BOUND >= 24
+    jobs, spec_hits = _jobs_from_states(batch[-3:], templates, {})
+    assert spec_hits == 3 and len(templates) <= 4
+
+
+def test_a_write_to_shared_atoms_raises():
+    from armada_tpu.events.convert import SpecTemplates
+    from armada_tpu.scheduler.sidecar import _jobs_from_states
+
+    F = config_for(False).resource_list_factory()
+    (a, b), _ = _jobs_from_states(
+        [_state("j1"), _state("j2")], SpecTemplates(F), {}
+    )
+    assert a.spec.resources.atoms is b.spec.resources.atoms
+    with pytest.raises(ValueError):
+        a.spec.resources.atoms[0] = 1
+    with pytest.raises(ValueError):
+        a.spec.resources.atoms += 1
+    # arithmetic makes new vectors, as ever
+    assert a.spec.resources.add(b.spec.resources).get("cpu") == 4000
+    assert b.spec.resources.get("cpu") == 2000
+
+
+def test_sessions_do_not_share_spec_templates():
+    from armada_tpu.scheduler.sidecar import ScheduleSession
+
+    one = ScheduleSession("one", config_for(False))
+    narrow = dataclasses.replace(
+        config_for(False), supported_resource_types=(("cpu", "1m"),)
+    )
+    two = ScheduleSession("two", narrow)
+    assert one.factory != two.factory
+    one.sync_jobs([_state("j1"), _state("j2")])
+    assert (len(one._spec_templates), len(two._spec_templates)) == (2 - 1, 0)
+    two.sync_jobs([_state("j1")])
+    assert (len(one._spec_templates), len(two._spec_templates)) == (1, 1)
+    j_one = one.jobdb.read_txn().get("j1")
+    j_two = two.jobdb.read_txn().get("j1")
+    assert j_one.spec.resources.factory is one.factory
+    assert j_two.spec.resources.factory is two.factory
+    assert len(j_one.spec.resources.atoms) != len(j_two.spec.resources.atoms)
+    assert one.jobdb.read_txn().get("j2").spec.resources is j_one.spec.resources

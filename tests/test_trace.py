@@ -506,7 +506,10 @@ def test_served_cycle_roots_are_covered_by_named_children(_fresh_recorder):
         "session_lock_wait", "job_from_state", "mirror_upsert", "mirror_commit",
     ]
     by_name = {c.name: c for c in sync.children}
-    assert by_name["job_from_state"].args == {"n": 6}
+    # six queued jobs of one shape: one spec template, interned by the first
+    # message and serving the other five (the terminal bookkeeping of the
+    # request's jobs runs inside this span too)
+    assert by_name["job_from_state"].args == {"n": 6, "templates": 1, "spec_hits": 5}
     assert by_name["mirror_upsert"].args == {"n": 6}
     commit = [c.name for c in by_name["mirror_commit"].children if c.name != "gc_collect"]
     assert commit == ["mirror_index", "feed_apply"]
