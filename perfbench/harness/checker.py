@@ -36,10 +36,27 @@ Invariants (README.md lists them for users):
      `termination` "exhausted", then after its leases no job that some queue
      under its per-queue cap held among its first `maxQueueLookback` queued
      jobs at the round's start (the queue's order: priority class, submit
-     time, id) fits, in every resource, into what any node has left.  Rounds
-     that preempt are left alone: the reference's second pass may free room
-     that nothing retries.  A configuration whose rounds can give up states
-     its `maxQueueLookback`: a round that gives up without one is reported.
+     time, id) fits, in every resource, into what any node that admits it
+     (invariant 11's rule) has left.  For a gang it holds what can be said
+     plainly and exactly, its members being of one shape: the nodes that admit
+     the shape have room between them for fewer members than the gang has
+     (a node's room being the least, over the resources asked, of free over
+     request, rounded down; within ONE value of the kind's `uniformity_label`
+     where it states one); a gang that is not whole within the lookback, or
+     has more members than the round's or its queue's cap has left, is left
+     out.  Rounds that preempt are left alone: the reference's second pass
+     may free room that nothing retries.  A configuration whose rounds can
+     give up states its `maxQueueLookback`: a round that gives up without one
+     is reported.
+ 10. a gang is leased whole in one round or not at all (its members count one
+     each toward invariant 4's caps); where its kind states a
+     `uniformity_label`, the members' nodes carry one value of that label;
+ 11. a lease names a node that admits the job: the node's labels satisfy the
+     job's `node_selector`, and the job tolerates every NoSchedule /
+     NoExecute taint of the node (`world.admits`); a lease that does not is
+     reported and not booked;
+ 12. a preempted member takes its whole gang with it in the same round: every
+     member of its gang that holds a lease is preempted in that round too.
 """
 
 from __future__ import annotations
@@ -167,6 +184,8 @@ class Checker:
             node = self._preempt(n, job_id)
             if node is not None:
                 touched.append(node)
+        if len(w.gang_size):
+            self._gangs_whole(n, leases, record["preempted"])
         if len(leases) > self.cap:
             self._bad(n, f"{len(leases)} leases, over the per-round cap {self.cap}")
         per_queue: dict = {}
@@ -192,6 +211,15 @@ class Checker:
             if queue != w.queue_names[w.job_queue[i]]:
                 self._bad(n, f"job {job_id} reported in queue {queue!r}")
             per_queue[queue] = per_queue.get(queue, 0) + 1
+            if not w.shape_admits[w.job_shape[i], w.node_kind[node]]:
+                kind = w.node_kinds[w.node_kind[node]]
+                self._bad(
+                    n,
+                    f"job {job_id} leased to node {node_id}, which does not admit it: selector "
+                    f"{w.shape_selector[w.job_shape[i]]} against labels {kind['labels']}, taints {kind['taints']} "
+                    f"against tolerations {w.shape_tolerations[w.job_shape[i]]}"
+                )
+                continue
             self.status[i] = LEASED
             self.node_of[i] = node
             self.used[node] += w.shape_req[w.job_shape[i]]
@@ -206,7 +234,7 @@ class Checker:
                 self._bad(
                     n,
                     f"node {w.node_ids[node]} holds {self.used[node].tolist()} "
-                    f"of {w.node_total[node].tolist()} (thousandths of cpu, memory)"
+                    f"of {w.node_total[node].tolist()} (thousandths of {', '.join(w.resources)})"
                 )
 
         if heads is not None:
@@ -229,6 +257,51 @@ class Checker:
                 counts,
                 {"leased": len(leases), "preempted": len(record["preempted"])},
             )
+
+    # ---- 10, 12: a gang is one unit ----
+
+    def _gangs_whole(self, n: int, leases: list, preempted: list) -> None:
+        """10: every gang with a member among the round's leases has all of
+        them there, on nodes of one value of its uniformity label; 12: every
+        gang with a member among the round's preemptions has every member that
+        held a lease at the round's start there.  Called after the round's
+        preemptions are booked and before its leases are."""
+        w = self.world
+        by_gang: dict = {}
+        for job_id, node_id, _ in leases:
+            try:
+                i = w.job_number(job_id)
+            except KeyError:
+                continue  # reported where the lease is booked
+            if w.job_gang[i] >= 0:
+                by_gang.setdefault(int(w.job_gang[i]), {})[i] = node_id
+        for g, got in by_gang.items():
+            size = int(w.gang_size[g])
+            if len(got) != size:
+                self._bad(n, f"gang {w.gang_id(g)} leased in part: {len(got)} of its {size} members")
+            label = w.shape_uniformity[w.job_shape[next(iter(got))]]
+            if label:
+                # of the members on nodes that admit them (11 reports the others)
+                kinds = {int(w.node_kind[w.node_index[x]]) for x in got.values() if x in w.node_index}
+                shape = w.job_shape[next(iter(got))]
+                values = {w.node_kinds[k]["labels"].get(label) for k in kinds if w.shape_admits[shape, k]}
+                if len(values) > 1 or None in values:
+                    self._bad(n, f"gang {w.gang_id(g)} leased across values {sorted(map(str, values))} of its uniformity label {label!r}")
+        gone = set()
+        for job_id in preempted:
+            try:
+                gone.add(w.job_number(job_id))
+            except KeyError:
+                continue  # an initial run (no gang), or reported by `_preempt`
+        for g in {int(w.job_gang[i]) for i in gone if w.job_gang[i] >= 0}:
+            members = np.arange(w.gang_start[g], w.gang_start[g] + w.gang_size[g])
+            left = members[self.status[members] == LEASED]  # the preempted are PREEMPTED by now
+            if len(left):
+                self._bad(
+                    n,
+                    f"gang {w.gang_id(g)} preempted in part: {len(left)} of its members keep their lease "
+                    f"(first {w.job_id(int(left[0]))})"
+                )
 
     # ---- 9: a round that gives up ----
 
@@ -258,28 +331,57 @@ class Checker:
         return order[queued & (before - ahead_of_queue < self.lookback)]
 
     def _nothing_left_fits(self, n: int, heads: np.ndarray, leased_per_queue: dict) -> None:
-        """`heads` less what the round leased, queue by queue: no shape left in
-        a queue still under its cap fits any node's free room."""
+        """`heads` less what the round leased, queue by queue: no single job
+        left in a queue still under its cap fits the free room of a node that
+        admits it, and no gang left whole there has room for all its members
+        (the docstring's rule)."""
         w = self.world
         left = heads[self.status[heads] == QUEUED]
         capped = [
             w.queue_index[q] for q, k in leased_per_queue.items() if k >= self.queue_cap
         ]
         left = left[~np.isin(w.job_queue[left], capped)]
-        shapes = np.unique(w.job_shape[left])
         free = w.node_total - self.used
-        fits = (w.shape_req[shapes][:, None, :] <= free[None, :, :]).all(axis=2)
-        if not fits.any():
-            return
-        s, node = (int(x) for x in np.argwhere(fits)[0])
-        shape = int(shapes[s])
-        cpu, mem, preemptible = w.shapes[shape]
-        holder = w.queue_names[int(w.job_queue[left[w.job_shape[left] == shape][0]])]
-        self._bad(
-            n,
-            f"the round gave up (exhausted, {sum(leased_per_queue.values())} leases under the "
-            f"cap {self.cap}, nothing preempted) while a job still fits: {holder} holds a "
-            f"{w.class_name(preemptible)} job of {cpu} cpu thousandths and {mem} memory within "
-            f"its lookback, node {w.node_ids[node]} has {free[node].tolist()} free; "
-            f"{int(fits.any(axis=0).sum())} nodes have room for one of {len(shapes)} shapes",
+        leased = sum(leased_per_queue.values())
+        told = (
+            f"the round gave up (exhausted, {leased} leases under the cap {self.cap}, nothing preempted) while "
         )
+        singles = left[w.job_gang[left] < 0]
+        shapes = np.unique(w.job_shape[singles])
+        fits = (w.shape_req[shapes][:, None, :] <= free[None, :, :]).all(axis=2) & w.shape_admits[shapes][:, w.node_kind]
+        if fits.any():
+            s, node = (int(x) for x in np.argwhere(fits)[0])
+            shape = int(shapes[s])
+            cpu, mem, preemptible = w.shapes[shape]
+            holder = w.queue_names[int(w.job_queue[singles[w.job_shape[singles] == shape][0]])]
+            self._bad(
+                n,
+                told + f"a job still fits: {holder} holds a "
+                f"{w.class_name(preemptible)} job of {cpu} cpu thousandths and {mem} memory within "
+                f"its lookback, node {w.node_ids[node]} has {free[node].tolist()} free; "
+                f"{int(fits.any(axis=0).sum())} nodes have room for one of {len(shapes)} shapes",
+            )
+            return
+        gangs, seen = np.unique(w.job_gang[left[w.job_gang[left] >= 0]], return_counts=True)
+        for g in gangs[seen == w.gang_size[gangs]]:  # whole within the lookback
+            size, first = int(w.gang_size[g]), int(w.gang_start[g])
+            queue = w.queue_names[int(w.job_queue[first])]
+            if size > min(self.cap - leased, self.queue_cap - leased_per_queue.get(queue, 0)):
+                continue
+            shape = int(w.job_shape[first])
+            req = w.shape_req[shape]
+            room = np.where(w.shape_admits[shape][w.node_kind], (free[:, req > 0] // req[req > 0]).min(axis=1), 0)
+            room = np.minimum(np.maximum(room, 0), size)
+            label = w.shape_uniformity[shape]
+            domains = [np.ones(len(room), bool)]
+            if label:
+                value = np.array([k["labels"].get(label) for k in w.node_kinds], object)[w.node_kind]
+                domains = [value == v for v in set(value.tolist()) - {None}]
+            if any(room[d].sum() >= size for d in domains):
+                self._bad(
+                    n,
+                    told + f"a gang still fits: {queue} holds gang {w.gang_id(int(g))} of {size} members "
+                    f"(each {req.tolist()} thousandths of {', '.join(w.resources)}) whole within its lookback, and "
+                    f"the nodes that admit it have room for {int(room.sum())} such members",
+                )
+                return

@@ -22,6 +22,8 @@ import sys
 import tempfile
 import time
 
+import numpy as np
+
 from perfbench.harness import layers, probes, tracered
 from perfbench.harness.cell import Cell
 from perfbench.harness.checker import Checker
@@ -167,8 +169,8 @@ class Run:
         )
         chunk = int(w.sizes["mirror_chunk"])
         sent = 0
-        for lo in range(0, w.num_jobs, chunk):
-            states = w.job_states(range(lo, min(lo + chunk, w.num_jobs)))
+        for numbers in w.chunks(w.num_jobs, chunk):  # a gang's members leave in one SyncState
+            states = w.job_states(numbers)
             self.wire.sync(pb.SyncStateRequest(session_id=self.sid, jobs=states))
             sent += len(states)
         priorities = {
@@ -197,7 +199,8 @@ class Run:
     def prepare(self, k: int):
         """Between cycles: cycle k's request, with the terminal states of the
         jobs that finished appended: every job leased `lifetime` cycles earlier
-        and still running or, where the mix gives `completions_per_cycle`, the
+        and still running (a gang's members were leased together, so they
+        finish together) or, where the mix gives `completions_per_cycle`, the
         K oldest live leases of the books (`finishing`).  A job the scheduler
         preempted since has left the books: `forget`."""
         if k not in self.requests:
@@ -217,17 +220,24 @@ class Run:
         fleet has a service rate: from cycle `lifetime` on, the K oldest live
         leases (by the cycle that leased them, then by their place in that
         round's response), or all of them if fewer are live; what one thin
-        round leased does not come back as one thin round of completions."""
+        round leased does not come back as one thin round of completions.  A
+        gang finishes whole: where the next oldest lease is a gang's member
+        and its live members no longer fit under K, the cycle finishes fewer
+        than K (K is a ceiling; `drift` reckons with what really finished)."""
         if self.service_rate is None:
             at = k - self.lifetime
             return [(at, i, lease) for i, lease in self.leased.pop(at, {}).items()]
         out, want = [], self.service_rate if k >= self.lifetime else 0
+        w = self.world
         for at in sorted(self.leased):
-            if len(out) >= want:
-                break
             live = self.leased[at]
-            for i in list(live)[: want - len(out)]:
-                out.append((at, i, live.pop(i)))
+            for i in list(live):
+                if i not in live:
+                    continue  # left with its gang
+                unit = [j for j in w.members(i) if j in live]
+                if len(out) + len(unit) > want:
+                    return out
+                out += [(at, j, live.pop(j)) for j in unit]
             if not live:
                 del self.leased[at]
         return out
@@ -313,6 +323,14 @@ class Run:
             except KeyError:
                 pass  # the checker reports it
         self.leased_at.update(dict.fromkeys(known, k))
+        # counted by the harness from the leases and the world's tables: the
+        # leases that are a gang's members, and those that ask a resource
+        # beyond cpu and memory (the GPUs of a fleet that has them)
+        rec["gang_members_leased"] = rec["gpu_leases"] = 0
+        if known and (len(self.world.gang_size) or len(self.world.resources) > 2):
+            numbers = np.fromiter(known, np.int64, len(known))
+            rec["gang_members_leased"] = int((self.world.job_gang[numbers] >= 0).sum())
+            rec["gpu_leases"] = int(self.world.shape_req[self.world.job_shape[numbers], 2:].any(axis=1).sum())
         for job_id in rec["preempted"]:
             self.forget(job_id)
         # the sidecar's two roots of this cycle (the plane's own idle
@@ -425,13 +443,15 @@ def histogram(values, bins: int = 12) -> list:
     return [[round(lo + i * width, 4), c] for i, c in enumerate(counts)]
 
 
-def drift(window: list, traffic: dict | None = None) -> tuple:
+def drift(window: list, traffic: dict | None = None, whole_gangs: bool = False) -> tuple:
     """How far `num_queued` and `num_running` moved between the window's first
     and last cycle, each as {"value", "limit"}, and the problem if one moved by
     more than its limit.  The running count is held to where it stood; the
     queued count to where the mix's own flows put it: with a service rate
     (`completions_per_cycle`) under the arrivals the backlog grows by
-    `submits_per_cycle` - `completions_per_cycle` every cycle BY DESIGN, and
+    `submits_per_cycle` - `completions_per_cycle` every cycle BY DESIGN (in a
+    world with gangs, `whole_gangs`, by the submits less what each record says
+    really finished: gangs finish whole, so the rate is a ceiling), and
     what is compared is how far it is from that.  The limit is 0 (exactly)
     unless the mix states `stationary_slack_per_cycle` S, jobs a cycle: then it
     is floor(S x the window's cycles after its first), for both counts, so a
@@ -442,12 +462,15 @@ def drift(window: list, traffic: dict | None = None) -> tuple:
     if None in first or None in last:
         return {}, moved
     steps = len(window) - 1
-    grows = 0
+    grows = due = 0
     if "completions_per_cycle" in traffic:
-        grows = int(traffic["submits_per_cycle"]) - int(traffic["completions_per_cycle"])
+        rate = int(traffic["completions_per_cycle"])
+        grows = int(traffic["submits_per_cycle"]) - rate
+        finished = sum(len(c["completed"]) if whole_gangs else rate for c in window[1:])
+        due = int(traffic["submits_per_cycle"]) * steps - finished
     limit = math.floor(float(traffic.get("stationary_slack_per_cycle", 0)) * steps)
     out = {
-        "queued_drift": {"value": abs(last[0] - first[0] - grows * steps), "limit": limit},
+        "queued_drift": {"value": abs(last[0] - first[0] - due), "limit": limit},
         "running_drift": {"value": abs(last[1] - first[1]), "limit": limit},
     }
     if all(c["value"] <= limit for c in out.values()):
@@ -482,7 +505,7 @@ def verdict(run: Run, window: list, on_tpu: bool) -> tuple:
         )
     if not run.diag["warm_clean"]:
         problems.append("warm-up never reached three cycles in a row without a compile")
-    drifts, moved = drift(window, run.traffic)
+    drifts, moved = drift(window, run.traffic, whole_gangs=bool(len(run.world.gang_size)))
     if moved:
         problems.append(moved)
     checks.update(

@@ -37,7 +37,15 @@ own from-scratch round (`run_scheduling_round`) on THIS process's backend
 from the same state: from a dump under `JAX_PLATFORMS=cpu` that is the second
 witness beside what a chip served, and `leases_by_shape_...` shows a size
 that one side stopped leasing (PERF.md section 7: the chip refuses a job whose
-memory of 33 equals a node's free memory; XLA:CPU and the oracle place it).  Exit code 0 only if the served rounds broke no invariant and round 0,
+memory of 33 equals a node's free memory; XLA:CPU and the oracle place it).
+A world with gangs, node types and a third resource goes in whole (`problem_of`):
+every resource, the gangs' ids and cardinalities, and to the program's round the
+labels, taints, selectors and tolerations as the configuration words them; the
+oracle knows no label and no taint, so it gets the node types that admit each job
+as the job's list of node types at throughput 1.0, which changes no score.  Each
+round's line says how many gangs each side leased, whether they are the same gangs
+(`same_gangs`; round 0's `same_jobs` holds it gang for gang) and whether any side
+leased a gang in part.  Exit code 0 only if the served rounds broke no invariant and round 0,
 where compared, leased the oracle's jobs: it says nothing of the rounds after.
 """
 
@@ -49,6 +57,8 @@ import os
 import sys
 import tempfile
 import time
+
+import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -154,30 +164,61 @@ def kinds(w, job_ids) -> list:
     return sorted(out)
 
 
-def problem_of(cell, w, queued, live: dict, runs) -> tuple:
+def problem_of(cell, w, queued, live: dict, runs, by_type: bool = False) -> tuple:
     """(config, nodes, queues, queued jobs, running jobs) of one round, as the
     program's own types.  `queued`: the job numbers in the backlog; `live`:
     {job number: node id} of leases still running; `runs`: the initial runs
-    still live."""
+    still live.  Every resource of the world, the nodes' labels and taints,
+    the jobs' selectors, tolerations and gangs go in as the configuration
+    words them.
+
+    `by_type` is the form the sequential oracle reads: it knows no label and
+    no taint, but it keeps a job that names node types to nodes of those
+    types.  So which node types ADMIT each shape is worked out here
+    (`world.admits`, plain Python over the configuration's words) and handed
+    over as the job's list of node types, each at throughput 1.0, which adds
+    nothing to a node's score; a world with no label and no taint gives the
+    oracle what it always got."""
     from armada_tpu.core.config import scheduling_config_from_dict
-    from armada_tpu.core.types import JobSpec, NodeSpec, Queue, RunningJob
+    from armada_tpu.core.types import JobSpec, NodeSpec, Queue, RunningJob, Taint, Toleration
 
     cfg = scheduling_config_from_dict(cell.scheduling())
     f = cfg.resource_list_factory()
-    rl = lambda cpu, mem: f.from_mapping({"cpu": f"{cpu}m", "memory": str(mem)})  # noqa: E731
-    shape_rl = [rl(cpu, mem) for cpu, mem, _ in w.shapes]
-    run_rl = [rl(cpu, mem) for cpu, mem, _ in w.run_shapes]
-    nodes = [
-        NodeSpec(id=w.node_ids[i], pool="default",
-                 total_resources=rl(int(c) * 1000, int(c) * int(w.sizes["memory_per_core"])))
-        for i, c in enumerate(w.node_cores)
-    ]
+    rl = lambda req: f.from_mapping({name: f"{int(a)}m" for name, a in zip(w.resources, req)})  # noqa: E731
+    shape_rl = [rl(req) for req in w.shape_req]
+    run_rl = [rl(req) for req in w.run_shape_req]
+    kind_rl = {}
+    plain = bool(w.shape_admits.all() and w.run_shape_admits.all())  # no label or taint keeps any job off any node
+    names = [k["name"] for k in w.node_kinds]
+    nodes = []
+    for i, k in enumerate(w.node_kind.tolist()):
+        if k not in kind_rl:
+            kind_rl[k] = rl(w.node_total[i])
+        kind = w.node_kinds[k]
+        if by_type:
+            more = {} if plain else {"node_type": names[k]}
+        else:
+            more = {"labels": dict(kind["labels"]),
+                    "taints": tuple(Taint(t["key"], t.get("value", ""), t.get("effect", "NoSchedule")) for t in kind["taints"])}
+        nodes.append(NodeSpec(id=w.node_ids[i], pool="default", total_resources=kind_rl[k], **more))
+
+    def placement(admitted, selector=(), tolerations=()):
+        if by_type:
+            return {} if plain else {"node_type_scores": tuple(sorted((names[k], 1.0) for k in np.flatnonzero(admitted)))}
+        return {"node_selector": dict(selector),
+                "tolerations": tuple(Toleration(key=t.get("key", ""), operator=t.get("operator", "Equal"),
+                                                value=t.get("value", ""), effect=t.get("effect", "")) for t in tolerations)}
+
+    shape_more = [placement(*x) for x in zip(w.shape_admits, w.shape_selector, w.shape_tolerations)]
+    run_more = [placement(admitted) for admitted in w.run_shape_admits]
 
     def spec(i):
-        s = w.job_shape[i]
+        s, g = w.job_shape[i], w.job_gang[i]
+        gang = {} if g < 0 else {"gang_id": w.gang_id(g), "gang_cardinality": int(w.gang_size[g]),
+                                 "gang_node_uniformity_label": w.shape_uniformity[s]}
         return JobSpec(
             id=w.job_id(i), queue=w.queue_names[w.job_queue[i]], priority_class=w.class_name(w.shapes[s][2]),
-            submit_time=float(w.job_submit[i]), resources=shape_rl[s],
+            submit_time=float(w.job_submit[i]), resources=shape_rl[s], **shape_more[s], **gang,
         )
 
     running = [
@@ -185,7 +226,7 @@ def problem_of(cell, w, queued, live: dict, runs) -> tuple:
             job=JobSpec(
                 id=f"r{i:08d}", queue=w.queue_names[w.run_queue[i]],
                 priority_class=w.class_name(w.run_shapes[w.run_shape[i]][2]), submit_time=-1.0,
-                resources=run_rl[w.run_shape[i]],
+                resources=run_rl[w.run_shape[i]], **run_more[w.run_shape[i]],
             ),
             node_id=w.node_ids[w.run_node[i]],
         )
@@ -202,7 +243,7 @@ def oracle_sets(cell, w, queued, live: dict, runs) -> tuple:
         sys.path.insert(0, tests)
     import test_parity_full as parity
 
-    o_sched, o_preempted, _ = parity._Oracle(*problem_of(cell, w, queued, live, runs)).run()
+    o_sched, o_preempted, _ = parity._Oracle(*problem_of(cell, w, queued, live, runs, by_type=True)).run()
     return dict(o_sched), set(o_preempted)
 
 
@@ -216,6 +257,17 @@ def program_sets(cell, w, queued, live: dict, runs) -> tuple:
     out = run_scheduling_round(cfg, pool="default", nodes=nodes, queues=queues, queued_jobs=jobs, running=running,
                                collect_stats=False)
     return dict(out.scheduled), set(out.preempted)
+
+
+def gangs_of(w, job_ids) -> dict:
+    """{gang number: members among `job_ids`} of the ids that are a gang's."""
+    out: dict = {}
+    for job_id in job_ids:
+        if not job_id.startswith("r"):
+            g = int(w.job_gang[w.job_number(job_id)])
+            if g >= 0:
+                out[g] = out.get(g, 0) + 1
+    return out
 
 
 def by_shape(w, *lease_sets) -> dict:
@@ -241,6 +293,7 @@ def oracle_round(cell, w, dump: dict, k: int) -> dict:
     p_sched, p_preempted = program_sets(cell, w, queued, live, runs)
     r = dump["rounds"][k]
     got = {job_id: node_id for job_id, node_id, _ in r["leases"]}
+    gangs = [gangs_of(w, x) for x in (got, o_sched, p_sched)]  # served, the oracle's, the program's
     return {
         "round": k,
         "gave_up": r["gave_up"],
@@ -253,6 +306,10 @@ def oracle_round(cell, w, dump: dict, k: int) -> dict:
         "served_not_oracle": len(set(got) - set(o_sched)),
         "oracle_not_served": len(set(o_sched) - set(got)),
         "same_node": sum(1 for j, n in got.items() if o_sched.get(j) == n),
+        # gang for gang: which gangs each side leased, and whether each was leased whole
+        "gangs_served_oracle_program": [len(x) for x in gangs],
+        "same_gangs": set(gangs[0]) == set(gangs[1]),
+        "gangs_in_part": sum(1 for x in gangs for g, k in x.items() if k != w.gang_size[g]),
         "preempted": len(r["preempted"]),
         "oracle_preempted": len(o_preempted),
         "same_preempted": set(r["preempted"]) == o_preempted,

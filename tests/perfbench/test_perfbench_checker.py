@@ -413,3 +413,214 @@ def test_a_queue_at_its_per_queue_cap_holds_nothing_against_the_round():
         c.cycle(1, recs[1])
         assert [v for v in c.violations if "while a job still fits" not in v] == []
         assert bool(c.violations) == reported, c.violations
+
+
+# ---- gangs, selectors and taints, a third resource: invariants 10-12, and 9 for a gang ----
+
+from perfbench_tiny import gang_world  # noqa: E402
+
+GANG_SIZES = {
+    k: v
+    for k, v in dict(SIZES, nodes=50, queued_jobs=400, running_jobs=20, queues=4, **gang_world(50, uniformity_label="zone")).items()
+    if v is not None
+}
+
+
+def gang_honest(w, cycles=5, units=6):
+    """Records of a scheduler that each cycle leases, in job-number order, the
+    next `units` single jobs and the next two whole gangs, each job to the
+    emptiest node (by jobs so far) of a type that admits it; the client
+    completes a round's leases two cycles later.  Cycle 3 preempts, whole, the
+    first `batch` gang still running: the client never completes it."""
+    out, status, held = [], np.zeros(0, np.int8), np.zeros(len(w.node_ids), np.int64)
+    queued, running, leased_in, victim = 400, 20, [], None
+    for k in range(cycles):
+        submitted = list(w.extend(20, float(k)))
+        status = np.concatenate([status, np.zeros(w.num_jobs - len(status), np.int8)])
+        completed = [i for i in (leased_in[k - 2] if k >= 2 else []) if status[i] == 1]
+        status[completed] = 2
+        queued += len(submitted)
+        running -= len(completed)
+        free = np.flatnonzero(status == 0)
+        singles = free[w.job_gang[free] < 0][:units]
+        gangs = np.unique(w.job_gang[free[w.job_gang[free] >= 0]])[:2]
+        picked = list(singles) + [i for g in gangs for i in w.members(int(w.gang_start[g]))]
+        leases = []
+        for i in picked:
+            nodes = np.flatnonzero(w.shape_admits[w.job_shape[i]][w.node_kind])
+            node = nodes[np.argmin(held[nodes])]
+            held[node] += 1
+            leases.append((w.job_id(int(i)), w.node_ids[node], w.queue_names[w.job_queue[i]]))
+        status[picked] = 1
+        leased_in.append([int(i) for i in picked])
+        preempted = []
+        if k == 3:
+            earlier = np.unique(w.job_gang[[i for r in leased_in[1:3] for i in r if w.job_gang[i] >= 0 and status[i] == 1]])
+            victim = next(int(g) for g in earlier if w.shapes[w.job_shape[w.gang_start[g]]][2])
+            preempted = [w.job_id(i) for i in w.members(int(w.gang_start[victim]))]
+            status[list(w.members(int(w.gang_start[victim])))] = 3
+        out.append(dict(submitted=submitted, completed=completed, leases=leases, preempted=preempted,
+                        num_queued=queued, num_running=running))
+        queued -= len(picked)
+        running += len(picked) - len(preempted)
+    return out, victim
+
+
+def gang_check(w, records, cap=40, queue_cap=40, lookback=None):
+    c = Checker(w, cap=cap, queue_cap=queue_cap, priority_classes=CLASSES, lookback=lookback)
+    for n, r in enumerate(records):
+        c.cycle(n, r)
+    return c
+
+
+def test_an_honest_gang_replay_passes_in_every_resource():
+    w = World(GANG_SIZES, 3)
+    recs, victim = gang_honest(w)
+    c = gang_check(w, recs)
+    assert c.violations == [] and c.used.shape == (50, 3)
+    assert recs[3]["preempted"] and all(len(r["leases"]) > 6 for r in recs)
+    # the preempted gang's GPUs are free again, the live members' are held
+    live = [i for i, node in c.node_of.items() if w.shape_req[w.job_shape[i], 2]]
+    assert c.used[:, 2].sum() == 1000 * len(live) and not set(w.members(int(w.gang_start[victim]))) & set(live)
+
+
+def _member(w, recs, k, n=-1):
+    """(place in round k's leases, lease) of the n-th gang member there."""
+    at = [p for p, (job_id, _, _) in enumerate(recs[k]["leases"]) if w.job_gang[w.job_number(job_id)] >= 0][n]
+    return at, recs[k]["leases"][at]
+
+
+def _member_lease_dropped(w, recs, victim):
+    del recs[4]["leases"][_member(w, recs, 4)[0]]
+
+
+def _member_leased_a_round_later(w, recs, victim):
+    at, lease = _member(w, recs, 3)
+    del recs[3]["leases"][at]
+    recs[4]["leases"].append(lease)
+    recs[4]["num_queued"] += 1
+    recs[4]["num_running"] -= 1
+
+
+def _member_off_the_label(w, recs, victim):
+    at, (job_id, _, queue) = _member(w, recs, 4)
+    recs[4]["leases"][at] = (job_id, w.node_ids[int(np.flatnonzero(w.node_kind == 0)[0])], queue)
+
+
+def _single_onto_the_taint(w, recs, victim):
+    job_id, _, queue = recs[4]["leases"][0]
+    assert w.shape_kind[w.job_shape[w.job_number(job_id)]] == "grid"
+    recs[4]["leases"][0] = (job_id, w.node_ids[int(np.flatnonzero(w.node_kind == 1)[0])], queue)
+
+
+def _preempted_member_dropped(w, recs, victim):
+    recs[3]["preempted"].pop()
+    del recs[4:]
+
+
+def _one_member_preempted(w, recs, victim):
+    recs[3]["preempted"] = recs[3]["preempted"][:1]
+    del recs[4:]
+
+
+def _gpus_over_capacity(w, recs, victim):
+    node = w.node_ids[int(np.flatnonzero(w.node_kind == 1)[0])]
+    for r in recs:
+        r["leases"] = [(j, node if w.shape_req[w.job_shape[w.job_number(j)], 2] else n, q) for j, n, q in r["leases"]]
+
+
+@pytest.mark.parametrize(
+    "doctor,says,others",
+    [
+        (_member_lease_dropped, "leased in part", 0),
+        (_member_leased_a_round_later, "leased in part", 1),  # the round that leases the straggler is reported too
+        (_member_off_the_label, "which does not admit it", 0),
+        (_single_onto_the_taint, "which does not admit it", 0),
+        (_preempted_member_dropped, "preempted in part: 1 of its members", 0),
+        (_one_member_preempted, "preempted in part", 0),
+        (_gpus_over_capacity, "nvidia.com/gpu)", None),
+    ],
+)
+def test_a_doctored_gang_record_is_reported_by_its_invariant(doctor, says, others):
+    w = World(GANG_SIZES, 3)
+    recs, victim = gang_honest(w)
+    doctor(w, recs, victim)
+    c = gang_check(w, recs)
+    mine = [v for v in c.violations if says in v]
+    assert mine, c.violations
+    if others is not None:  # and by no other invariant
+        assert len(c.violations) == len(mine) == 1 + others, c.violations
+
+
+def test_a_gang_across_two_values_of_its_uniformity_label():
+    """Invariant 10's second half: every GPU node carries zone z1 in the tiny
+    world, so a second zone is a second node type, and a gang with a member in
+    each is reported; the same members within one zone are not."""
+    sizes = dict(GANG_SIZES)
+    a100 = sizes["node_types"][1]
+    sizes["node_types"] = [sizes["node_types"][0], dict(a100, count=5),
+                           dict(a100, name="a100-z2", count=5, labels=dict(a100["labels"], zone="z2"))]
+    w = World(sizes, 3)
+    recs, _ = gang_honest(w, cycles=2)
+    assert gang_check(w, recs).violations == [] or all("uniformity" in v for v in gang_check(w, recs).violations)
+    z = {zone: w.node_ids[int(np.flatnonzero(w.node_kind == k)[0])] for zone, k in (("z1", 1), ("z2", 2))}
+    members = [p for p, (j, _, _) in enumerate(recs[1]["leases"]) if w.job_gang[w.job_number(j)] >= 0]
+    for p in members:  # every gang of the round within z1
+        recs[1]["leases"][p] = (recs[1]["leases"][p][0], z["z1"], recs[1]["leases"][p][2])
+    for r in recs[:1]:
+        r["leases"] = [(j, z["z2"] if w.job_gang[w.job_number(j)] >= 0 else n, q) for j, n, q in r["leases"]]
+    assert gang_check(w, recs).violations == []
+    j, _, q = recs[1]["leases"][members[-1]]
+    recs[1]["leases"][members[-1]] = (j, z["z2"], q)
+    violations = gang_check(w, recs).violations
+    assert len(violations) == 1 and "leased across values ['z1', 'z2'] of its uniformity label 'zone'" in violations[0]
+
+
+def _gave_up(w, cap=40, fill_gpus_to=None, lookback=1000):
+    """One round that leased nothing and reports `exhausted`, on a world whose
+    GPU nodes hold `fill_gpus_to` members each from an earlier round (through
+    honest leases of the backlog's own gangs and singles of the kind)."""
+    recs = [dict(submitted=[], completed=[], leases=[], preempted=[], num_queued=400, num_running=20)]
+    if fill_gpus_to is not None:
+        kind = np.flatnonzero(w.shape_req[w.job_shape[:400], 2] > 0)
+        gpu_nodes = np.flatnonzero(w.node_kind == 1)
+        take, leases = iter(kind.tolist()), []
+        for node in gpu_nodes:
+            for _ in range(fill_gpus_to):
+                i = next(take)
+                leases.append((w.job_id(i), w.node_ids[node], w.queue_names[w.job_queue[i]]))
+        recs[0]["leases"] = leases
+        recs.append(dict(submitted=[], completed=[], leases=[], preempted=[], num_queued=400 - len(leases),
+                         num_running=20 + len(leases)))
+    recs[-1]["termination"] = "exhausted"
+    return recs
+
+
+def test_a_round_that_gives_up_while_a_gang_still_fits():
+    """Invariant 9 for a gang: its members are of one shape, so it fits where
+    the nodes that admit it have room for as many members as it has, within one
+    value of its uniformity label.  GPU nodes (10 of them, 8 GPUs each, a
+    member asks one) that hold 8 members each have room for none; with 7 each
+    they have room for 10 members between them, which a gang of 4 fits."""
+    sizes = dict(GANG_SIZES, job_cpu_milli=[64000])  # no grid job fits a 32-core node: only the kind can
+    w = World(sizes, 3)
+    # (members of whole gangs leased out of the backlog leave their gangs in part there: those are left out)
+    full = gang_check(w, _gave_up(w, fill_gpus_to=8), cap=400, queue_cap=400, lookback=1000)
+    assert [v for v in full.violations if "gave up" in v] == []
+    room = gang_check(w, _gave_up(w, fill_gpus_to=7), cap=400, queue_cap=400, lookback=1000)
+    said = [v for v in room.violations if "gave up" in v]
+    assert len(said) == 1 and ("a job still fits" in said[0] or "a gang still fits" in said[0])
+    # with the kind's single jobs out of the way (cardinality 2-4 only) it is the gang that is named
+    sizes["job_kinds"] = [dict(sizes["job_kinds"][0], gang={"cardinality": {"2": 1, "4": 1}, "uniformity_label": "zone"})]
+    w = World(dict(sizes, queued_jobs=396), 3)  # 198 members: 33 gangs of 2 and 33 of 4
+    recs = _gave_up(w, fill_gpus_to=7)
+    for r in recs:
+        r["num_queued"] -= 4
+    room = gang_check(w, recs, cap=400, queue_cap=400, lookback=1000)
+    said = [v for v in room.violations if "gave up" in v]
+    assert len(said) == 1 and "a gang still fits" in said[0] and "room for 10 such members" in said[0], room.violations
+    # a cap with less room than the gang has members leaves it out (the round's, or its queue's)
+    capped = gang_check(w, recs, cap=1, queue_cap=400, lookback=1000)
+    assert [v for v in capped.violations if "gave up" in v] == []
+    capped = gang_check(w, recs, cap=400, queue_cap=1, lookback=1000)
+    assert [v for v in capped.violations if "gave up" in v] == []

@@ -81,6 +81,90 @@ def test_config_file(config):
     assert doc["reduced"] == config["reduced"] and len(config["reduced"]) <= 16
     assert doc["guarantees"] and doc["assumed"]
     assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    assert world_form_problems(doc["world"]) == []
+
+
+def world_form_problems(world):
+    """What is wrong with the FORM of a `world` block's optional keys
+    (perfbench/README.md, "A configuration"), as a list of sentences; none of
+    it pins a value a later configuration may need.  The nodes are stated
+    once, by the short form or by `node_types` (a name, cores, memory, and a
+    `count` or a `share` each; extra resources among `resources`); a
+    `job_kinds` entry has a share, a size, extra resources among `resources`,
+    a `node_selector` that some `node_types` entry's labels satisfy, and a
+    gang cardinality that is a whole number from 1 or such numbers with
+    weights; `queue_demand` is "1/k", the one demand the generator draws."""
+    out = []
+    resources = list(world["resources"])
+    if resources[:2] != ["cpu", "memory"]:
+        out.append("`resources` starts with cpu and memory")
+    if world.get("queue_demand", "1/k") != "1/k":
+        out.append("`queue_demand` is \"1/k\": the generator draws no other")
+    if ("node_types" in world) == ("node_cores" in world):
+        out.append("the nodes are stated once: `node_cores` x `memory_per_core`, or `node_types`")
+    types = world.get("node_types", [])
+    for t in types:
+        if not {"name", "cores", "memory"} <= set(t) or ("count" in t) == ("share" in t):
+            out.append(f"node type {t.get('name')!r}: a name, cores, memory, and a `count` or a `share`")
+        if not set(t.get("resources", {})) <= set(resources[2:]):
+            out.append(f"node type {t.get('name')!r} has a resource that `resources` does not list")
+    for n, kind in enumerate(world.get("job_kinds", [])):
+        name = kind.get("name", f"kind{n}")
+        if not (0 < kind.get("share", 0) <= 1 and {"cpu_milli", "memory"} <= set(kind)):
+            out.append(f"job kind {name}: a `share` over 0 and at most 1, `cpu_milli` and `memory`")
+        if not set(kind.get("resources", {})) <= set(resources[2:]):
+            out.append(f"job kind {name} asks a resource that `resources` does not list")
+        selector = kind.get("node_selector", {})
+        if selector and not any(all(t.get("labels", {}).get(k) == v for k, v in selector.items()) for t in types):
+            out.append(f"job kind {name}: no `node_types` entry's labels satisfy its node_selector")
+        cardinality = kind.get("gang", {}).get("cardinality", 1)
+        sizes = cardinality if isinstance(cardinality, dict) else {cardinality: 1}
+        if not sizes or not all(str(c).isdigit() and int(c) >= 1 and w > 0 for c, w in sizes.items()):
+            out.append(f"job kind {name}: a gang's cardinality is a whole number from 1, or such numbers with weights")
+    return out
+
+
+GANG_FLEET = {  # a labelled three-resource fleet in the fewest keys (the tests' tiny gang cell, perfbench_tiny.py, runs the same)
+    "resources": ["cpu", "memory", "nvidia.com/gpu"], "node_cores": None, "memory_per_core": None,
+    "node_types": [{"name": "cpu", "share": 0.8, "cores": 32, "memory": 128},
+                   {"name": "a100", "share": 0.2, "cores": 16, "memory": 64, "resources": {"nvidia.com/gpu": 8},
+                    "labels": {"accelerator": "a100"}, "taints": [{"key": "nvidia.com/gpu", "value": "present", "effect": "NoSchedule"}]}],
+    "job_kinds": [{"name": "gang", "share": 0.048, "cpu_milli": 16000, "memory": 64, "resources": {"nvidia.com/gpu": 8},
+                   "node_selector": {"accelerator": "a100"}, "tolerations": [{"key": "nvidia.com/gpu", "operator": "Exists"}],
+                   "gang": {"cardinality": 8}}],
+}
+
+
+def _kind(**over):
+    return dict(GANG_FLEET, job_kinds=[dict(GANG_FLEET["job_kinds"][0], **over)])
+
+
+@pytest.mark.parametrize(
+    "keys,says",
+    [
+        ({}, None),  # the accepted form states none of the new keys
+        (GANG_FLEET, None),
+        (_kind(gang={"cardinality": {"4": 3, "16": 1}, "uniformity_label": "zone"}, share=1.0), None),  # any value of the form
+        (_kind(resources={"amd.com/gpu": 1}), "asks a resource that `resources` does not list"),
+        (_kind(node_selector={"accelerator": "h100"}), "labels satisfy its node_selector"),
+        (dict(_kind(), node_types=None, node_cores=[16], memory_per_core=4), "labels satisfy its node_selector"),
+        (_kind(gang={"cardinality": 0}), "whole number from 1"),
+        (_kind(share=0), "a `share` over 0"),
+        (dict(GANG_FLEET, node_cores=[16]), "stated once"),
+        (dict(GANG_FLEET, node_types=[{"name": "x", "cores": 8, "memory": 8}]), "a `count` or a `share`"),
+        (dict(GANG_FLEET, resources=["cpu", "memory"]), "does not list"),
+        ({"queue_demand": "uniform"}, "draws no other"),
+    ],
+    ids=["the-accepted-form", "a-gang-fleet", "other-values", "unlisted-resource", "selector-nobody-satisfies", "selector-without-node-types",
+         "cardinality-0", "no-share", "nodes-twice", "type-without-count", "gpu-not-listed", "queue-demand"],
+)
+def test_the_form_of_a_world_block(keys, says):
+    world = _updated(dict(load("perfbench", "configs", "cluster-100k-5k.json")["world"]), dict(keys))
+    problems = world_form_problems(world)
+    if says is None:
+        assert problems == []
+    else:
+        assert any(says in p for p in problems), problems
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])

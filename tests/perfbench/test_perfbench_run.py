@@ -9,6 +9,7 @@ import re
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 from oracle_rounds import kinds as _kinds  # a preempted set up to which of two interchangeable jobs went
@@ -724,3 +725,194 @@ def test_an_answer_altered_where_it_is_produced_makes_the_run_incorrect(tmp_path
     assert result["failed"] >= 1 and result["checks"]["checker_violations"]["value"] >= 1
     record = json.load(open(tmp_path / "out" / "tiny.steady-40.seed11.trace0.0.json"))
     assert any("leased twice" in p or "after it finished" in p for p in record["problems"]), record["problems"]
+
+
+# ---- the tiny GANG cell: gangs of 1-4 on a fleet a fifth of whose nodes carry GPUs, a label and a taint ----
+
+# 60 submits a cycle, 30 of them the kind's: three single jobs and three gangs each of 2, 3 and 4 members.  A
+# round stops at the first gang that no longer fits under its cap, so rounds lease 57-60 and the backlog creeps
+# up: the tiny mix states a slack (CPU counts, seeds 5-13 and two large ones: 0.2-0.6 jobs a cycle).  The sizes
+# keep the window clear of the program's capacity steps: the scatter's 256-row bucket (a gang world rewrites
+# ~320 rows a cycle) and the gang-unit region's 64 (270 gangs queued at the start, 320 the next step).
+GANGS = dict(queued=1800, burst=60)
+GANG_MIX = {"stationary_slack_per_cycle": 3, "stationary_slack_per_cycle_why": "tests: sound windows drift 0.2-0.6 a cycle"}
+
+
+def _gangs(**more):
+    return dict(traffic=GANG_MIX, scheduling={"shapeBucket": 4096}, **more)
+
+
+@pytest.fixture(scope="module")
+def gang_run(tmp_path_factory):
+    root = tmp_path_factory.mktemp("gangs")
+    bench = make_tiny(root, gangs=_gangs(), **GANGS)
+    out = os.path.join(root, "out")
+    p = _run_cli("--workload", "tiny.steady-40", "--seed", "3800000021", "--seconds", "1.5", "--trace", "1",
+                 "--allow-cpu", "--benchmark", bench, "--out", out)
+    assert p.returncode == 0, p.stderr[-2000:]
+    record = json.load(open(os.path.join(out, "tiny.steady-40.seed3800000021.trace1.0.json")))
+    return json.loads(p.stdout.strip().splitlines()[-1]), record
+
+
+def test_the_gang_cell_is_correct_and_its_record_counts_gangs_and_gpus(gang_run):
+    result, record = gang_run
+    assert result["correct"] is True and result["failed"] == 0, record["problems"]
+    assert result["checks"]["checker_violations"] == {"value": 0, "limit": 0}
+    window = [c for c in record["per_cycle"] if c["phase"] == "window"]
+    assert len(window) >= 5 and all(57 <= c["leases"] <= 60 and c["termination"] == "global_burst" for c in window)
+    # counted by the harness from the leases: a gang's members, and the jobs that ask a GPU (the kind's single jobs too)
+    assert all(0 < c["gang_members_leased"] <= c["gpu_leases"] <= c["leases"] for c in window)
+    assert result_metric(record, "gang_members_leased_per_cycle") == pytest.approx(
+        sum(c["gang_members_leased"] for c in window) / len(window)
+    )
+    assert result_metric(record, "gpu_leases_per_cycle") >= result_metric(record, "gang_members_leased_per_cycle") > 10
+    h = record["histograms"]
+    assert h["gangs_by_size"] == {"2": 90, "3": 90, "4": 90} and h["jobs_by_kind"] == {"grid": 900, "gang-a100": 900}
+    assert h["node_kind"] == {"cpu": 96, "a100": 24}
+    # the cells without gangs record the two fields too, as 0
+    assert record["books"]["preempted_in_window"] == 0
+
+
+def test_the_gang_cells_first_round_is_the_oracles_gang_for_gang(tmp_path, capsys):
+    """`oracle_rounds.py` on the tiny gang cell: round 0 leases the sequential
+    oracle's jobs, so its gangs, each whole, and the program's own from-scratch
+    round from the same state leases them too; selectors and taints reach the
+    oracle as the node types that admit each job (`problem_of`)."""
+    import oracle_rounds
+
+    bench = make_tiny(tmp_path, gangs=_gangs(), **GANGS)
+    argv = ["--workload", "tiny.steady-40", "--seed", "3800000023", "--benchmark", bench, "--out", str(tmp_path / "out")]
+    assert oracle_rounds.main(argv + ["--rounds", "3", "--allow-cpu"]) == 0
+    lines = [x for x in capsys.readouterr().out.splitlines() if x.startswith("perfbench oracle ")]
+    rounds = [json.loads(x.removeprefix("perfbench oracle round ")) for x in lines[:-1]]
+    last = json.loads(lines[-1].removeprefix("perfbench oracle "))
+    assert last["violations"] == 0 and last["round_0_agrees"] is True and last["rounds_compared"][0] == 0
+    first = rounds[0]
+    assert first["same_jobs"] and first["same_gangs"] and first["gangs_in_part"] == 0
+    assert first["leases"] == first["oracle_leases"] == first["program_leases"] >= 57
+    served, oracle, program = first["gangs_served_oracle_program"]
+    assert served == oracle == program >= 1
+    assert first["served_not_program"] == first["program_not_served"] == 0
+    by_shape = first["leases_by_shape_served_oracle_program"]
+    assert by_shape["2000x8"][0] == by_shape["2000x8"][1] > 0  # the kind's jobs among them
+
+
+PRESSED = dict(queued=1800, burst=60, lifetime=12)  # 30 members a cycle for 12 cycles ask 360 of the fleet's 192 GPUs
+
+
+def test_the_three_gang_controls_are_each_reported_by_their_own_invariant(tmp_path):
+    """`control.py`'s three newer controls on a tiny gang cell whose GPU nodes
+    fill up (every gang `batch`, so fair-share eviction takes whole gangs and
+    the known fault of mixed classes on a full node stays out of it): the
+    served rounds pass the checker; a member's lease dropped is reported by
+    invariant 10 alone, a GPU member moved to a node without the label by 11
+    alone, a preempted gang short of one member by 12 (and, where the round
+    leased to that member's node again, by 3 on that node: the same fault)."""
+    import control
+    import oracle_rounds
+    from perfbench.harness.cell import Cell
+
+    gangs = _gangs(share=0.6, gang_preemptible_share=1.0, cardinality={"2": 1, "4": 1})
+    cell = Cell(make_tiny(tmp_path, gangs=gangs, **PRESSED), "tiny.steady-40")
+    run, records = oracle_rounds.serve(cell, 11, 26)
+    run.cycles, run.first_window_k = records, 20
+    for n, r in enumerate(records):
+        run.checker.cycle(n, r)
+    assert run.checker.violations == []
+    w = run.world
+    taken = [sum(1 for j in r["preempted"] if control._gang(w, j) >= 0) for r in records]
+    assert sum(taken) >= 4, "a round preempted a gang"
+    for r in records:  # and whole: the members preempted are whole gangs' (invariant 12, honest)
+        gangs_hit = {control._gang(w, j) for j in r["preempted"]} - {-1}
+        assert sum(int(w.gang_size[g]) for g in gangs_hit) == sum(1 for j in r["preempted"] if control._gang(w, j) >= 0)
+    out = control.control(run)
+    assert out["honest_violations"] == 0 and control.controls_hold(out)
+    says = {"member_lease_dropped": "leased in part", "member_moved_off_label": "which does not admit it",
+            "preempted_member_dropped": "preempted in part"}
+    for name, c in out["gangs"].items():
+        assert c["round_doctored"] is not None and c["by_its_invariant_alone"], (name, c)
+        assert says[name] in c["first"] and c["doctored_violations"] == 1 + c["also"]
+        # beside 12 alone: the node of the member that kept its lease, over capacity where the round leased to it again
+        assert c["also"] == 0 or (name == "preempted_member_dropped" and c["also"] == 1)
+    assert "selector {'accelerator': 'a100'}" in out["gangs"]["member_moved_off_label"]["first"]
+    assert out["round_doctored"] is None  # no round gave up: invariant 9 has no round here
+
+
+def test_a_members_lease_dropped_where_it_is_produced_makes_the_run_incorrect(tmp_path, monkeypatch):
+    """The rest of a run with the timed path broken underneath, on the gang
+    cell: once the window is near, the client's `ScheduleRound` call hands
+    back each round without the lease of the last gang member in it.  The gang
+    was leased in part (invariant 10), and the scheduler's own counts say it
+    leased one more than it told (invariant 5): `correct` comes out false."""
+    import argparse
+    import time
+
+    from perfbench.harness import runner
+
+    class Dropping(runner.Wire):
+        def __init__(self, port):
+            super().__init__(port)
+            honest, rounds = self.round, []
+
+            def round_(req):
+                resp = honest(req)
+                rounds.append(len(resp.scheduled))
+                if len(rounds) > 6:
+                    del resp.scheduled[len(resp.scheduled) - 1]
+                return resp
+
+            self.round = round_
+
+    monkeypatch.setattr(runner, "Wire", Dropping)
+    args = argparse.Namespace(
+        benchmark=make_tiny(tmp_path, gangs=_gangs(cardinality={"4": 1}, share=0.8), **GANGS), workload="tiny.steady-40",
+        seed=3800000029, seconds=0.5, trace=0, out=str(tmp_path / "out"), allow_cpu=True, keep_trace=False,
+    )
+    code, result = runner.run_cell(args, time.time())
+    assert code == 0 and result["correct"] is False and result["failed"] >= 1
+    record = json.load(open(tmp_path / "out" / "tiny.steady-40.seed3800000029.trace0.0.json"))
+    assert any("leased in part" in p for p in record["problems"]), record["problems"]
+    assert any("scheduler counts" in p for p in record["problems"]), record["problems"]
+
+
+def test_a_service_rate_finishes_whole_gangs_and_is_a_ceiling(tmp_path):
+    """`completions_per_cycle` K on a world with gangs: the oldest live leases
+    finish gang by gang; a gang whose live members no longer fit under K waits
+    for the next cycle (K is a ceiling), and `drift` holds the backlog to the
+    submits less what really finished."""
+    from armada_tpu.rpc import rpc_pb2 as pb
+
+    from perfbench.harness.cell import Cell
+    from perfbench.harness.runner import Run, drift
+    from perfbench.harness.world import World
+
+    cell = Cell(make_tiny(tmp_path, lifetime=2, gangs=_gangs(cardinality={"4": 1}, share=0.8), **GANGS), "tiny.steady-40")
+    cell.traffic.update(completions_per_cycle=6)
+    run = Run(cell, 5, 1.0, False)
+    w = run.world = World(cell.config["world"], 5)
+    run.wire, run.sid = type("Wire", (), {"pb": pb})(), "books"
+    gangs = [list(w.members(int(s))) for s in w.gang_start[:3]]
+    singles = [int(i) for i in np.flatnonzero(w.job_gang < 0)[:3]]
+    order = [singles[0], *gangs[0][:2], singles[1], *gangs[0][2:], *gangs[1], singles[2], *gangs[2]]  # a response's order
+    run.leased[0] = {
+        i: pb.RoundLease(job_id=w.job_id(i), run_id=f"run-{i}", node_id="n000001", executor="ex0", pool="default")
+        for i in order
+    }
+    run.leased_at.update(dict.fromkeys(order, 0))
+    run.forget(w.job_id(gangs[1][0]))  # a member preempted since has left the books; the rest of its gang finishes
+    done = []
+    for k in (2, 3, 4, 5):
+        run.k = k
+        done.append(run.prepare(k)[2])
+    # 6 a cycle at the most: a single and its neighbour gang whole (5), the next single (6); then the three
+    # live members of the second gang and a single (4: the last gang's four would make 8); then that gang
+    assert done == [[singles[0], *gangs[0], singles[1]], [*gangs[1][1:], singles[2]], gangs[2], []]
+    assert run.leased == {} and run.leased_at == {}
+    # the backlog is due to grow by what was submitted less what finished
+    window = [{"num_queued": 100, "num_running": 50, "completed": []}] + [
+        {"num_queued": 100 + q, "num_running": 50, "completed": c} for q, c in zip((54, 110, 166), done[:3])
+    ]
+    mix = {"submits_per_cycle": 60, "completions_per_cycle": 6}
+    checks, problem = drift(window, mix, whole_gangs=True)
+    assert problem is None and checks["queued_drift"] == {"value": 0, "limit": 0}
+    assert drift(window, mix)[0]["queued_drift"]["value"] == 4  # a world without gangs is held to the rate itself, as before
