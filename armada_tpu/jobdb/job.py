@@ -219,6 +219,7 @@ class Job:
         return self._with(failed=True, queued=False)
 
     def with_new_run(self, run: JobRun) -> "Job":
+        # `with_new_runs` below makes the same lease for a batch: change both
         if run.job_id != self.id:
             raise ValueError(f"run {run.id} belongs to {run.job_id}, not {self.id}")
         return self._with(
@@ -231,3 +232,50 @@ class Job:
         if all(r.id != run.id for r in runs):
             raise ValueError(f"job {self.id} has no run {run.id}")
         return self._with(runs=runs)
+
+
+# Prototype filling, for the paths that make a batch of jobdb objects at once
+# (a sync's JobStates, a round's leases): `blank(cls)` is an instance whose
+# generated __init__ never ran (an object.__setattr__ a field, and Job's
+# __post_init__), filled through its __dict__ from `JOB_DEFAULTS` /
+# `RUN_DEFAULTS` and the fields that differ.  The instances are ordinary
+# ones: ==, dataclasses.replace and the with_* methods work on them.
+blank = object.__new__
+
+
+def _field_defaults(cls) -> dict:
+    return {
+        f.name: f.default
+        for f in dataclasses.fields(cls)
+        if f.default is not dataclasses.MISSING
+    }
+
+
+JOB_DEFAULTS = _field_defaults(Job)
+RUN_DEFAULTS = _field_defaults(JobRun)
+
+
+def with_new_runs(leases, **shared) -> list[tuple[Job, JobRun]]:
+    """A batch of leases, each (job, fields of its new run), made in one pass:
+    for each, the run `JobRun(job_id=job.id, **shared, **fields)` and the job
+    `job.with_new_run(run)`, field for field.  Each run is filled from the
+    prototype (`RUN_DEFAULTS` and `shared`) and each job is a copy of the
+    old one's __dict__ with the lease's three changes, so no generated
+    __init__ runs a lease.  The run belongs to its job by construction."""
+    proto = {**RUN_DEFAULTS, **shared}
+    out = []
+    for job, fields in leases:
+        was = job.__dict__
+        run = blank(JobRun)
+        d = run.__dict__
+        d.update(proto)
+        d.update(fields)
+        d["job_id"] = was["spec"].id
+        job = blank(Job)
+        d = job.__dict__
+        d.update(was)
+        d["runs"] = was["runs"] + (run,)
+        d["queued"] = False
+        d["queued_version"] = was["queued_version"] + 1
+        out.append((job, run))
+    return out

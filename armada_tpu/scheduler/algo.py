@@ -19,7 +19,7 @@ Executor health filters mirror scheduling_algo.go:
 from __future__ import annotations
 
 import dataclasses
-import uuid
+import os
 from typing import Callable, Optional, Sequence
 
 from armada_tpu.core.config import SchedulingConfig
@@ -29,7 +29,7 @@ from armada_tpu.core.pipeline import (
     prefetch_worthwhile,
 )
 from armada_tpu.core.types import JobSpec, NodeSpec, Queue, RunningJob
-from armada_tpu.jobdb.job import Job, JobRun
+from armada_tpu.jobdb.job import Job, JobRun, with_new_runs
 from armada_tpu.jobdb.jobdb import WriteTxn
 from armada_tpu.models import (
     PoolRoundSpec,
@@ -94,8 +94,21 @@ class SchedulerResult:
             self.failed = ChainedJobIds()
 
 
-def _new_run_id() -> str:
-    return uuid.uuid4().hex
+# uuid4's version (byte 6: 0100xxxx) and variant (byte 8: 10xxxxxx) bits, as
+# translation tables over one byte
+_UUID4_VERSION = bytes((b & 0x0F) | 0x40 for b in range(256))
+_UUID4_VARIANT = bytes((b & 0x3F) | 0x80 for b in range(256))
+
+
+def _new_run_ids(n: int) -> list[str]:
+    """n run ids, uuid4 hex as `uuid.uuid4().hex` makes them, from ONE draw
+    of the OS's randomness: 16 random bytes an id, uuid4's version and
+    variant bits set."""
+    raw = bytearray(os.urandom(16 * n))
+    raw[6::16] = raw[6::16].translate(_UUID4_VERSION)
+    raw[8::16] = raw[8::16].translate(_UUID4_VARIANT)
+    hexed = raw.hex()
+    return [hexed[k : k + 32] for k in range(0, 32 * n, 32)]
 
 
 def _running_of(job: Job, run: JobRun) -> RunningJob:
@@ -116,7 +129,7 @@ class FairSchedulingAlgo:
         config: SchedulingConfig,
         queues: Callable[[], Sequence[Queue]],
         clock_ns: Callable[[], int],
-        run_id_factory: Callable[[], str] = _new_run_id,
+        run_ids: Callable[[int], list[str]] = _new_run_ids,
         collect_stats: bool = True,
         bid_prices=None,
         priority_overrides=None,
@@ -134,7 +147,7 @@ class FairSchedulingAlgo:
         self.config = config
         self._queues = queues
         self._clock_ns = clock_ns
-        self._run_id = run_id_factory
+        self._run_ids = run_ids
         self.bid_prices = bid_prices
         self.priority_overrides = priority_overrides
         market_pools = [p.name for p in config.pools if p.market_driven]
@@ -411,10 +424,11 @@ class FairSchedulingAlgo:
                 pool=pool,
                 scheduled=len(outcome.scheduled),
                 preempted=len(outcome.preempted),
-            ):
-                self._apply_outcome(
+            ) as apply_span:
+                classes = self._apply_outcome(
                     txn, outcome, pool, executor_of_node, now_ns, result
                 )
+                apply_span.annotate(classes=classes)
             if incremental:
                 # Later pools must see this pool's leases/preemptions; the
                 # overlay registry keeps this O(this pool's changes), not
@@ -757,15 +771,18 @@ class FairSchedulingAlgo:
         # away priority level so the host pool's home jobs can always evict
         # them.  The host's running set is refreshed with this cycle's own
         # decisions (leases added, preemptions removed) so the away round
-        # cannot double-book capacity the home rounds just committed.
-        # O(decisions) every round, whether or not any pool lends nodes
-        with _trace().span("away_prepare", scheduled=len(result.scheduled)):
-            preempted_ids = {job.id for job, _ in result.preempted}
-            extra_running: dict[str, list[RunningJob]] = {}
-            for job, run in result.scheduled:
-                extra_running.setdefault(run.pool, []).append(
-                    _running_of(job, run)
-                )
+        # cannot double-book capacity the home rounds just committed.  The
+        # feed's running set holds them already; without a feed they are
+        # viewed here, O(decisions), for the away rounds and the optimiser.
+        preempted_ids: set = set()
+        extra_running: dict[str, list[RunningJob]] = {}
+        if not incremental:
+            with _trace().span("away_prepare", scheduled=len(result.scheduled)):
+                preempted_ids = {job.id for job, _ in result.preempted}
+                for job, run in result.scheduled:
+                    extra_running.setdefault(run.pool, []).append(
+                        _running_of(job, run)
+                    )
 
         def host_running(host: str) -> list[RunningJob]:
             kept = [
@@ -845,11 +862,12 @@ class FairSchedulingAlgo:
                     away_jobs = [
                         j for j in away_jobs if j.id not in scheduled_ids
                     ]
-                    for job, run in result.scheduled:
-                        if job.id in scheduled_ids:
-                            extra_running.setdefault(run.pool, []).append(
-                                _running_of(job, run)
-                            )
+                    if not incremental:
+                        for job, run in result.scheduled:
+                            if job.id in scheduled_ids:
+                                extra_running.setdefault(run.pool, []).append(
+                                    _running_of(job, run)
+                                )
 
         # Optimiser pass (optimiser/node_scheduler.go via pqs.go:250-272):
         # jobs the rounds could not place get one targeted-preemption attempt.
@@ -1093,27 +1111,44 @@ class FairSchedulingAlgo:
         now_ns: int,
         result: SchedulerResult,
         away: bool = False,
-    ) -> None:
+    ) -> int:
+        """Apply a pool's outcome to the txn and `result`; returns the
+        distinct priority classes its leases resolved.
+
+        The leases in ONE pass (`with_new_runs`), their priorities resolved
+        once a priority class and their run ids drawn once a round."""
+        get = txn.get
+        leased = [
+            (job, node_id)
+            for job_id, node_id in outcome.scheduled.items()
+            if (job := get(job_id)) is not None
+        ]
         away_priority = self.config.priority_ladder()[0]
-        for job_id, node_id in outcome.scheduled.items():
-            job = txn.get(job_id)
-            if job is None:
-                continue
-            pc = job.priority_class(self.config)
-            run = JobRun(
-                id=self._run_id(),
-                job_id=job_id,
-                created_ns=now_ns,
-                executor=executor_of_node.get(node_id, ""),
-                node_id=node_id,
-                node_name=node_id,
-                pool=pool,
-                scheduled_at_priority=away_priority if away else pc.priority,
-                pool_scheduled_away=away,
+        priority_of: dict[str, int] = {}  # priority class name -> priority
+        leases = []
+        for (job, node_id), run_id in zip(leased, self._run_ids(len(leased))):
+            name = job.spec.priority_class
+            priority = priority_of.get(name)
+            if priority is None:
+                pc = self.config.priority_class(name)
+                priority = priority_of[name] = away_priority if away else pc.priority
+            leases.append(
+                (
+                    job,
+                    {
+                        "id": run_id,
+                        "executor": executor_of_node.get(node_id, ""),
+                        "node_id": node_id,
+                        "node_name": node_id,
+                        "scheduled_at_priority": priority,
+                    },
+                )
             )
-            job = job.with_new_run(run)
-            txn.upsert(job)
-            result.scheduled.append((job, run))
+        pairs = with_new_runs(
+            leases, created_ns=now_ns, pool=pool, pool_scheduled_away=away
+        )
+        txn.upsert([job for job, _ in pairs])
+        result.scheduled.extend(pairs)
 
         for job_id in outcome.preempted:
             job = txn.get(job_id)
@@ -1128,3 +1163,4 @@ class FairSchedulingAlgo:
             result.preempted.append((job, run))
 
         result.failed.extend(outcome.failed)
+        return len(priority_of)

@@ -25,7 +25,6 @@ corrected by the next sync.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import json
 import time
 import uuid
@@ -36,7 +35,7 @@ from armada_tpu.core.logging import get_logger
 from armada_tpu.core.pipeline import pipeline_enabled, prefetch_worthwhile
 from armada_tpu.core.types import JobSpec, Queue
 from armada_tpu.events.convert import SpecTemplates, job_spec_from_proto
-from armada_tpu.jobdb.job import Job, JobRun
+from armada_tpu.jobdb.job import JOB_DEFAULTS, RUN_DEFAULTS, Job, JobRun, blank
 from armada_tpu.jobdb.jobdb import JobDb
 from armada_tpu.ops.trace import recorder as _trace
 from armada_tpu.scheduler.algo import FairSchedulingAlgo, SchedulerResult
@@ -143,19 +142,6 @@ def _job_from_state(msg, factory) -> Job:
     )
 
 
-def _field_defaults(cls) -> dict:
-    return {
-        f.name: f.default
-        for f in dataclasses.fields(cls)
-        if f.default is not dataclasses.MISSING
-    }
-
-
-_JOB_DEFAULTS = _field_defaults(Job)
-_RUN_DEFAULTS = _field_defaults(JobRun)
-_new = object.__new__
-
-
 def _jobs_from_states(msgs, templates: SpecTemplates, terminal_synced: dict):
     """A SyncState's JobStates -> jobdb Jobs, in ONE pass: each Job equal,
     field for field, to `_job_from_state(msg, factory)` (the one-message
@@ -184,7 +170,7 @@ def _jobs_from_states(msgs, templates: SpecTemplates, terminal_synced: dict):
         terminal = m.terminal
         r = m.run
 
-        spec = _new(JobSpec)
+        spec = blank(JobSpec)
         d = spec.__dict__
         d.update(fields)
         d["id"] = job_id
@@ -209,9 +195,9 @@ def _jobs_from_states(msgs, templates: SpecTemplates, terminal_synced: dict):
                     )
                 )
         if r.run_id or r.node_id:
-            run = _new(JobRun)
+            run = blank(JobRun)
             d = run.__dict__
-            d.update(_RUN_DEFAULTS)
+            d.update(RUN_DEFAULTS)
             d["id"] = r.run_id or uuid.uuid4().hex
             d["job_id"] = job_id
             d["executor"] = r.executor
@@ -240,9 +226,9 @@ def _jobs_from_states(msgs, templates: SpecTemplates, terminal_synced: dict):
         else:
             terminal_synced.pop(job_id, None)
 
-        job = _new(Job)
+        job = blank(Job)
         d = job.__dict__
-        d.update(_JOB_DEFAULTS)
+        d.update(JOB_DEFAULTS)
         d["spec"] = spec
         # what Job.__post_init__ derives from a priority that is given
         d["priority"] = d["requested_priority"] = int(m.priority)

@@ -265,3 +265,34 @@ def test_no_away_without_config(tmp_path):
     # gpu does not host cpu jobs)
     assert res.events_by_kind().get("job_errors") == 1
     cp.close()
+
+
+def test_away_views_are_built_where_the_away_round_reads_them(tmp_path):
+    """The away pass's views of the cycle's own decisions (the home leases
+    as RunningJobs, the home preemptions) are built once a cycle on the
+    legacy path, whose away round reads them; on the incremental feed the
+    host's running set comes from the feed, and no view is built.  Both
+    lease the same: two at home, two away at the lowest priority."""
+    from armada_tpu.ops.trace import recorder, reset_recorder
+    from tests.test_trace import _find
+
+    cp = build_plane(tmp_path)
+    cp.server.submit_jobs("qa", "js", [item() for _ in range(4)])
+    cp.ingest()
+    reset_recorder()
+    cp.scheduler.cycle()
+    leases = sorted(leases_by_pool(cp).values())
+    low = CFG.priority_ladder()[0]
+    home = CFG.priority_class(CFG.default_priority_class).priority
+    assert leases == sorted(
+        [("cpu", False, home)] * 2 + [("gpu", True, low)] * 2
+    )
+    built = [s for t in recorder().last() for s in _find(t.root, "away_prepare")]
+    if CFG.incremental_problem_build:
+        assert built == []
+    else:
+        (span,) = built
+        # the home round's two leases, viewed once for the gpu host's round
+        assert span.args == {"scheduled": 2}
+    reset_recorder()
+    cp.close()

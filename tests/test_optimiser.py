@@ -125,17 +125,19 @@ def test_cheapest_node_wins():
     assert d.node_id == "n1" and len(d.preempted_job_ids) == 1
 
 
-def test_end_to_end_optimiser_unsticks_job(tmp_path):
-    """Normal rounds can't place the big job (same priority, fair-share
-    eviction disabled); the optimiser preempts over-share victims for it."""
+def _starved_big_job(tmp_path, **config):
+    """Eight small hog jobs fill the cluster; then a big job arrives for
+    another queue that normal rounds can't place (same priority, fair-share
+    eviction disabled).  Two more steps; returns (plane, the big job's id,
+    the job states)."""
     from armada_tpu.server import JobSubmitItem, QueueRecord
     from tests.control_plane import ControlPlane
 
     cfg = SchedulingConfig(
         shape_bucket=32,
         protected_fraction_of_fair_share=100.0,  # normal eviction off
-        optimiser_enabled=True,
         default_priority_class="armada-preemptible",
+        **config,
     )
     cp = ControlPlane.build(tmp_path, config=cfg, runtime_s=600.0)
     cp.server.create_queue(QueueRecord("hog"))
@@ -148,42 +150,48 @@ def test_end_to_end_optimiser_unsticks_job(tmp_path):
     cp.step()
     assert sum(1 for s in cp.job_states().values() if s == "leased") == 8
 
-    big = cp.server.submit_jobs(
+    (big,) = cp.server.submit_jobs(
         "starved", "big", [JobSubmitItem(resources={"cpu": "8", "memory": "8"})]
     )
     cp.step()
     cp.step()
-    states = cp.job_states()
-    assert states[big[0]] == "leased", states
+    return cp, big, cp.job_states()
+
+
+def test_end_to_end_optimiser_unsticks_job(tmp_path):
+    """The optimiser preempts over-share victims for the stuck big job."""
+    cp, big, states = _starved_big_job(tmp_path, optimiser_enabled=True)
+    assert states[big] == "leased", states
     # exactly one node's worth of hogs (4 x 2cpu) was preempted
     assert sum(1 for s in states.values() if s == "failed") == 4
     cp.close()
 
 
-def test_optimiser_off_leaves_job_stuck(tmp_path):
-    from armada_tpu.server import JobSubmitItem, QueueRecord
-    from tests.control_plane import ControlPlane
+@pytest.mark.parametrize("incremental", [False, True], ids=["legacy", "incremental"])
+def test_optimiser_reads_the_cycles_own_leases_where_there_is_no_feed(tmp_path, incremental):
+    """The optimiser takes the running set from the feed where there is one,
+    else from the round's inputs and the cycle's own decisions, whose views
+    are then built once a cycle (an `away_prepare` span), and never where
+    the feed serves.  Both paths unstick the big job with the same four
+    preemptions."""
+    from armada_tpu.ops.trace import recorder, reset_recorder
+    from tests.test_trace import _find
 
-    cfg = SchedulingConfig(
-        shape_bucket=32,
-        protected_fraction_of_fair_share=100.0,
-        default_priority_class="armada-preemptible",
+    reset_recorder()
+    cp, big, states = _starved_big_job(
+        tmp_path, optimiser_enabled=True, incremental_problem_build=incremental
     )
-    cp = ControlPlane.build(tmp_path, config=cfg, runtime_s=600.0)
-    cp.server.create_queue(QueueRecord("hog"))
-    cp.server.create_queue(QueueRecord("starved"))
-    cp.server.submit_jobs(
-        "hog", "fill", [JobSubmitItem(resources={"cpu": "2", "memory": "2"}) for _ in range(8)]
-    )
-    for ex in cp.executors:
-        ex.run_once()
-    cp.step()
-    big = cp.server.submit_jobs(
-        "starved", "big", [JobSubmitItem(resources={"cpu": "8", "memory": "8"})]
-    )
-    cp.step()
-    cp.step()
-    assert cp.job_states()[big[0]] == "queued"
+    assert states[big] == "leased", states
+    assert sum(1 for s in states.values() if s == "failed") == 4
+    built = [len(_find(t.root, "away_prepare")) for t in recorder().last()]
+    assert built and set(built) == ({0} if incremental else {1})
+    reset_recorder()
+    cp.close()
+
+
+def test_optimiser_off_leaves_job_stuck(tmp_path):
+    cp, big, states = _starved_big_job(tmp_path)
+    assert states[big] == "queued"
     cp.close()
 
 def test_banned_node_never_hosts_the_retry():

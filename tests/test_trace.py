@@ -520,7 +520,7 @@ def test_served_cycle_roots_are_covered_by_named_children(_fresh_recorder):
     # the response's stats JSON is dumped inside the root (PR 28), last
     assert top[-3:] == ["mirror_commit", "slo_feed", "stats_encode"]
     assert {
-        "fleet_scan", "pool_nodes", "pool_prepare", "assemble", "round", "apply_outcome", "away_prepare",
+        "fleet_scan", "pool_nodes", "pool_prepare", "assemble", "round", "apply_outcome",
     } <= set(top)
     (assemble,) = _find(rnd, "assemble")
     assert [c.name for c in assemble.children if c.name != "gc_collect"] == [
@@ -604,13 +604,29 @@ _EXPECTED_STEADY_SPANS = {
     "assemble_bundle": 1, "round": 1, "devcache_apply": 1, "kernel_dispatch": 1,
     "decode_dispatch": 1, "shadow": 1, "shadow_thunk": 1, "sweep": 1, "fetch_decode": 1,
     "device_wait": 1, "decode": 1, "apply_outcome": 1, "remove_many": 1, "table_remove": 1,
-    "g_ids_copy": 1, "lease_many": 1, "away_prepare": 1, "slo_feed": 1, "xfer_down": 1,
+    "g_ids_copy": 1, "lease_many": 1, "slo_feed": 1, "xfer_down": 1,
     # the queue axis (PR 28): one span a boundary, whatever the queue count
     "queue_tokens": 1, "queue_caps": 1, "stats_encode": 1,
     # the sync's commit (PR 32): the run table asked once for every id that
     # may have left a run behind (here the fresh submits, which hold none)
     "unlease_many": 1,
 }
+
+
+def test_a_served_cycle_builds_no_away_views(_fresh_recorder):
+    """One pool with no away pools, on the incremental feed: nothing reads
+    the away pass's views of the cycle's own decisions, so none is built and
+    no `away_prepare` span opens.  The apply pass counts the priority classes
+    it resolved (`classes`) beside the leases it filled (`scheduled`)."""
+    sidecar, sid, F = _served_session()
+    for first in (0, 100):
+        _sync, rnd, resp = _served_cycle(sidecar, sid, F, first, 6)
+        assert len(resp.scheduled) == 6
+        assert not _find(rnd, "away_prepare")
+        (apply_,) = _find(rnd, "apply_outcome")
+        assert apply_.args == {
+            "pool": "default", "scheduled": 6, "preempted": 0, "classes": 1,
+        }
 
 
 @pytest.mark.parametrize("stats", [False, True], ids=["served", "stats-collected"])
@@ -816,7 +832,19 @@ def test_named_scopes_change_only_metadata(_fresh_recorder, monkeypatch):
 
     unnamed.__name__ = bare_round.__name__  # the module is named after it
     bare_fn = jax.jit(unnamed, static_argnames=tuple(statics))
-    bare = bare_fn.lower(p, **statics).compile().as_text()
+    # compiled afresh: a persistent compilation cache that an earlier test of
+    # this process enabled keys the program without its names, and would hand
+    # back the named executable
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        bare = bare_fn.lower(p, **statics).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
     assert "armada." not in bare
     assert _canonical_hlo(named) == _canonical_hlo(bare)
 
