@@ -976,3 +976,49 @@ def test_device_programs_keep_their_names(scope, _fresh_recorder, monkeypatch):
         fn = module._kernel()
     assert seen == [scope]
     assert fn.__name__ == name
+
+
+# What a device trace is read by (perfbench/harness/scopes.py): the op_name
+# path of each operation, which must name the program and, in the round's
+# loop, the trip's phase.
+_SCOPES_IN_OP_NAME = {
+    "_schedule_round_jit": (
+        "armada.round/armada.round.evict/", "armada.round.loop/while/body/select/",
+        "armada.round.loop/while/body/fit/", "armada.round.loop/while/body/commit/",
+        "armada.round.loop/while/body/window/", "armada.round/armada.round.repair/",
+    ),
+    "compact_result": ("/armada.compact/",),
+    "_verify_kernel_impl": ("/armada.verify/",),
+    "apply_delta": ("/armada.scatter/",),
+}
+
+
+def test_served_programs_carry_their_scopes_in_op_name(_fresh_recorder, monkeypatch):
+    """Two served cycles at the tiny size; each program they ran, lowered
+    (not compiled) with the arguments it ran with, names its scopes in the
+    `op_name` metadata of its operations."""
+    from armada_tpu.models import fair_scheduler as fs, slab, verify
+
+    calls: dict = {}
+
+    def spy(real):
+        def call(*args, **kw):
+            calls.setdefault(real.__name__, (real, args, kw))
+            return real(*args, **kw)
+
+        return call
+
+    for name in ("_schedule_round_jit", "compact_result"):
+        monkeypatch.setattr(fs, name, spy(getattr(fs, name)))
+    monkeypatch.setattr(verify, "_KERNEL", spy(verify._kernel()))
+    monkeypatch.setattr(slab, "_APPLY", spy(slab._make_apply()))
+    monkeypatch.setenv("ARMADA_VERIFY", "1")
+    sidecar, sid, F = _served_session()
+    _served_cycle(sidecar, sid, F, 0, 6)
+    _served_cycle(sidecar, sid, F, 6, 6)
+    assert sorted(calls) == sorted(_SCOPES_IN_OP_NAME)
+    for name, scopes in _SCOPES_IN_OP_NAME.items():
+        real, args, kw = calls[name]
+        text = real.lower(*args, **kw).as_text(debug_info=True)
+        for scope in scopes:
+            assert f'"jit({name})/' in text and scope in text, (name, scope)
